@@ -54,8 +54,8 @@ def bench_scheduler(seed: int, n_requests: int, prompt_len: int,
     from repro.serving.inference import get_engine, reset_engines
 
     reset_engines()
-    eng = get_engine(ARCH, seed, need_len=prompt_len + new_tokens,
-                     batch_size=batch)
+    eng = get_engine(ARCH, seed, reduced=True,
+                     need_len=prompt_len + new_tokens, batch_size=batch)
     rng = np.random.default_rng(seed)
     prompts = make_prompt_pool(rng, n_requests, prompt_len,
                                eng.cfg.vocab_size)
@@ -88,7 +88,7 @@ def bench_cached_serving(seed: int, n_requests: int, pool: int,
     """Zipfian request stream through the generate() calcfunction with the
     content-addressed cache on; hits are calls that ran zero decode steps."""
     from repro.caching import enable_caching
-    from repro.core.datatypes import ArrayData, Int, Str
+    from repro.core.datatypes import ArrayData, Bool, Int, Str
     from repro.engine.runner import Runner, set_default_runner
     from repro.observability.metrics import get_registry
     from repro.provenance.store import configure_store
@@ -115,7 +115,7 @@ def bench_cached_serving(seed: int, n_requests: int, pool: int,
             before = decode_steps.value
             out = generate(Str(ARCH), ArrayData(np.asarray(prompts[idx],
                                                            np.int32)),
-                           Int(new_tokens), Int(seed), Int(-1))
+                           Int(new_tokens), Int(seed), Int(-1), Bool(True))
             got = [int(t) for t in np.asarray(out["tokens"].value)]
             if decode_steps.value == before:
                 hits += 1
@@ -146,7 +146,7 @@ def assert_hit_fast_path(seed: int) -> None:
     """The --smoke acceptance check: the SECOND occurrence of a prompt runs
     zero decode steps and returns identical tokens."""
     from repro.caching import enable_caching
-    from repro.core.datatypes import ArrayData, Int, Str
+    from repro.core.datatypes import ArrayData, Bool, Int, Str
     from repro.engine.runner import Runner, set_default_runner
     from repro.observability.metrics import get_registry
     from repro.provenance.store import configure_store
@@ -159,9 +159,11 @@ def assert_hit_fast_path(seed: int) -> None:
     prompt = ArrayData(np.asarray([7, 11, 13, 17, 19, 23], np.int32))
     decode_steps = get_registry().counter("serving.decode_steps")
     with enable_caching():
-        cold = generate(Str(ARCH), prompt, Int(5), Int(seed), Int(-1))
+        cold = generate(Str(ARCH), prompt, Int(5), Int(seed), Int(-1),
+                        Bool(True))
         before = decode_steps.value
-        hot = generate(Str(ARCH), prompt, Int(5), Int(seed), Int(-1))
+        hot = generate(Str(ARCH), prompt, Int(5), Int(seed), Int(-1),
+                       Bool(True))
     ran = decode_steps.value - before
     same = np.array_equal(np.asarray(cold["tokens"].value),
                           np.asarray(hot["tokens"].value))

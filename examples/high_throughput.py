@@ -2,11 +2,16 @@
 a batch of training jobs with injected faults — the paper's headline
 deployment (fig. 4/5).
 
-    PYTHONPATH=src python examples/high_throughput.py --jobs 8 --workers 2
+    PYTHONPATH=src python examples/high_throughput.py --jobs 8
     PYTHONPATH=src python examples/high_throughput.py --crash   # kill workers mid-run
 
-With --crash, workers hard-exit every ~2s until the supervisor has
-restarted four of them; jobs still finish because (a) the broker requeues
+The jobs run on the device, and a chip belongs to one process at a time,
+so the daemon runs one worker (one per chip): a second worker process
+would fail on the TPU library's lock. The worker's slots still run many
+processes concurrently.
+
+With --crash, the worker hard-exits every ~2s until the supervisor has
+restarted it four times; jobs still finish because (a) the broker requeues
 un-acked tasks when heartbeats stop, and (b) each process resumes from its
 last persisted checkpoint on whichever worker picks it up.
 
@@ -50,7 +55,8 @@ def submit_batch(daemon, n_jobs):
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--jobs", type=int, default=6)
-    ap.add_argument("--workers", type=int, default=2)
+    ap.add_argument("--workers", type=int, default=1,
+                    help="worker processes; one per chip for device jobs")
     ap.add_argument("--crash", action="store_true")
     ap.add_argument("--cached-rerun", action="store_true",
                     help="resubmit the batch after it finishes; every job "

@@ -2,8 +2,9 @@
 
     PYTHONPATH=src python examples/serve_lm.py --batch 4 --requests 10
 
-Front door is ``repro.configs.setup_devices`` (host-device forcing works
-on CPU-only machines), then a :class:`~repro.serving.serve.BatchScheduler`
+Runs on whatever devices JAX finds (a TPU where one is attached; set
+``JAX_PLATFORMS=cpu`` to force the host). A
+:class:`~repro.serving.serve.BatchScheduler`
 drives prefill + per-slot-position decode: requests of different prompt
 lengths and budgets are co-batched, evicted on completion, and replaced
 from the FIFO queue mid-flight. ``--decode-impl pallas`` routes the
@@ -17,17 +18,16 @@ import time
 
 sys.path.insert(0, "src")
 
+import jax
 import numpy as np
 
-from repro.configs import get_config, setup_devices
+from repro.configs import enable_compile_cache, get_config
 from repro.models.registry import build
 from repro.serving.serve import BatchScheduler, Request
 
 
 def main():
     ap = argparse.ArgumentParser()
-    ap.add_argument("--platform", default="cpu")
-    ap.add_argument("--devices", type=int, default=1)
     ap.add_argument("--batch", type=int, default=4,
                     help="decode slots (micro-batch size)")
     ap.add_argument("--requests", type=int, default=10)
@@ -38,10 +38,9 @@ def main():
     ap.add_argument("--int8-kv", action="store_true")
     args = ap.parse_args()
 
-    devices = setup_devices(platform=args.platform, n_devices=args.devices)
-    print(f"devices: {len(devices)}x {devices[0].platform}")
-
-    import jax  # after setup_devices so the platform choice sticks
+    enable_compile_cache()
+    devices = jax.devices()
+    print(f"devices: {len(devices)}x {devices[0].device_kind}")
 
     cfg = get_config("aiida-demo-110m").replace(
         num_layers=4, d_model=256, num_heads=4, num_kv_heads=2, d_ff=704,

@@ -24,7 +24,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.configs import get_config
+from repro.configs import enable_compile_cache, get_config
 from repro.core import Dict, Float, Int, WorkChain, while_
 from repro.engine.launch import run_get_node
 from repro.engine.runner import Runner, set_default_runner
@@ -174,6 +174,7 @@ def main():
                     help="pk of an interrupted chain to resume")
     args = ap.parse_args()
 
+    enable_compile_cache()
     store = configure_store("examples_out/train_lm.db")
     runner = Runner(store=store)
     set_default_runner(runner)

@@ -8,8 +8,9 @@ from repro.calcjobs import TPUTrainJob
 
 def main():
     workdir = tempfile.mkdtemp(prefix="daemon_crash_")
-    # workers hard-exit (os._exit(17)) ~1.5s after starting — mid-job
-    daemon = Daemon(workdir, workers=2, slots=10, crash_after=1.5)
+    # the worker hard-exits (os._exit(17)) ~1.5s after starting — mid-job;
+    # one worker process: the jobs hold the device, one process per chip
+    daemon = Daemon(workdir, workers=1, slots=10, crash_after=1.5)
     daemon.start()
 
     pks = [daemon.submit(TPUTrainJob, {"config": Dict({

@@ -8,7 +8,8 @@ from repro.calcjobs import TPUTrainJob
 
 def main():
     workdir = tempfile.mkdtemp(prefix="daemon_test_")
-    daemon = Daemon(workdir, workers=2, slots=10)
+    # one worker process: the jobs hold the device, one process per chip
+    daemon = Daemon(workdir, workers=1, slots=10)
     daemon.start()
     print("daemon started on", daemon.host, daemon.port)
 

@@ -5,7 +5,8 @@ from __future__ import annotations
 
 import importlib
 
-from repro.configs.devices import make_serving_mesh, setup_devices  # noqa: F401
+from repro.configs.devices import (enable_compile_cache, make_mesh,  # noqa: F401
+                                   make_serving_mesh, setup_devices)
 from repro.models.common import ModelConfig
 
 ARCH_IDS = [

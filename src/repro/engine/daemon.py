@@ -141,10 +141,12 @@ def _worker_main(broker_host: str, broker_port: int, store_path: str,
     import random
     import uuid
 
+    from repro.configs.devices import enable_compile_cache
     from repro.engine.broker import BrokerClient
     from repro.engine.runner import Runner, set_default_runner
     from repro.provenance.store import configure_store
 
+    enable_compile_cache()  # device jobs in this worker share compiles
     obs_logs.configure()  # honours REPRO_LOG_LEVEL; repro.* namespace only
     store = configure_store(store_path)
 

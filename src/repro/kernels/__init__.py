@@ -1,3 +1,11 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+"""Pallas TPU kernels of the hot path, each with ``kernel.py`` (the
+``pallas_call``), ``ops.py`` (the jitted wrapper) and ``ref.py`` (the
+``jax.numpy`` oracle the tests compare against)."""
+
+import jax
+
+
+def interpret_default() -> bool:
+    """Run kernels in interpret mode everywhere but on a TPU backend: the
+    CPU tests execute the same kernel code through the Pallas interpreter."""
+    return jax.default_backend() != "tpu"

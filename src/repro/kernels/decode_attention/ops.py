@@ -5,6 +5,7 @@ from __future__ import annotations
 
 import jax
 
+from repro.kernels import interpret_default
 from repro.kernels.decode_attention import kernel as K
 
 
@@ -15,7 +16,7 @@ def decode_attention(q, k, v, kv_len, *, scale: float | None = None,
     if scale is None:
         scale = 1.0 / float(q.shape[-1]) ** 0.5
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = interpret_default()
     return K.decode_attention_kernel(q, k, v, kv_len, scale=float(scale),
                                      block_kv=int(block_kv),
                                      interpret=bool(interpret))

@@ -9,6 +9,9 @@ thread-block's shared-memory accumulator.
 
 Grid (fwd): (B, H, nQ, nKV); K/V index_map folds GQA: kv_head = h // G.
 Fully-masked KV blocks are skipped with pl.when (causal/local windows).
+Per-row statistics (m, l, the saved log-sum-exp and the backward's delta)
+are (rows, 1) columns with a trailing unit axis: the TPU compiler accepts
+a block whose last two dims are (multiple of 8, full dim).
 """
 
 from __future__ import annotations
@@ -85,16 +88,16 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
                    q_offset=q_offset)
         logits = jnp.where(ok, logits, NEG_INF)
 
-        m_prev = m_scr[...]
+        m_prev = m_scr[...]                           # (bq, 1)
         l_prev = l_scr[...]
-        m_new = jnp.maximum(m_prev, jnp.max(logits, axis=1))
+        m_new = jnp.maximum(m_prev, jnp.max(logits, axis=1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(logits - m_new[:, None])
-        l_new = l_prev * alpha + jnp.sum(p, axis=1)
+        p = jnp.exp(logits - m_new)
+        l_new = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
         pv = jax.lax.dot_general(
             p.astype(v_ref.dtype), v_ref[0, 0], (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        acc_scr[...] = acc_scr[...] * alpha[:, None] + pv
+        acc_scr[...] = acc_scr[...] * alpha + pv
         m_scr[...] = m_new
         l_scr[...] = l_new
 
@@ -102,13 +105,15 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
     def _finalize():
         l = l_scr[...]
         o_ref[0, 0] = (acc_scr[...] /
-                       jnp.maximum(l, 1e-30)[:, None]).astype(o_ref.dtype)
+                       jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
         lse_ref[0, 0] = (m_scr[...] + jnp.log(jnp.maximum(l, 1e-30)))
 
 
 def flash_attention_fwd(q, k, v, *, causal, window, scale, softcap, q_offset,
                         block_q, block_kv, interpret):
-    """q: (B, Sq, H, hd); k/v: (B, Skv, Hkv, hd) -> (out, lse)."""
+    """q: (B, Sq, H, hd); k/v: (B, Skv, Hkv, hd) -> (out, lse).
+
+    lse is (B, H, Sq, 1)."""
     b, sq, h, hd = q.shape
     skv, hkv = k.shape[1], k.shape[2]
     g = h // hkv
@@ -143,16 +148,16 @@ def flash_attention_fwd(q, k, v, *, causal, window, scale, softcap, q_offset,
         out_specs=[
             pl.BlockSpec((1, 1, bq, hd),
                          lambda ib, ih, iq, ik: (ib, ih, iq, 0)),
-            pl.BlockSpec((1, 1, bq),
-                         lambda ib, ih, iq, ik: (ib, ih, iq)),
+            pl.BlockSpec((1, 1, bq, 1),
+                         lambda ib, ih, iq, ik: (ib, ih, iq, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b, h, sq, hd), q.dtype),
-            jax.ShapeDtypeStruct((b, h, sq), jnp.float32),
+            jax.ShapeDtypeStruct((b, h, sq, 1), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((bq,), jnp.float32),
-            pltpu.VMEM((bq,), jnp.float32),
+            pltpu.VMEM((bq, 1), jnp.float32),
+            pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, hd), jnp.float32),
         ],
         interpret=interpret,
@@ -194,12 +199,12 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
         ok = _mask(bq, bkv, iq, ik, causal=causal, window=window,
                    q_offset=q_offset)
         logits = jnp.where(ok, logits, NEG_INF)
-        p = jnp.exp(logits - lse_ref[0, 0][:, None])
+        p = jnp.exp(logits - lse_ref[0, 0])
         do = do_ref[0, 0].astype(jnp.float32)
         dp = jax.lax.dot_general(do, v_ref[0, 0].astype(jnp.float32),
                                  (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_ref[0, 0][:, None])
+        ds = p * (dp - delta_ref[0, 0])
         if dcap is not None:
             ds = ds * dcap
         ds = jnp.where(ok, ds, 0.0)
@@ -247,7 +252,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         ok = _mask(bq, bkv, iq, ik, causal=causal, window=window,
                    q_offset=q_offset)
         logits = jnp.where(ok, logits, NEG_INF)
-        p = jnp.exp(logits - lse_ref[0, 0][:, None])         # (bq, bkv)
+        p = jnp.exp(logits - lse_ref[0, 0])                  # (bq, bkv)
         do = do_ref[0, 0].astype(jnp.float32)                # (bq, hd)
         dv_scr[...] += jax.lax.dot_general(
             p, do, (((0,), (0,)), ((), ())),
@@ -255,7 +260,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dp = jax.lax.dot_general(do, v_ref[0, 0].astype(jnp.float32),
                                  (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_ref[0, 0][:, None])
+        ds = p * (dp - delta_ref[0, 0])
         if dcap is not None:
             ds = ds * dcap
         ds = jnp.where(ok, ds, 0.0)
@@ -288,9 +293,9 @@ def flash_attention_bwd(q, k, v, out, lse, do, *, causal, window, scale,
     kt = k.transpose(0, 2, 1, 3)
     vt = v.transpose(0, 2, 1, 3)
     dot = do.transpose(0, 2, 1, 3)
-    # delta = rowsum(do * out) per (b, h, s)
+    # delta = rowsum(do * out) per (b, h, s), laid out like lse
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
-                    axis=-1).transpose(0, 2, 1)   # (B, H, Sq)
+                    axis=-1).transpose(0, 2, 1)[..., None]   # (B, H, Sq, 1)
 
     dq_kernel = functools.partial(
         _bwd_dq_kernel, scale=scale, causal=causal, window=window,
@@ -305,8 +310,10 @@ def flash_attention_bwd(q, k, v, out, lse, do, *, causal, window, scale,
             pl.BlockSpec((1, 1, bkv, hd),
                          lambda ib, ih, iq, ik, g=g: (ib, ih // g, ik, 0)),
             pl.BlockSpec((1, 1, bq, hd), lambda ib, ih, iq, ik: (ib, ih, iq, 0)),
-            pl.BlockSpec((1, 1, bq), lambda ib, ih, iq, ik: (ib, ih, iq)),
-            pl.BlockSpec((1, 1, bq), lambda ib, ih, iq, ik: (ib, ih, iq)),
+            pl.BlockSpec((1, 1, bq, 1),
+                         lambda ib, ih, iq, ik: (ib, ih, iq, 0)),
+            pl.BlockSpec((1, 1, bq, 1),
+                         lambda ib, ih, iq, ik: (ib, ih, iq, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, bq, hd),
                                lambda ib, ih, iq, ik: (ib, ih, iq, 0)),
@@ -324,7 +331,7 @@ def flash_attention_bwd(q, k, v, out, lse, do, *, causal, window, scale,
         return (ib, ih * g + ig // nq, ig % nq, 0)
 
     def lmap(ib, ih, ik, ig, g=g, nq=nq):
-        return (ib, ih * g + ig // nq, ig % nq)
+        return (ib, ih * g + ig // nq, ig % nq, 0)
 
     dk, dv = pl.pallas_call(
         dkv_kernel,
@@ -336,8 +343,8 @@ def flash_attention_bwd(q, k, v, out, lse, do, *, causal, window, scale,
             pl.BlockSpec((1, 1, bkv, hd),
                          lambda ib, ih, ik, ig: (ib, ih, ik, 0)),
             pl.BlockSpec((1, 1, bq, hd), qmap),
-            pl.BlockSpec((1, 1, bq), lmap),
-            pl.BlockSpec((1, 1, bq), lmap),
+            pl.BlockSpec((1, 1, bq, 1), lmap),
+            pl.BlockSpec((1, 1, bq, 1), lmap),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, bkv, hd),

@@ -11,11 +11,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret_default
 from repro.kernels.flash_attention import kernel as K
-
-
-def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 @functools.partial(jax.custom_vjp,
@@ -60,7 +57,7 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
     if scale is None:
         scale = 1.0 / float(q.shape[-1]) ** 0.5
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = interpret_default()
     q_offset = int(q_offset) if not hasattr(q_offset, "shape") else 0
     return _flash(q, k, v, causal, window, float(scale), float(softcap),
                   q_offset, int(block_q), int(block_kv), bool(interpret))

@@ -4,8 +4,7 @@ exact with no host-side correction."""
 
 from __future__ import annotations
 
-import jax
-
+from repro.kernels import interpret_default
 from repro.kernels.mlstm_chunk import kernel as K
 
 
@@ -15,6 +14,6 @@ def mlstm_chunk(q, k, v, li, lf, C0, n0, m0, *, chunk: int = 128,
 
     Returns (h: (B,H,S,hd), (C, n, m) final state, fp32)."""
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = interpret_default()
     return K.mlstm_chunk_kernel(q, k, v, li, lf, C0, n0, m0,
                                 chunk=int(chunk), interpret=bool(interpret))

@@ -30,19 +30,22 @@ def _kernel(a_ref, x_ref, h0_ref, hs_ref, hlast_ref, h_scr, *, nt, bt):
     x = x_ref[...].astype(jnp.float32)
 
     # in-block parallel prefix: after k rounds, for each t,
-    #   x[t] = combined update over (t-2^k, t];  a[t] = product of decays
+    #   x[t] = combined update over (t-2^k, t];  a[t] = product of decays.
+    # The shift by k along time is a rotation whose wrapped-around head
+    # (t < k) is masked: x gains nothing there, a keeps its value.
+    t = lax.broadcasted_iota(jnp.int32, a.shape, 1)
     k = 1
     while k < bt:
-        a_shift = jnp.pad(a, ((0, 0), (k, 0), (0, 0)))[:, :bt]
-        x_shift = jnp.pad(x, ((0, 0), (k, 0), (0, 0)))[:, :bt]
-        x = x + a * x_shift
-        a = a * jnp.where(
-            lax.broadcasted_iota(jnp.int32, a.shape, 1) >= k, a_shift, 1.0)
+        live = t >= k
+        a_shift = pltpu.roll(a, k, 1)
+        x_shift = pltpu.roll(x, k, 1)
+        x = x + jnp.where(live, a * x_shift, 0.0)
+        a = a * jnp.where(live, a_shift, 1.0)
         k *= 2
 
     hs = x + a * h_scr[...][:, None, :]
     hs_ref[...] = hs.astype(hs_ref.dtype)
-    h_scr[...] = hs[:, -1, :]
+    h_scr[...] = hs_ref[:, bt - 1, :]                   # static-index load
 
     @pl.when(it == nt - 1)
     def _final():

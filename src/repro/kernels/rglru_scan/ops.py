@@ -15,11 +15,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret_default
 from repro.kernels.rglru_scan import kernel as K
-
-
-def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def _scan(a, x, h0, block_t, block_d, interpret):
@@ -67,5 +64,5 @@ def rglru_scan(a, x, h0, *, block_t: int = 128, block_d: int = 512,
                interpret: bool | None = None):
     """h_t = a_t*h_{t-1} + x_t, blocked for TPU. Returns (hs, h_last)."""
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = interpret_default()
     return _rglru(a, x, h0, int(block_t), int(block_d), bool(interpret))

@@ -7,7 +7,7 @@ forces 512 host devices while tests/benches must see 1.
 
 from __future__ import annotations
 
-import jax
+from repro.configs.devices import make_mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -15,12 +15,7 @@ def make_production_mesh(*, multi_pod: bool = False):
     256 as (pod=2, data=16, model=16) — 'pod' is the DCN-connected axis."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
-
-
-def make_local_mesh(data: int = 1, model: int = 1):
-    """Small mesh over whatever devices exist (tests / examples)."""
-    return jax.make_mesh((data, model), ("data", "model"))
+    return make_mesh(shape, axes)
 
 
 # TPU v5e hardware model used by the roofline analysis (per chip).

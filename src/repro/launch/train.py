@@ -18,9 +18,9 @@ import time
 import jax
 import jax.numpy as jnp
 
-from repro.configs import get_config, reduced_config
+from repro.configs import (enable_compile_cache, get_config, make_mesh,
+                           reduced_config)
 from repro.distributed.sharding import make_rules, tree_named_shardings
-from repro.launch.mesh import make_local_mesh
 from repro.models.common import axis_rules
 from repro.models.registry import build
 from repro.training import checkpoint as ckpt_mod
@@ -52,9 +52,10 @@ def main() -> None:
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = reduced_config(args.arch) if args.reduced else get_config(args.arch)
     bundle = build(cfg)
-    mesh = make_local_mesh(args.data_mesh, args.model_mesh)
+    mesh = make_mesh((args.data_mesh, args.model_mesh), ("data", "model"))
     rules = make_rules(cfg, mesh, fsdp=args.data_mesh > 1)
     tcfg = TrainConfig(
         optim=OptimConfig(name=args.optimizer, lr=args.lr,

@@ -17,15 +17,19 @@ the math is unchanged — the repeat is purely a layout transformation.
 
 from __future__ import annotations
 
+import functools
 from typing import Any
 
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.sharding import PartitionSpec as P
 
 from repro.models.common import (
     ModelConfig,
     ParamSpec,
+    active_mesh,
+    logical_to_spec,
     rms_norm,
     rope,
     shard,
@@ -371,13 +375,9 @@ def attn_decode(cfg: ModelConfig, p: dict[str, jax.Array], x: jax.Array,
     # on the kernel's scalar-prefetch lens argument. Ring buffers and
     # soft-capping stay on the masked-einsum path below.
     if cfg.decode_impl == "pallas" and window == 0 and not cfg.attn_softcap:
-        from repro.kernels.decode_attention import ops as da_ops
-
         kv_len = (pos if per_row else jnp.broadcast_to(pos, (b,))) + 1
-        out = da_ops.decode_attention(q[:, 0], ck, cv,
-                                      kv_len.astype(jnp.int32),
-                                      scale=float(scale),
-                                      block_kv=cfg.attn_kv_block)
+        out = _decode_kernel(cfg, q[:, 0], ck, cv, kv_len.astype(jnp.int32),
+                             float(scale))
         out = out[:, None]                                  # (B, 1, H, hd)
         out = shard(out, "kv_batch", None, "heads_sharded", None)
         dt = x.dtype
@@ -409,6 +409,36 @@ def attn_decode(cfg: ModelConfig, p: dict[str, jax.Array], x: jax.Array,
     out = shard(out, "kv_batch", None, "heads_sharded", None)
     dt = x.dtype
     return jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(dt)), cache
+
+
+def _decode_kernel(cfg: ModelConfig, q, ck, cv, kv_len, scale: float):
+    """The flash-decode kernel; under a mesh, run per shard via shard_map.
+
+    A Pallas kernel cannot be partitioned automatically, so with axis
+    rules active it runs on each device's block of the cache: batch rows
+    over ``kv_batch``, KV heads (and their query-head groups) over
+    ``kv_heads_sharded``. A cache sharded along the sequence needs a
+    cross-shard softmax merge that does not exist yet; that case keeps the
+    bare call, which the TPU compiler refuses (the CPU interpreter lowers
+    it to plain HLO, which GSPMD partitions).
+    """
+    from repro.kernels.decode_attention import ops as da_ops
+
+    call = functools.partial(da_ops.decode_attention, scale=scale,
+                             block_kv=cfg.attn_kv_block)
+    mesh = active_mesh()
+    if mesh is None:
+        return call(q, ck, cv, kv_len)
+    cache_axes = kv_cache_axes(cfg, layers=False)["k"]
+    batch, seq, heads, _ = logical_to_spec(cache_axes)
+    seq_axes = (seq,) if isinstance(seq, str) else tuple(seq or ())
+    if any(mesh.shape[a] > 1 for a in seq_axes):
+        return call(q, ck, cv, kv_len)
+    kv_spec = P(batch, None, heads, None)
+    q_spec = P(batch, heads, None)
+    return jax.shard_map(call, mesh=mesh,
+                         in_specs=(q_spec, kv_spec, kv_spec, P(batch)),
+                         out_specs=q_spec, check_vma=False)(q, ck, cv, kv_len)
 
 
 def prefill_into_cache(cfg: ModelConfig, p: dict[str, jax.Array], x: jax.Array,

@@ -288,6 +288,11 @@ def logical_to_spec(axes: Sequence[str | None]):
     return P(*parts)
 
 
+def active_mesh():
+    """The mesh of the installed axis rules; None when no rules are active."""
+    return _AXIS_RULES.mesh if _AXIS_RULES.rules is not None else None
+
+
 def shard(x: jax.Array, *axes: str | None) -> jax.Array:
     """Apply a logical sharding constraint; identity when no rules active."""
     if _AXIS_RULES.rules is None or _AXIS_RULES.mesh is None:

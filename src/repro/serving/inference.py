@@ -14,8 +14,11 @@ any other calculation. Greedy decoding makes the mapping
 
 The execution side is a per-OS-process :class:`ServingEngine` memo: one
 compiled :class:`~repro.serving.serve.BatchScheduler` per (arch, seed,
-cache size) that cold prompts are batched through. The reduced demo
-config decodes through the Pallas flash-decode kernel
+cache size) that cold prompts are batched through. The model is the
+published ``arch`` config unless the call asks for ``reduced`` (the
+CPU-sized test config); the size is a calcfunction input, so it enters
+the provenance fingerprint and a reduced generation is never served for a
+published one. Decode runs through the Pallas flash-decode kernel
 (``decode_impl='pallas'``, interpreted off-TPU) so the serving hot loop
 exercises the same kernel the TPU path runs.
 """
@@ -28,12 +31,11 @@ from typing import Any
 
 import numpy as np
 
-from repro.core.datatypes import ArrayData, Dict, Int, Str
+from repro.core.datatypes import ArrayData, Bool, Dict, Int, Str
 from repro.core.process_functions import calcfunction
 from repro.serving.serve import BatchScheduler, Request
 
-#: serving defaults for the reduced demo model (kept deliberately small —
-#: CPU interpret-mode decode must stay test-friendly)
+#: serving defaults (tests ask for the reduced size of this arch)
 DEFAULT_ARCH = "aiida-demo-110m"
 DEFAULT_BATCH_SIZE = 4
 _MIN_CACHE = 128
@@ -41,10 +43,10 @@ _MIN_CACHE = 128
 _ENGINES: dict[tuple, "ServingEngine"] = {}
 
 
-def _serving_config(arch: str, decode_impl: str):
-    from repro.configs import reduced_config
+def _serving_config(arch: str, reduced: bool, decode_impl: str):
+    from repro.configs import get_config, reduced_config
 
-    cfg = reduced_config(arch)
+    cfg = reduced_config(arch) if reduced else get_config(arch)
     return cfg.replace(decode_impl=decode_impl)
 
 
@@ -53,13 +55,14 @@ class ServingEngine:
 
     def __init__(self, arch: str, seed: int, max_len: int,
                  batch_size: int = DEFAULT_BATCH_SIZE,
-                 eos_id: int = -1, decode_impl: str = "pallas"):
+                 eos_id: int = -1, decode_impl: str = "pallas",
+                 reduced: bool = False):
         import jax
 
         from repro.models.registry import build
 
         self.arch, self.seed = arch, int(seed)
-        self.cfg = _serving_config(arch, decode_impl)
+        self.cfg = _serving_config(arch, bool(reduced), decode_impl)
         self.bundle = build(self.cfg)
         self.params = self.bundle.init_params(jax.random.PRNGKey(int(seed)))
         self.scheduler = BatchScheduler(self.bundle, self.params,
@@ -85,19 +88,21 @@ class ServingEngine:
 
 
 def get_engine(arch: str = DEFAULT_ARCH, seed: int = 0, *,
-               need_len: int = _MIN_CACHE, batch_size: int = DEFAULT_BATCH_SIZE,
-               eos_id: int = -1, decode_impl: str = "pallas") -> ServingEngine:
+               reduced: bool = False, need_len: int = _MIN_CACHE,
+               batch_size: int = DEFAULT_BATCH_SIZE, eos_id: int = -1,
+               decode_impl: str = "pallas") -> ServingEngine:
     """Memoised engine; ``need_len`` is bucketed to a power of two so one
     compiled cache serves a band of request sizes."""
     max_len = _MIN_CACHE
     while max_len < int(need_len) + 1:
         max_len *= 2
-    key = (arch, int(seed), max_len, batch_size, eos_id, decode_impl)
+    key = (arch, int(seed), bool(reduced), max_len, batch_size, eos_id,
+           decode_impl)
     eng = _ENGINES.get(key)
     if eng is None:
         eng = _ENGINES[key] = ServingEngine(
             arch, seed, max_len, batch_size=batch_size, eos_id=eos_id,
-            decode_impl=decode_impl)
+            decode_impl=decode_impl, reduced=reduced)
     return eng
 
 
@@ -106,28 +111,33 @@ def reset_engines() -> None:
     _ENGINES.clear()
 
 
-def prompt_fingerprint(arch: str, seed: int, prompt: Any) -> str:
+def prompt_fingerprint(arch: str, seed: int, prompt: Any,
+                       reduced: bool = False) -> str:
     """The serving-side prompt-prefix fingerprint: sha256 over the model
-    identity and the exact prompt token sequence. Two requests with the
-    same fingerprint are guaranteed the same continuation (greedy), which
-    is the property the content-addressed cache exploits."""
+    identity (arch, size, seed) and the exact prompt token sequence. Two
+    requests with the same fingerprint are guaranteed the same
+    continuation (greedy), which is the property the content-addressed
+    cache exploits."""
     toks = np.asarray(prompt, np.int32)
+    size = "reduced" if reduced else "published"
     h = hashlib.sha256()
-    h.update(f"{arch}|{int(seed)}|".encode())
+    h.update(f"{arch}|{size}|{int(seed)}|".encode())
     h.update(toks.tobytes())
     return h.hexdigest()
 
 
 @calcfunction
 def generate(arch: Str, prompt: ArrayData, max_new_tokens: Int,
-             seed: Int, eos_id: Int):
-    """Greedy continuation of ``prompt`` under the (reduced) ``arch`` model
-    with parameters drawn from ``seed``. Returns the generated tokens plus
-    a stats document; both are provenance outputs, so identical calls are
+             seed: Int, eos_id: Int, reduced: Bool = lambda: Bool(False)):
+    """Greedy continuation of ``prompt`` under the ``arch`` model — its
+    published config, or the CPU-sized one when ``reduced`` — with
+    parameters drawn from ``seed``. Returns the generated tokens plus a
+    stats document; both are provenance outputs, so identical calls are
     cache hits that never re-decode."""
     toks = [int(t) for t in np.asarray(prompt.value).reshape(-1)]
     new = int(max_new_tokens.value)
-    eng = get_engine(str(arch.value), int(seed.value),
+    small = bool(reduced.value)
+    eng = get_engine(str(arch.value), int(seed.value), reduced=small,
                      need_len=len(toks) + new, eos_id=int(eos_id.value))
     t0 = time.monotonic()
     req = eng.generate_many([toks], new)[0]
@@ -139,7 +149,7 @@ def generate(arch: Str, prompt: ArrayData, max_new_tokens: Int,
             "new_tokens": len(req.generated),
             "finish_reason": req.finish_reason,
             "fingerprint": prompt_fingerprint(str(arch.value),
-                                              int(seed.value), toks),
+                                              int(seed.value), toks, small),
             "wall_seconds": dt,
         }),
     }

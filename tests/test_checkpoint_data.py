@@ -87,7 +87,9 @@ def test_elastic_restore_resharding(tmp_path):
     single-device analogue of resuming on a different mesh size)."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    mesh = jax.make_mesh((1,), ("data",))
+    from repro.configs import make_mesh
+
+    mesh = make_mesh((1,), ("data",))
     state = {"w": jnp.arange(16.0).reshape(4, 4)}
     ckpt.save_checkpoint(str(tmp_path), 1, state)
     sh = {"w": NamedSharding(mesh, P("data", None))}

@@ -287,7 +287,7 @@ def test_sharded_decode_matches_single_device():
 
 def test_generate_cache_hit_runs_zero_decode_steps(runner):
     from repro.caching import enable_caching
-    from repro.core.datatypes import ArrayData, Int, Str
+    from repro.core.datatypes import ArrayData, Bool, Int, Str
     from repro.observability.metrics import get_registry
     from repro.serving.inference import (generate, prompt_fingerprint,
                                          reset_engines)
@@ -298,7 +298,7 @@ def test_generate_cache_hit_runs_zero_decode_steps(runner):
 
     def call():
         return generate(Str(ARCH), ArrayData(np.asarray(prompt, np.int32)),
-                        Int(4), Int(0), Int(-1))
+                        Int(4), Int(0), Int(-1), Bool(True))
 
     with enable_caching():
         cold = call()
@@ -309,20 +309,21 @@ def test_generate_cache_hit_runs_zero_decode_steps(runner):
                                   np.asarray(hot["tokens"].value))
     stats = hot["stats"].value
     assert stats["new_tokens"] == len(np.asarray(hot["tokens"].value))
-    assert stats["fingerprint"] == prompt_fingerprint(ARCH, 0, prompt)
+    assert stats["fingerprint"] == prompt_fingerprint(ARCH, 0, prompt,
+                                                      reduced=True)
 
 
 def test_generate_distinct_prompts_do_not_collide(runner):
     from repro.caching import enable_caching
-    from repro.core.datatypes import ArrayData, Int, Str
+    from repro.core.datatypes import ArrayData, Bool, Int, Str
     from repro.serving.inference import generate, reset_engines
 
     reset_engines()
     with enable_caching():
         a = generate(Str(ARCH), ArrayData(np.asarray([1, 2, 3], np.int32)),
-                     Int(4), Int(0), Int(-1))
+                     Int(4), Int(0), Int(-1), Bool(True))
         b = generate(Str(ARCH), ArrayData(np.asarray([1, 2, 4], np.int32)),
-                     Int(4), Int(0), Int(-1))
+                     Int(4), Int(0), Int(-1), Bool(True))
     fa = a["stats"].value["fingerprint"]
     fb = b["stats"].value["fingerprint"]
     assert fa != fb
@@ -332,9 +333,57 @@ def test_engine_memo_buckets_by_cache_size():
     from repro.serving.inference import get_engine, reset_engines
 
     reset_engines()
-    e1 = get_engine(ARCH, 0, need_len=10)
-    e2 = get_engine(ARCH, 0, need_len=100)     # same 128-slot bucket
-    e3 = get_engine(ARCH, 0, need_len=200)     # next power of two
+    e1 = get_engine(ARCH, 0, reduced=True, need_len=10)
+    e2 = get_engine(ARCH, 0, reduced=True, need_len=100)  # same 128 bucket
+    e3 = get_engine(ARCH, 0, reduced=True, need_len=200)  # next power of 2
     assert e1 is e2
     assert e3 is not e1
     assert e3.scheduler.max_len == 256
+
+
+def test_model_size_enters_the_fingerprint():
+    from repro.serving.inference import prompt_fingerprint
+
+    prompt = [3, 5, 7]
+    assert (prompt_fingerprint(ARCH, 0, prompt, reduced=True)
+            != prompt_fingerprint(ARCH, 0, prompt, reduced=False))
+
+
+def test_reduced_generation_is_not_served_for_published(runner, monkeypatch):
+    """The size is a calcfunction input: a cached reduced generation is no
+    hit for the published size of the same arch, nor the other way round.
+    The published engine is stood in by the reduced one (same interface),
+    so the test stays CPU-sized while the provenance inputs differ."""
+    from repro.caching import enable_caching
+    from repro.core.datatypes import ArrayData, Bool, Int, Str
+    from repro.observability.metrics import get_registry
+    from repro.serving import inference
+
+    inference.reset_engines()
+    real_get_engine = inference.get_engine
+    sizes = []
+
+    def cpu_sized(arch, seed, *, reduced=False, **kw):
+        sizes.append(reduced)
+        return real_get_engine(arch, seed, reduced=True, **kw)
+
+    monkeypatch.setattr(inference, "get_engine", cpu_sized)
+    steps = get_registry().counter("serving.decode_steps")
+    prompt = ArrayData(np.asarray([2, 4, 6, 8], np.int32))
+
+    def call(*reduced):
+        before = steps.value
+        out = inference.generate(Str(ARCH), prompt, Int(3), Int(0), Int(-1),
+                                 *reduced)
+        return out, steps.value - before
+
+    with enable_caching():
+        small, ran_small = call(Bool(True))
+        big, ran_big = call()                      # default: published
+        _, ran_small_again = call(Bool(True))
+        _, ran_big_again = call(Bool(False))
+    assert sizes == [True, False]                  # the hits built nothing
+    assert ran_small > 0 and ran_big > 0, "a size was served another's cache"
+    assert ran_small_again == 0 and ran_big_again == 0
+    assert (small["stats"].value["fingerprint"]
+            != big["stats"].value["fingerprint"])

@@ -76,10 +76,11 @@ SUBPROCESS_PROG = textwrap.dedent("""
 
     # shrink the production mesh for the in-test lowering
     import repro.launch.mesh as mesh_mod
+    from repro.configs import make_mesh
     def small_mesh(*, multi_pod=False):
         if multi_pod:
-            return jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
-        return jax.make_mesh((2, 4), ("data", "model"))
+            return make_mesh((2, 2, 2), ("pod", "data", "model"))
+        return make_mesh((2, 4), ("data", "model"))
     mesh_mod.make_production_mesh = small_mesh
     dr.make_production_mesh = small_mesh
 
@@ -113,3 +114,22 @@ def test_real_lowering_on_8_fake_devices(arch, shape, multi):
     assert line, proc.stdout
     result = json.loads(line[0][len("RESULT:"):])
     assert result["ok"], proc.stdout
+
+
+def test_make_mesh_has_auto_axes():
+    from jax.sharding import AxisType
+
+    from repro.configs import make_mesh, make_serving_mesh
+
+    assert make_mesh((1,), ("data",)).axis_types == (AxisType.Auto,)
+    assert make_serving_mesh(1, 1).axis_types == (AxisType.Auto,) * 2
+
+
+def test_setup_devices_pins_no_platform_by_default():
+    import jax
+
+    from repro.configs import setup_devices
+
+    before = jax.config.read("jax_platform_name")
+    assert setup_devices() == jax.devices()
+    assert jax.config.read("jax_platform_name") == before
