@@ -20,6 +20,7 @@ from repro.core.datatypes import DataValue, to_data_value
 from repro.core.exit_code import ExitCode
 from repro.core.process import Process
 from repro.core.process_spec import ProcessSpec
+from repro.observability import trace
 from repro.provenance.store import NodeType
 
 
@@ -88,18 +89,7 @@ def _process_function(fn: Callable, node_type: NodeType) -> Callable:
 
     @functools.wraps(fn)
     def wrapper(*args, **kwargs):
-        bound = sig.bind(*args, **kwargs)
-        inputs: dict[str, Any] = {}
-        for name, value in bound.arguments.items():
-            param = sig.parameters[name]
-            if param.kind is param.VAR_KEYWORD:
-                for k2, v2 in value.items():
-                    inputs[k2] = to_data_value(v2)
-            else:
-                inputs[name] = to_data_value(value)
-        from repro.engine.runner import default_runner
-        runner = default_runner()
-        process = process_class(inputs=inputs, runner=runner)
+        process, runner = _create(process_class, sig, args, kwargs)
         exit_code = runner.run_sync(process)
         if exit_code.status == 999:
             logs = runner.store.get_logs(process.pk)
@@ -137,19 +127,26 @@ def _outputs_as_result(process: Process) -> Any:
     return outputs
 
 
-def _run_get_node(wrapper, process_class, sig, *args, **kwargs):
+def _create(process_class, sig, args, kwargs):
+    """Bind a call's arguments as inputs and create its process on the
+    default runner; returns the process and the runner."""
     from repro.engine.runner import default_runner
-    bound = sig.bind(*args, **kwargs)
-    inputs = {}
-    for name, value in bound.arguments.items():
-        param = sig.parameters[name]
-        if param.kind is param.VAR_KEYWORD:
-            for k2, v2 in value.items():
-                inputs[k2] = to_data_value(v2)
-        else:
-            inputs[name] = to_data_value(value)
-    runner = default_runner()
-    process = process_class(inputs=inputs, runner=runner)
+    with trace.span("process.create"):
+        bound = sig.bind(*args, **kwargs)
+        inputs: dict[str, Any] = {}
+        for name, value in bound.arguments.items():
+            param = sig.parameters[name]
+            if param.kind is param.VAR_KEYWORD:
+                for k2, v2 in value.items():
+                    inputs[k2] = to_data_value(v2)
+            else:
+                inputs[name] = to_data_value(value)
+        runner = default_runner()
+        return process_class(inputs=inputs, runner=runner), runner
+
+
+def _run_get_node(wrapper, process_class, sig, *args, **kwargs):
+    process, runner = _create(process_class, sig, args, kwargs)
     exit_code = runner.run_sync(process)
     result = getattr(process, "_result_value", None)
     if result is None and process.outputs:

@@ -37,12 +37,13 @@ def _default_runner():
     return default_runner()
 
 
-def _expand(process, inputs, kwargs):
-    """Combine the positional inputs dict and keyword inputs, then expand:
-    both override-styles flow through the same builder-merge semantics."""
+def _merge(inputs, kwargs) -> dict[str, Any]:
+    """Combine the positional inputs dict and keyword inputs (keywords
+    win); both override-styles then flow through the same builder-merge
+    semantics of ``expand_launch_target``."""
     overrides = dict(inputs or {})
     overrides.update(kwargs)
-    return expand_launch_target(process, overrides)
+    return overrides
 
 
 def run(process, inputs: Mapping[str, Any] | None = None, *,
@@ -56,9 +57,9 @@ def run_get_node(process, inputs: Mapping[str, Any] | None = None, *,
     """Run a process to completion, blocking; returns ``(outputs,
     process)`` — the process object doubles as the provenance node view
     (``.pk``, ``.exit_code``, ``.is_finished_ok``)."""
-    process_class, merged = _expand(process, inputs, kwargs)
-    runner = runner or _default_runner()
-    outputs, node = runner.run(process_class, merged)
+    # the runner expands the target inside its process.create span
+    outputs, node = (runner or _default_runner()).run(
+        process, _merge(inputs, kwargs))
     return ResultAndNode(outputs, node)
 
 
@@ -75,7 +76,8 @@ def submit(process, inputs: Mapping[str, Any] | None = None, *,
     a ``ProcessHandle`` on a local runner, a ``QueuedHandle`` when the
     runner is distributed and the process was shipped to the daemon's
     task queue (paper §III.C.a)."""
-    process_class, merged = _expand(process, inputs, kwargs)
+    process_class, merged = expand_launch_target(process,
+                                                 _merge(inputs, kwargs))
     runner = runner or _default_runner()
     return runner.submit(process_class, inputs=merged)
 
@@ -84,5 +86,6 @@ def instantiate(process, inputs: Mapping[str, Any] | None = None, *,
                 runner=None, **kwargs) -> Process:
     """Construct (but do not schedule) a process: node + input links +
     initial checkpoint are created, so the pk can be shipped anywhere."""
-    process_class, merged = _expand(process, inputs, kwargs)
+    process_class, merged = expand_launch_target(process,
+                                                 _merge(inputs, kwargs))
     return process_class(inputs=merged, runner=runner or _default_runner())

@@ -177,8 +177,10 @@ class Runner:
         """Blockingly run a process (class or ProcessBuilder) to
         completion on this runner's loop."""
         from repro.core.builder import expand_launch_target
-        process_class, inputs = expand_launch_target(process_class, inputs)
-        process = process_class(inputs=inputs, runner=self)
+        with trace.span("process.create"):
+            process_class, inputs = expand_launch_target(process_class,
+                                                         inputs)
+            process = process_class(inputs=inputs, runner=self)
         if self.loop.is_running():
             raise RuntimeError("Runner.run() cannot be used inside a running "
                                "loop; use submit()")

@@ -102,5 +102,6 @@ def decode_attention_kernel(q, k, v, kv_len, *, scale, block_kv, interpret):
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, g, hd), q.dtype),
         interpret=interpret,
+        name="decode_attention",
     )(lens, qg, kt, vt)
     return out.reshape(b, h, hd)

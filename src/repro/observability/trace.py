@@ -15,9 +15,13 @@ is near-zero-cost: ``span()`` returns a shared no-op singleton — no
 ``REPRO_TRACE_SAMPLE`` (0.0–1.0) keeps only that fraction of *root*
 spans/timelines when tracing is on.
 
-Finished spans go to the current :class:`Timeline` sink (set by
-``Process.step_until_terminated`` for the duration of a run) or, when no
-sink is active, to a small bounded in-memory ring for inspection.
+An enabled span also enters a ``jax.profiler.TraceAnnotation`` of its
+bare name, once jax is loaded (this module never imports it), so a
+profiler trace holds the program's spans on the host plane, on the same
+clock as the device planes. Finished spans go to the current
+:class:`Timeline` sink (set by ``Process.step_until_terminated`` for the
+duration of a run); a span opened with ``persist=False`` (one per decode
+step) and a span finished outside any sink reach the profiler only.
 """
 
 from __future__ import annotations
@@ -28,8 +32,8 @@ import inspect
 import itertools
 import os
 import random
+import sys
 import time
-from collections import deque
 from typing import Any, Callable
 
 ENV_VAR = "REPRO_TRACE"
@@ -43,9 +47,6 @@ _CURRENT: contextvars.ContextVar["Span | None"] = \
 #: where finished spans are collected (a per-process Timeline, usually)
 _SINK: contextvars.ContextVar["Timeline | None"] = \
     contextvars.ContextVar("TRACE_SINK", default=None)
-
-#: fallback ring for spans finished outside any timeline
-_RECENT: deque = deque(maxlen=1000)
 
 _enabled: bool | None = None  # None = not yet resolved from the env
 _sample: float = 1.0
@@ -81,26 +82,33 @@ def disable() -> None:
 
 
 def reset() -> None:
-    """Back to env-resolved state; clears the in-memory ring (tests)."""
+    """Back to env-resolved state (tests)."""
     global _enabled
     _enabled = None
-    _RECENT.clear()
 
 
 def _sampled() -> bool:
     return _sample >= 1.0 or random.random() < _sample
 
 
+def _profiler_annotation(name: str):
+    """A profiler annotation of ``name`` if jax is already loaded, else
+    None: processes that never touch a device stay free of jax."""
+    profiler = sys.modules.get("jax.profiler")
+    return profiler.TraceAnnotation(name) if profiler is not None else None
+
+
 class Span:
     """One named wall-clock interval. Use via :func:`span`, not directly."""
 
     __slots__ = ("name", "span_id", "parent", "start", "end", "attrs",
-                 "_token")
+                 "persist", "_token", "_annotation")
 
-    def __init__(self, name: str, attrs: dict | None):
+    def __init__(self, name: str, attrs: dict | None, persist: bool = True):
         self.name = name
         self.span_id = next(_ids)
         self.attrs = attrs
+        self.persist = persist
         self.start = 0.0
         self.end = 0.0
         self.parent = _CURRENT.get()
@@ -111,17 +119,20 @@ class Span:
 
     def __enter__(self) -> "Span":
         self._token = _CURRENT.set(self)
+        self._annotation = _profiler_annotation(self.name)
+        if self._annotation is not None:
+            self._annotation.__enter__()
         self.start = time.perf_counter()
         return self
 
     def __exit__(self, *exc) -> None:
         self.end = time.perf_counter()
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
         _CURRENT.reset(self._token)
         sink = _SINK.get()
         if sink is not None:
             sink.append(self)
-        else:
-            _RECENT.append(self)
 
     def to_dict(self) -> dict:
         d = {"name": self.name, "id": self.span_id,
@@ -147,14 +158,16 @@ class _NoopSpan:
 _NOOP = _NoopSpan()
 
 
-def span(name: str, **attrs: Any):
+def span(name: str, persist: bool = True, **attrs: Any):
     """Open a span (context manager). Returns the shared no-op singleton
-    when tracing is disabled or this would-be root span is sampled out."""
+    when tracing is disabled or this would-be root span is sampled out.
+    ``persist=False`` keeps the span out of persisted process timelines
+    (a drained :class:`Timeline`); the profiler still records it."""
     if not (_enabled if _enabled is not None else _resolve()):
         return _NOOP
     if _sample < 1.0 and _CURRENT.get() is None and not _sampled():
         return _NOOP
-    return Span(name, attrs or None)
+    return Span(name, attrs or None, persist)
 
 
 def traced(name: str | None = None, **attrs: Any) -> Callable:
@@ -182,11 +195,6 @@ def current_span() -> Span | None:
     return _CURRENT.get()
 
 
-def recent_spans() -> list[Span]:
-    """Spans finished outside any timeline (newest last)."""
-    return list(_RECENT)
-
-
 # ---------------------------------------------------------------------------
 # Timelines — per-process span collection
 # ---------------------------------------------------------------------------
@@ -195,7 +203,8 @@ class Timeline:
     """Collects the finished spans of one logical operation (a process
     run). Installed as the context's sink with :func:`push_sink`;
     drained once at the end — appends after draining are dropped so a
-    late-finishing stray span cannot resurrect a persisted timeline."""
+    late-finishing stray span cannot resurrect a persisted timeline.
+    ``spans`` holds every span; the drain leaves out ``persist=False`` ones."""
 
     __slots__ = ("spans", "_closed")
 
@@ -213,14 +222,15 @@ class Timeline:
         (e.g. the root span around the caller) are included with their
         end stamped 'now'."""
         self._closed = True
-        out = [s.to_dict() for s in self.spans]
+        out = [s.to_dict() for s in self.spans if s.persist]
         if stamp_open:
             now = time.perf_counter()
             open_span = _CURRENT.get()
             while open_span is not None:
-                d = open_span.to_dict()
-                d["end"] = now
-                out.append(d)
+                if open_span.persist:
+                    d = open_span.to_dict()
+                    d["end"] = now
+                    out.append(d)
                 open_span = open_span.parent
         out.sort(key=lambda d: d["start"])
         return out

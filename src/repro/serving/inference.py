@@ -33,6 +33,7 @@ import numpy as np
 
 from repro.core.datatypes import ArrayData, Bool, Dict, Int, Str
 from repro.core.process_functions import calcfunction
+from repro.observability import trace
 from repro.serving.serve import BatchScheduler, Request
 
 #: serving defaults (tests ask for the reduced size of this arch)
@@ -140,7 +141,8 @@ def generate(arch: Str, prompt: ArrayData, max_new_tokens: Int,
     eng = get_engine(str(arch.value), int(seed.value), reduced=small,
                      need_len=len(toks) + new, eos_id=int(eos_id.value))
     t0 = time.monotonic()
-    req = eng.generate_many([toks], new)[0]
+    with trace.span("serving.generate"):
+        req = eng.generate_many([toks], new)[0]
     dt = time.monotonic() - t0
     return {
         "tokens": ArrayData(np.asarray(req.generated, np.int32)),

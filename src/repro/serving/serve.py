@@ -17,6 +17,9 @@ building blocks (also lowered by the dry-run for ``decode_*`` cells).
   slot for the next queued request mid-flight;
 * **metrics** — per-request latency and token counts land in the
   process-wide observability registry (``serving.*``).
+* **spans** — ``serving.admit`` per request; ``serving.step`` and its
+  ``serving.fetch`` per decode step, unpersisted (profiler only), so a
+  long generation adds no rows to its process timeline.
 
 Greedy decoding throughout: a given (model, prompt) pair always yields
 the same continuation, which is what lets generations participate in the
@@ -36,6 +39,7 @@ import numpy as np
 from jax import lax
 
 from repro.models.registry import LM_FAMILIES, ModelBundle
+from repro.observability import trace
 from repro.observability.metrics import get_registry
 
 
@@ -157,20 +161,21 @@ class BatchScheduler:
             full_cache, row_cache)
 
     def _prefill_into_slot(self, req: Request, slot: int) -> None:
-        req.started_at = time.monotonic()
-        prompt = jnp.asarray(req.prompt, jnp.int32)[None, :]
-        row_cache = self.bundle.init_cache(1, self.max_len)
-        first_tok, row_cache = self.prefill_step(
-            self.params, {"tokens": prompt}, row_cache)
-        self.cache = self._insert_row(self.cache, row_cache,
-                                      jnp.asarray(slot, jnp.int32))
-        self.slots[slot] = req
-        self.pos[slot] = len(req.prompt)
-        tok = int(jax.device_get(first_tok)[0, 0])
-        self.tokens[slot, 0] = tok
-        req.generated = [tok]
-        self._m_prefill_tokens.inc(len(req.prompt))
-        self._m_tokens.inc()
+        with trace.span("serving.admit"):
+            req.started_at = time.monotonic()
+            prompt = jnp.asarray(req.prompt, jnp.int32)[None, :]
+            row_cache = self.bundle.init_cache(1, self.max_len)
+            first_tok, row_cache = self.prefill_step(
+                self.params, {"tokens": prompt}, row_cache)
+            self.cache = self._insert_row(self.cache, row_cache,
+                                          jnp.asarray(slot, jnp.int32))
+            self.slots[slot] = req
+            self.pos[slot] = len(req.prompt)
+            tok = int(jax.device_get(first_tok)[0, 0])
+            self.tokens[slot, 0] = tok
+            req.generated = [tok]
+            self._m_prefill_tokens.inc(len(req.prompt))
+            self._m_tokens.inc()
 
     def _admit(self) -> list[Request]:
         """Fill free slots from the queue; returns requests that finished
@@ -210,26 +215,28 @@ class BatchScheduler:
     def step(self) -> list[Request]:
         """Admit waiting requests, then run ONE decode step across all
         active slots; returns the requests that finished this step."""
-        finished = self._admit()
-        active = [i for i, s in enumerate(self.slots) if s is not None]
-        if not active:
-            self._g_active.set(0)
+        with trace.span("serving.step", persist=False):
+            finished = self._admit()
+            active = [i for i, s in enumerate(self.slots) if s is not None]
+            if not active:
+                self._g_active.set(0)
+                return finished
+            next_tok, self.cache = self.decode_step(
+                self.params, self.cache, jnp.asarray(self.tokens),
+                jnp.asarray(self.pos, jnp.int32))
+            self._m_decode_steps.inc()
+            with trace.span("serving.fetch", persist=False):
+                next_host = jax.device_get(next_tok)[:, 0]
+            for i in active:
+                req = self.slots[i]
+                req.generated.append(int(next_host[i]))
+                self.pos[i] += 1
+                self.tokens[i, 0] = int(next_host[i])
+                self._m_tokens.inc()
+                if self._maybe_finish(i):
+                    finished.append(req)
+            self._g_active.set(sum(s is not None for s in self.slots))
             return finished
-        next_tok, self.cache = self.decode_step(
-            self.params, self.cache, jnp.asarray(self.tokens),
-            jnp.asarray(self.pos, jnp.int32))
-        self._m_decode_steps.inc()
-        next_host = jax.device_get(next_tok)[:, 0]
-        for i in active:
-            req = self.slots[i]
-            req.generated.append(int(next_host[i]))
-            self.pos[i] += 1
-            self.tokens[i, 0] = int(next_host[i])
-            self._m_tokens.inc()
-            if self._maybe_finish(i):
-                finished.append(req)
-        self._g_active.set(sum(s is not None for s in self.slots))
-        return finished
 
     def run(self) -> list[Request]:
         """Drain queue + slots to completion; finished in completion order."""

@@ -4,7 +4,12 @@ timelines, namespaced logging, and the stats/top CLI surface (ISSUE 6)."""
 import asyncio
 import json
 import logging
+import os
+import subprocess
+import sys
+import textwrap
 import time
+from pathlib import Path
 
 import pytest
 
@@ -138,6 +143,105 @@ class TestSpans:
         assert trace.start_timeline() is None
         trace.enable(sample=1.0)
         assert trace.start_timeline() is not None
+
+    def test_unpersisted_span_stays_out_of_timelines(self):
+        trace.enable()
+        tl = trace.start_timeline()
+        token = trace.push_sink(tl)
+        try:
+            with trace.span("step", persist=False) as step:
+                with trace.span("admit") as admit:
+                    pass
+        finally:
+            trace.pop_sink(token)
+        assert admit.parent_id == step.span_id
+        assert [s["name"] for s in tl.drain()] == ["admit"]
+        with trace.capture() as seen:
+            with trace.span("step", persist=False):
+                pass
+        assert [s.name for s in seen.spans] == ["step"]
+
+
+# ---------------------------------------------------------------------------
+# Spans on the profiler's clock
+# ---------------------------------------------------------------------------
+
+class TestProfilerExport:
+    def test_span_reaches_the_profiler_host_plane_by_its_bare_name(
+            self, tmp_path):
+        import jax
+        from jax.profiler import ProfileData
+
+        trace.enable()
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+        try:
+            with trace.span("obs.probe", pk=7):
+                time.sleep(0.001)
+        finally:
+            jax.profiler.stop_trace()
+        path, = tmp_path.glob("**/*.xplane.pb")
+        names = {e.name for plane in ProfileData.from_file(str(path)).planes
+                 if plane.name.startswith("/host:")
+                 for line in plane.lines for e in line.events}
+        assert "obs.probe" in names
+
+    def test_disabled_span_makes_no_profiler_call(self, monkeypatch):
+        import jax
+
+        made = []
+
+        class Annotation:
+            def __init__(self, name):
+                made.append(name)
+
+            def __enter__(self):
+                return self
+
+            def __exit__(self, *exc):
+                pass
+
+        monkeypatch.setattr(jax.profiler, "TraceAnnotation", Annotation)
+        trace.disable()
+        with trace.span("off", pk=1):
+            pass
+        assert made == []
+        trace.enable()
+        with trace.span("on", pk=1):
+            pass
+        assert made == ["on"]
+
+    def test_tracing_a_calcfunction_does_not_import_jax(self, tmp_path):
+        prog = textwrap.dedent("""
+            import sys
+            from repro.core import Int, calcfunction
+            from repro.engine.launch import run_get_node
+            from repro.engine.runner import Runner, set_default_runner
+            from repro.observability import trace
+            from repro.observability.timeline import load_spans
+            from repro.provenance.store import configure_store
+
+            trace.enable()
+            store = configure_store(sys.argv[1])
+            set_default_runner(Runner(store=store))
+
+            @calcfunction
+            def add(a, b):
+                return a + b
+
+            _res, node = run_get_node(add.process_class, a=Int(1), b=Int(2))
+            names = {s["name"] for s in load_spans(store, node.pk)}
+            assert "process.run" in names, names
+            print("jax" in sys.modules)
+        """)
+        src = Path(__file__).resolve().parents[1] / "src"
+        env = {**os.environ, "PYTHONPATH": str(src)}
+        proc = subprocess.run(
+            [sys.executable, "-c", prog, str(tmp_path / "p.db")],
+            capture_output=True, text=True, timeout=120, env=env)
+        assert proc.returncode == 0, proc.stderr[-2000:]
+        assert proc.stdout.split() == ["False"]
 
 
 # ---------------------------------------------------------------------------

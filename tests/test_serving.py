@@ -387,3 +387,58 @@ def test_reduced_generation_is_not_served_for_published(runner, monkeypatch):
     assert ran_small_again == 0 and ran_big_again == 0
     assert (small["stats"].value["fingerprint"]
             != big["stats"].value["fingerprint"])
+
+
+def test_serving_spans_one_admit_per_request_one_step_per_fetch():
+    from repro.observability import trace
+    from repro.observability.metrics import get_registry
+    from repro.serving.inference import get_engine, reset_engines
+
+    reset_engines()
+    eng = get_engine(ARCH, 0, reduced=True, need_len=16)
+    steps = get_registry().counter("serving.decode_steps")
+    prompts = _prompts(5)   # one more than the engine's slots
+    trace.enable()
+    try:
+        before = steps.value
+        with trace.capture() as tl:
+            eng.generate_many(prompts, 3)
+        counted = steps.value - before
+    finally:
+        trace.reset()
+    names = [s.name for s in tl.spans]
+    assert names.count("serving.admit") == len(prompts)
+    step_ids = {s.span_id for s in tl.spans if s.name == "serving.step"}
+    holding_fetch = {s.parent_id for s in tl.spans
+                     if s.name == "serving.fetch"}
+    assert holding_fetch <= step_ids
+    assert len(holding_fetch) == counted > 0
+
+
+def test_generate_timeline_persists_request_spans_not_step_spans(runner):
+    from repro.core.datatypes import ArrayData, Bool, Int, Str
+    from repro.engine.launch import run_get_node
+    from repro.observability import trace
+    from repro.observability.timeline import load_spans, render_timeline
+    from repro.serving.inference import generate, reset_engines
+
+    reset_engines()
+    trace.enable()
+    try:
+        _res, node = run_get_node(
+            generate.process_class, arch=Str(ARCH),
+            prompt=ArrayData(np.asarray([3, 5, 7, 11], np.int32)),
+            max_new_tokens=Int(4), seed=Int(0), eos_id=Int(-1),
+            reduced=Bool(True))
+    finally:
+        trace.reset()
+    spans = load_spans(runner.store, node.pk)
+    names = [s["name"] for s in spans]
+    assert names.count("serving.generate") == 1
+    assert names.count("serving.admit") == 1
+    assert not {"serving.step", "serving.fetch"} & set(names)
+    # the admission's parent is the unpersisted step: drawn as a root
+    admit = next(s for s in spans if s["name"] == "serving.admit")
+    assert admit["parent"] is not None
+    assert admit["parent"] not in {s["id"] for s in spans}
+    assert "serving.admit" in render_timeline(spans)
