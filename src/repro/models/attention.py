@@ -55,8 +55,10 @@ def make_attn_specs(cfg: ModelConfig, *, cross: bool = False) -> dict[str, Param
         specs["bk"] = ParamSpec((hkv, hd), ("kv_heads_w", "head_dim"), init="zeros")
         specs["bv"] = ParamSpec((hkv, hd), ("kv_heads_w", "head_dim"), init="zeros")
     if cfg.qk_norm:
-        specs["q_norm"] = ParamSpec((hd,), ("head_dim",), init="ones")
-        specs["k_norm"] = ParamSpec((hd,), ("head_dim",), init="ones")
+        specs["q_norm"] = ParamSpec((hd,), ("head_dim",), init="ones",
+                                    f32_at_use=True)
+        specs["k_norm"] = ParamSpec((hd,), ("head_dim",), init="ones",
+                                    f32_at_use=True)
     return specs
 
 

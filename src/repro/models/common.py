@@ -8,7 +8,10 @@ derive ``PartitionSpec`` trees for any mesh.
 Design notes
 ------------
 * Parameters are stored in ``param_dtype`` (fp32 master copies) and cast to
-  ``dtype`` (bf16) at use — the standard mixed-precision recipe.
+  ``dtype`` (bf16) at use — the standard mixed-precision recipe. A leaf the
+  forward reads in float32 instead (norm scales, the MoE router) says so in
+  its spec (``ParamSpec.f32_at_use``); serving stores every other leaf in
+  ``dtype`` once (``registry.serving_params``).
 * Homogeneous layer stacks carry a leading ``layers`` dimension and are
   executed with ``jax.lax.scan`` so the HLO contains one layer body
   regardless of depth (essential for compile time at 512-way GSPMD).
@@ -165,6 +168,9 @@ class ParamSpec:
     axes: tuple[str | None, ...]
     init: str = "normal"          # normal | zeros | ones | rglru_lambda
     scale: float = 1.0
+    # the forward reads this leaf in float32, not at the activation dtype;
+    # declared for the LM families, whose trees serving stores in ``dtype``
+    f32_at_use: bool = False
 
     def __post_init__(self):
         assert len(self.shape) == len(self.axes), (self.shape, self.axes)
@@ -189,8 +195,13 @@ def spec_axes(spec_tree: SpecTree) -> Any:
     )
 
 
-def init_params(rng: jax.Array, spec_tree: SpecTree, dtype: jnp.dtype) -> ParamTree:
-    """Materialise a parameter tree (only used for real, small runs)."""
+def init_params(rng: jax.Array, spec_tree: SpecTree, dtype: jnp.dtype,
+                keep: Callable | None = None) -> ParamTree:
+    """Materialise a parameter tree (only used for real, small runs).
+
+    ``keep(spec, leaf)``, when given, maps each leaf as it is drawn to the
+    array the tree holds, before the next one is drawn: serving keeps its
+    cast copy, so the whole tree never exists in ``dtype``."""
     leaves, treedef = jax.tree.flatten(
         spec_tree, is_leaf=lambda x: isinstance(x, ParamSpec)
     )
@@ -210,6 +221,8 @@ def init_params(rng: jax.Array, spec_tree: SpecTree, dtype: jnp.dtype) -> ParamT
             fan_in = s.shape[-2] if len(s.shape) >= 2 else s.shape[-1]
             std = s.scale / math.sqrt(max(1, fan_in))
             out.append(std * jax.random.normal(key, s.shape, dtype))
+        if keep is not None:
+            out[-1] = keep(s, out[-1])
     return jax.tree.unflatten(treedef, out)
 
 
@@ -220,6 +233,7 @@ def stacked(spec: ParamSpec, layers: int) -> ParamSpec:
         axes=("layers", *spec.axes),
         init=spec.init,
         scale=spec.scale,
+        f32_at_use=spec.f32_at_use,
     )
 
 

@@ -64,7 +64,7 @@ def make_moe_specs(cfg: ModelConfig) -> dict[str, ParamSpec]:
         ax = ("expert", "embed", "moe_ffn")
         ax_down = ("expert", "moe_ffn", "embed")
     return {
-        "router": ParamSpec((d, e), ("embed", None)),
+        "router": ParamSpec((d, e), ("embed", None), f32_at_use=True),
         "w_gate": ParamSpec((e, d, f), ax),
         "w_up": ParamSpec((e, d, f), ax),
         "w_down": ParamSpec((e, f, d), ax_down),

@@ -15,13 +15,14 @@ paper):
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
 
 from repro.models import encdec, rglru, transformer, xlstm
-from repro.models.common import ModelConfig, spec_axes, spec_shapes
+from repro.models.common import ModelConfig, ParamSpec, spec_axes, spec_shapes
 
 LM_FAMILIES = ("dense", "moe", "vlm")
 
@@ -72,6 +73,20 @@ class ModelBundle:
         from repro.models.common import init_params
         return init_params(rng, self.specs, self.cfg.weight_dtype)
 
+    def init_serving_params(self, rng: jax.Array):
+        """``serving_params(cfg, init_params(rng))``, each leaf cast as it
+        is drawn, so the device never holds both trees."""
+        from repro.models.common import init_params
+        _check_served(self.cfg)
+
+        def keep(spec, leaf):
+            # wait for the cast: with asynchronous dispatch the host would
+            # draw the next leaves while this one's float32 buffers are
+            # still held, and several float32 leaves would be live at once
+            return jax.block_until_ready(_serving_leaf(self.cfg, spec, leaf))
+
+        return init_params(rng, self.specs, self.cfg.weight_dtype, keep=keep)
+
     # -- input specs (ShapeDtypeStruct stand-ins, no allocation) -------------
     def batch_struct(self, cell: ShapeCell) -> dict[str, jax.ShapeDtypeStruct]:
         cfg = self.cfg
@@ -118,6 +133,37 @@ class ModelBundle:
             return False, "full attention is O(S^2); long_500k assigned to " \
                           "sub-quadratic families only (see DESIGN.md)"
         return True, ""
+
+
+# ---------------------------------------------------------------------------
+# Serving storage
+# ---------------------------------------------------------------------------
+
+def _check_served(cfg: ModelConfig) -> None:
+    if cfg.family not in LM_FAMILIES:
+        raise ValueError(f"serving parameters are declared for {LM_FAMILIES}, "
+                         f"not {cfg.family!r}")
+
+
+def _serving_leaf(cfg: ModelConfig, spec: ParamSpec, leaf: jax.Array):
+    dt = cfg.activation_dtype
+    if spec.f32_at_use or leaf.dtype == dt:
+        return leaf
+    return leaf.astype(dt)
+
+
+def serving_params(cfg: ModelConfig, params: Any) -> Any:
+    """The tree a served step reads: every leaf the forward uses at
+    ``cfg.activation_dtype`` (embedding, head, attention and MLP/expert
+    matrices and biases, projector) stored in it; the leaves it reads in
+    float32 (``ParamSpec.f32_at_use``: norm scales, the MoE router) as
+    given. The forward casts each matrix at use, so it multiplies the same
+    rounded values either way, but no longer converts every weight in
+    every step. A leaf already in its dtype is passed through, not copied."""
+    _check_served(cfg)
+    return jax.tree.map(functools.partial(_serving_leaf, cfg),
+                        transformer.make_lm_specs(cfg), params,
+                        is_leaf=lambda x: isinstance(x, ParamSpec))
 
 
 # ---------------------------------------------------------------------------

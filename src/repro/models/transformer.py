@@ -36,8 +36,10 @@ from repro.models.common import (
 
 def make_layer_specs(cfg: ModelConfig) -> dict[str, Any]:
     specs: dict[str, Any] = {
-        "ln_attn": ParamSpec((cfg.d_model,), ("embed",), init="ones"),
-        "ln_mlp": ParamSpec((cfg.d_model,), ("embed",), init="ones"),
+        "ln_attn": ParamSpec((cfg.d_model,), ("embed",), init="ones",
+                             f32_at_use=True),
+        "ln_mlp": ParamSpec((cfg.d_model,), ("embed",), init="ones",
+                            f32_at_use=True),
         "attn": attn.make_attn_specs(cfg),
     }
     if cfg.family == "moe":
@@ -52,7 +54,8 @@ def make_lm_specs(cfg: ModelConfig) -> dict[str, Any]:
     specs: dict[str, Any] = {
         "embedding": ParamSpec((vp, cfg.d_model), ("vocab", "embed")),
         "layers": stack_specs(make_layer_specs(cfg), cfg.num_layers),
-        "ln_final": ParamSpec((cfg.d_model,), ("embed",), init="ones"),
+        "ln_final": ParamSpec((cfg.d_model,), ("embed",), init="ones",
+                              f32_at_use=True),
     }
     if not cfg.tie_embeddings:
         specs["lm_head"] = ParamSpec((cfg.d_model, vp), ("embed", "vocab"))

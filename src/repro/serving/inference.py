@@ -65,10 +65,13 @@ class ServingEngine:
         self.arch, self.seed = arch, int(seed)
         self.cfg = _serving_config(arch, bool(reduced), decode_impl)
         self.bundle = build(self.cfg)
-        self.params = self.bundle.init_params(jax.random.PRNGKey(int(seed)))
-        self.scheduler = BatchScheduler(self.bundle, self.params,
-                                        batch_size=batch_size,
-                                        max_len=max_len, eos_id=eos_id)
+        # drawn and stored in the compute dtype leaf by leaf: no float32
+        # copy of the weights stays on the device
+        self.scheduler = BatchScheduler(
+            self.bundle,
+            self.bundle.init_serving_params(jax.random.PRNGKey(int(seed))),
+            batch_size=batch_size, max_len=max_len, eos_id=eos_id)
+        self.params = self.scheduler.params
         self._next_rid = 0
 
     def generate_many(self, prompts: list[list[int]],

@@ -15,6 +15,9 @@ building blocks (also lowered by the dry-run for ``decode_*`` cells).
   flash-decode kernel's scalar-prefetch lengths directly;
 * **eviction** — EOS, ``max_new_tokens`` or cache exhaustion frees the
   slot for the next queued request mid-flight;
+* **weights** — every tree given to the scheduler is stored in the
+  compute dtype (``registry.serving_params``), so the step converts no
+  weight; ``serving.weight_bytes`` reads the tree's bytes;
 * **metrics** — per-request latency and token counts land in the
   process-wide observability registry (``serving.*``).
 * **spans** — ``serving.admit`` per request; ``serving.step`` and its
@@ -38,7 +41,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from repro.models.registry import LM_FAMILIES, ModelBundle
+from repro.models.registry import LM_FAMILIES, ModelBundle, serving_params
 from repro.observability import trace
 from repro.observability.metrics import get_registry
 
@@ -100,6 +103,7 @@ class BatchScheduler:
                 f"not {bundle.cfg.family!r} (recurrent families have no "
                 f"per-slot cache rows to splice)")
         self.bundle = bundle
+        self._g_weight_bytes = get_registry().gauge("serving.weight_bytes")
         self.params = params
         self.batch_size = batch_size
         self.max_len = max_len
@@ -135,6 +139,19 @@ class BatchScheduler:
         self._g_queue = reg.gauge("serving.queue_depth")
         self._h_latency = reg.histogram("serving.request_seconds")
         self._m_rejected = reg.counter("serving.rejected")
+
+    @property
+    def params(self) -> Any:
+        """The tree the steps read. Assigning one stores its leaves used at
+        the compute dtype in that dtype (a no-op on a tree that already
+        is) and sets ``serving.weight_bytes``."""
+        return self._params
+
+    @params.setter
+    def params(self, tree: Any) -> None:
+        self._params = serving_params(self.bundle.cfg, tree)
+        self._g_weight_bytes.set(sum(
+            leaf.nbytes for leaf in jax.tree.leaves(self._params)))
 
     # -- admission -----------------------------------------------------------
     def submit(self, req: Request) -> None:

@@ -7,6 +7,7 @@ main pytest process keeps its single CPU device).
 """
 
 import json
+import re
 import subprocess
 import sys
 import textwrap
@@ -442,3 +443,193 @@ def test_generate_timeline_persists_request_spans_not_step_spans(runner):
     assert admit["parent"] is not None
     assert admit["parent"] not in {s["id"] for s in spans}
     assert "serving.admit" in render_timeline(spans)
+
+
+# ---------------------------------------------------------------------------
+# weights stored in the compute dtype
+# ---------------------------------------------------------------------------
+
+#: one reduced config per served family; qwen3-4b adds the q/k norms
+SERVED_ARCHS = ("qwen2-0.5b", "qwen3-4b", "moonshot-v1-16b-a3b",
+                "llava-next-34b")
+#: leaves the forward reads in float32, by name (independent of the specs)
+F32_LEAF_NAMES = ("ln_attn", "ln_mlp", "ln_final", "q_norm", "k_norm",
+                  "router")
+
+
+def _named_leaves(tree):
+    return [(str(getattr(path[-1], "key", path[-1])), leaf)
+            for path, leaf in jax.tree_util.tree_flatten_with_path(tree)[0]]
+
+
+def _served_model(arch, **over):
+    """Reduced config and float32 masters whose norm scales are off 1, so
+    a norm scale rounded to bfloat16 would show in the logits."""
+    cfg = reduced_config(arch).replace(**over)
+    bundle = build(cfg)
+    params = bundle.init_params(jax.random.PRNGKey(3))
+    rng = np.random.default_rng(3)
+
+    def jitter(path, leaf):
+        if str(getattr(path[-1], "key", "")) not in F32_LEAF_NAMES:
+            return leaf
+        return leaf * (1 + 0.05 * rng.standard_normal(leaf.shape,
+                                                      np.float32))
+
+    return cfg, bundle, jax.tree_util.tree_map_with_path(jitter, params)
+
+
+def test_served_archs_cover_every_served_family():
+    from repro.models.registry import LM_FAMILIES
+
+    assert {reduced_config(a).family for a in SERVED_ARCHS} == \
+        set(LM_FAMILIES)
+
+
+@pytest.mark.parametrize("arch", SERVED_ARCHS)
+def test_serving_params_give_the_float32_masters_logits(arch):
+    """The forward rounds each matrix to bfloat16 at use; storing it
+    rounded gives the same bits to multiply, so the logits are equal bit
+    for bit, in prefill and in the decode step after it."""
+    from repro.models.registry import serving_params
+
+    cfg, bundle, params = _served_model(arch)
+    assert cfg.activation_dtype == jnp.bfloat16
+    served = serving_params(cfg, params)
+    n = 8
+    batch = {"tokens": jnp.asarray(RNG.integers(1, cfg.vocab_size, (1, n)),
+                                   jnp.int32)}
+    if cfg.family == "vlm":
+        batch["patches"] = jnp.asarray(
+            RNG.normal(size=(1, cfg.num_patches, cfg.d_model)), jnp.bfloat16)
+        n += cfg.num_patches
+    prefill, decode = jax.jit(bundle.prefill_fn), jax.jit(bundle.decode_fn)
+    out = {}
+    for name, tree in (("masters", params), ("served", served)):
+        logits, cache = prefill(tree, batch, bundle.init_cache(1, 32))
+        tok = jnp.argmax(logits[:, -1:, :cfg.vocab_size], -1).astype(jnp.int32)
+        step, _ = decode(tree, cache, tok, jnp.asarray([n], jnp.int32))
+        out[name] = (np.asarray(logits, np.float32),
+                     np.asarray(step, np.float32))
+    np.testing.assert_array_equal(out["served"][0], out["masters"][0])
+    np.testing.assert_array_equal(out["served"][1], out["masters"][1])
+
+
+@pytest.mark.parametrize("arch", SERVED_ARCHS)
+def test_serving_params_keep_float32_leaves(arch):
+    from repro.models.registry import serving_params
+
+    cfg, _, params = _served_model(arch)
+    for name, leaf in _named_leaves(serving_params(cfg, params)):
+        want = jnp.float32 if name in F32_LEAF_NAMES else jnp.bfloat16
+        assert leaf.dtype == want, name
+
+
+@pytest.mark.parametrize("arch", SERVED_ARCHS)
+def test_serving_params_change_nothing_at_float32(arch):
+    from repro.models.registry import serving_params
+
+    cfg, _, params = _served_model(arch, dtype="float32")
+    served = serving_params(cfg, params)
+    assert jax.tree.structure(served) == jax.tree.structure(params)
+    for a, b in zip(jax.tree.leaves(served), jax.tree.leaves(params)):
+        assert a is b
+
+
+def test_serving_params_reject_an_unserved_family():
+    from repro.models.registry import serving_params
+
+    cfg = reduced_config("recurrentgemma-2b")
+    params = build(cfg).init_params(jax.random.PRNGKey(0))
+    with pytest.raises(ValueError, match="serving parameters"):
+        serving_params(cfg, params)
+
+
+def test_init_serving_params_is_the_cast_of_init_params():
+    from repro.models.registry import serving_params
+
+    cfg = reduced_config("qwen2-0.5b")
+    bundle = build(cfg)
+    drawn = bundle.init_serving_params(jax.random.PRNGKey(3))
+    want = serving_params(cfg, bundle.init_params(jax.random.PRNGKey(3)))
+    assert jax.tree.structure(drawn) == jax.tree.structure(want)
+    for a, b in zip(jax.tree.leaves(drawn), jax.tree.leaves(want)):
+        assert a.dtype == b.dtype
+        np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                      np.asarray(b, np.float32))
+
+
+_F32_TO_BF16 = re.compile(r"stablehlo\.convert %\S+ : "
+                          r"\(tensor<([0-9x]+)xf32>\) -> tensor<[0-9x]+xbf16>")
+
+
+def _weight_converts(text, shapes):
+    return {s for s in _F32_TO_BF16.findall(text) if s in shapes}
+
+
+def test_step_programs_convert_no_weight():
+    """Lowered with the scheduler's own tree, neither step converts a
+    weight (stacked, or one layer's slice inside the scan); lowered with
+    the float32 masters, both convert every one of them."""
+    # six query heads over two KV heads: no activation of either step
+    # shares a weight's shape, so a convert by shape is a weight's
+    cfg, bundle, params = _served_model("qwen2-0.5b", num_heads=6,
+                                        decode_impl="pallas")
+    sched = BatchScheduler(bundle, params, batch_size=3, max_len=64)
+    shapes = set()
+    for name, leaf in _named_leaves(params):
+        if name not in F32_LEAF_NAMES:
+            shapes.add("x".join(map(str, leaf.shape)))
+            shapes.add("x".join(map(str, leaf.shape[1:])))
+    tokens = jnp.zeros((3, 1), jnp.int32)
+    pos = jnp.zeros(3, jnp.int32)
+    prompt = {"tokens": jnp.zeros((1, 7), jnp.int32)}
+
+    def lowered(tree):
+        return (sched.decode_step.lower(tree, sched.cache, tokens,
+                                        pos).as_text(),
+                sched.prefill_step.lower(tree, prompt,
+                                         bundle.init_cache(1, 64)).as_text())
+
+    for text in lowered(sched.params):
+        assert _weight_converts(text, shapes) == set()
+    per_leaf = {"x".join(map(str, leaf.shape[1:] if name != "embedding"
+                             else leaf.shape))
+                for name, leaf in _named_leaves(params)
+                if name not in F32_LEAF_NAMES}
+    for text in lowered(params):
+        assert _weight_converts(text, shapes) >= per_leaf
+
+
+def test_assigned_tree_is_stored_cast_and_gauged():
+    """The benchmark's pattern, ``eng.params = eng.scheduler.params =
+    tree``: a float32 tree comes out cast, a cast tree passes as it is
+    (its buffers, so nothing recompiles), and the gauge reads the bytes."""
+    from repro.observability.metrics import get_registry
+
+    cfg, bundle, params = _served_model("qwen2-0.5b")
+    gauge = get_registry().gauge("serving.weight_bytes")
+    f32_bytes = sum(leaf.nbytes for leaf in jax.tree.leaves(params))
+    sched = BatchScheduler(bundle, bundle.init_serving_params(
+        jax.random.PRNGKey(0)), batch_size=1, max_len=16)
+    sched.params = params
+    served = sched.params
+    for name, leaf in _named_leaves(served):
+        want = jnp.float32 if name in F32_LEAF_NAMES else jnp.bfloat16
+        assert leaf.dtype == want, name
+    nbytes = sum(leaf.nbytes for leaf in jax.tree.leaves(served))
+    assert gauge.value == nbytes < 0.6 * f32_bytes
+    sched.params = served
+    for a, b in zip(jax.tree.leaves(sched.params), jax.tree.leaves(served)):
+        assert a is b
+    assert gauge.value == nbytes
+
+
+def test_engine_keeps_no_float32_weights():
+    from repro.serving.inference import ServingEngine
+
+    eng = ServingEngine(ARCH, 0, 32, reduced=True, decode_impl="direct")
+    assert eng.params is eng.scheduler.params
+    for name, leaf in _named_leaves(eng.params):
+        want = jnp.float32 if name in F32_LEAF_NAMES else jnp.bfloat16
+        assert leaf.dtype == want, name
