@@ -1,12 +1,13 @@
 """Arithmetic shared by the metric readers in ``bench/metrics/``. Each
-reader returns ``None`` when its run holds nothing to read."""
+reader returns ``None`` when its run holds nothing to read. Useful work
+is counted by the run's architecture module (``run.arch``)."""
 
 from __future__ import annotations
 
 import bisect
 import math
 
-from benchlib import flops, xtrace
+from benchlib import xtrace
 
 DECODE_STEP = "jit_serve_step"
 KERNEL = 'custom_call_target="tpu_custom_call"'
@@ -64,10 +65,11 @@ def _misses(run):
 
 def decode_attn_roofline(run):
     """Least time the chip needs for the decode-attention kernel's useful
-    work (the active request's keys and values, at the model's own KV
-    heads), over the kernel's time in the trace. Decode attention reads
-    every cached byte once for a few operations per byte, so the byte
-    bound is the larger one at these shapes."""
+    work (the active request's cache in every layer that holds attention,
+    as the architecture module counts it), over the kernel's time in the
+    trace. Decode attention reads every cached byte once for a few
+    operations per byte, so the byte bound is the larger one at these
+    shapes."""
     if run.trace is None or run.peaks is None:
         return None
     steps = _modules(run, DECODE_STEP)
@@ -85,9 +87,10 @@ def decode_attn_roofline(run):
     ops = nbytes = 0
     for c in _misses(run):
         for i in range(c.new_tokens - 1):
-            o, b = flops.decode_attention_work(run.model, c.prompt_len + 1 + i)
-            ops += o * run.model["num_hidden_layers"]
-            nbytes += b * run.model["num_hidden_layers"]
+            o, b = run.arch.decode_attention_work(run.model,
+                                                  c.prompt_len + 1 + i)
+            ops += o
+            nbytes += b
     if kernel_ns <= 0 or ops == 0:
         return None
     least = max(ops / run.peaks["bf16_flops"],
@@ -100,7 +103,7 @@ def mfu(run):
     window, over the window, over the chip's bf16 peak."""
     if run.trace is None or run.peaks is None:
         return None
-    work = sum(flops.generate_flops(run.model, c.prompt_len, c.new_tokens)
+    work = sum(run.arch.generate_flops(run.model, c.prompt_len, c.new_tokens)
                for c in _misses(run))
     if not work:
         return None

@@ -2,14 +2,32 @@
 
 * ``BENCHMARK.json`` at the root: cells, metrics, bounds;
 * ``bench/configs/<config>.json``: the model configuration as it runs;
-* ``bench/refs/<reference>.py``: its plain reference, named by the config;
+* ``bench/refs/<reference>.py``: its architecture module, named by the
+  config (below);
 * ``bench/traffic/<mix>.json``: a traffic mix for ``benchlib.traffic``;
 * ``bench/entries/<entry>.py``: the code that runs the entry point a mix names;
 * ``bench/metrics/<metric>.py``: the reader of one metric;
 * ``bench/limits/<cell>.json``: the limits of the cell's correctness check.
 
 Adding a configuration, mix, metric or cell is adding files; no file that
-exists has to change.
+exists has to change. The architecture module is the one place that knows
+the model's architecture; ``m`` is the configuration file's dictionary:
+
+* ``logits_at(m, seed, tokens, rows, precision="f32")``: the plain float32
+  reference's logits at (sequence, position) ``rows`` of ``tokens``, from
+  the seeded weights; ``precision="fp8"`` is the control;
+* ``leaf_specs(m)``: every weight leaf of the program's tree by its dotted
+  path, as a ``benchlib.weights.Leaf`` (per-layer shape, standard
+  deviation, mean, the depth it is stacked over, its vocabulary axis);
+  ``benchlib.weights`` draws and writes them;
+* ``check_program(cfg, m)``: the fields of the program's ``ModelConfig``
+  that differ from the file, as name -> (program's, file's); the run stops
+  before any call where there is one;
+* ``generate_flops(m, prompt, new_tokens)``: model operations of one
+  request, prefill and decode;
+* ``decode_attention_work(m, context)``: (operations, bytes) of decode
+  attention for one sequence over ``context`` positions, summed over every
+  layer that holds attention.
 """
 
 from __future__ import annotations

@@ -26,13 +26,12 @@ def readings(workload: str, seed: int, seconds: float,
              require_chip: bool = True, root=bench_run.ROOT) -> dict:
     from entries.generate import gap
 
-    spec, run, device = bench_run.run_cell(root, workload, seed, seconds,
-                                           False, require_chip,
-                                           time.monotonic())
+    _spec, run, device = bench_run.run_cell(root, workload, seed, seconds,
+                                            False, require_chip,
+                                            time.monotonic())
     tokens, rows, served = run.check_batch
-    ref = spec.reference(run.model["reference"])
-    exact = ref.logits_at(run.model, seed, tokens, rows)
-    low = ref.logits_at(run.model, seed, tokens, rows, "fp8")
+    exact = run.arch.logits_at(run.model, seed, tokens, rows)
+    low = run.arch.logits_at(run.model, seed, tokens, rows, "fp8")
     control_checks = {**run.checks, "logit_gap": {
         **run.checks["logit_gap"], "value": gap(exact, low.argmax(axis=-1))}}
     return {"workload": workload, "seed": seed, "device": device["kind"],

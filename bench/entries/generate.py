@@ -28,15 +28,6 @@ from benchlib import traffic, weights
 #: trace starts this long, plus the longest call so far, before the window
 #: closes, so that it holds a call however long calls are
 TRACE_SECONDS = 4.0
-#: ModelConfig field -> configuration-file key, for the program check
-_FIELDS = {"num_layers": "num_hidden_layers", "d_model": "hidden_size",
-           "num_heads": "num_attention_heads",
-           "num_kv_heads": "num_key_value_heads", "hd": "head_dim",
-           "d_ff": "intermediate_size", "vocab_size": "vocab_size",
-           "qkv_bias": "qkv_bias", "tie_embeddings": "tie_word_embeddings",
-           "rope_theta": "rope_theta", "norm_eps": "rms_norm_eps"}
-_SCALARS = {"embedding_multiplier": 1.0, "residual_multiplier": 1.0,
-            "attention_multiplier": 0.0, "logits_scaling": 1.0}
 
 
 @dataclasses.dataclass
@@ -56,6 +47,8 @@ class Call:
 @dataclasses.dataclass
 class Run:
     model: dict
+    #: the configuration's architecture module, ``bench/refs/<reference>.py``
+    arch: object
     setup_s: float
     window_s: float
     calls: list[Call]
@@ -74,10 +67,11 @@ class Run:
     peaks: dict | None = None
 
 
-def program_config(m: dict):
+def program_config(m: dict, ref):
     """The program's configuration for this cell, with the file's
-    ``program.overrides`` set on the registered config, checked against
-    every size the file states."""
+    ``program.overrides`` set on the registered config. Raises where the
+    architecture module's ``check_program`` finds it differs from the file,
+    or its dtypes differ from those ``program`` states."""
     import importlib
 
     from repro.configs import _module_name, get_config, reduced_config
@@ -89,18 +83,10 @@ def program_config(m: dict):
         mod.CONFIG = mod.CONFIG.replace(**prog["overrides"])
     cfg = reduced_config(arch) if prog.get("reduced") else get_config(arch)
     cfg = cfg.replace(decode_impl=prog["decode_impl"])
-    wrong = {f: (getattr(cfg, f), m[k]) for f, k in _FIELDS.items()
-             if getattr(cfg, f) != m[k]}
-    wrong.update({f: (getattr(cfg, f), m.get(f, d))
-                  for f, d in _SCALARS.items()
-                  if getattr(cfg, f) != m.get(f, d)})
+    wrong = dict(ref.check_program(cfg, m))
     for f in ("dtype", "param_dtype", "kv_cache_dtype"):
         if getattr(cfg, f) != prog[f]:
             wrong[f] = (getattr(cfg, f), prog[f])
-    if (cfg.family, cfg.mlp_act, cfg.qk_norm, cfg.attn_softcap,
-            cfg.use_rope) != ("dense", "silu", False, 0.0, True):
-        wrong["architecture"] = (cfg.family, cfg.mlp_act, cfg.qk_norm,
-                                 cfg.attn_softcap, cfg.use_rope)
     if wrong:
         raise ValueError(f"program config of {arch} differs from the "
                          f"configuration file: {wrong}")
@@ -146,7 +132,8 @@ def run(spec, cell: dict, seed: int, seconds: float, trace: bool,
     m = spec.config(cell["config"])
     mix = spec.traffic(cell["traffic"])
     limits = spec.limits(cell["name"])
-    cfg = program_config(m)
+    ref = spec.reference(m["reference"])
+    cfg = program_config(m, ref)
     arch, reduced = m["program"]["arch"], bool(m["program"].get("reduced"))
     vocab, eos = m["vocab_size"], int(mix["eos_id"])
     buckets = {_bucket(int(p) + int(mix["new_tokens"]))
@@ -168,7 +155,8 @@ def run(spec, cell: dict, seed: int, seconds: float, trace: bool,
     jax.block_until_ready(eng.params)
     phases = {"imports": t - t_start, "engine": time.monotonic() - t}
     t = time.monotonic()
-    eng.params = eng.scheduler.params = weights.overwrite(eng.params, m, seed)
+    eng.params = eng.scheduler.params = weights.overwrite(
+        eng.params, ref.leaf_specs(m), seed)
     jax.block_until_ready(eng.params)
     phases["weights"] = time.monotonic() - t
 
@@ -259,10 +247,9 @@ def run(spec, cell: dict, seed: int, seconds: float, trace: bool,
     worst = float("inf")
     if batch is not None:
         tokens, rows, served = batch
-        ref = spec.reference(m["reference"])
         worst = gap(ref.logits_at(m, seed, tokens, rows), served)
     checks["logit_gap"] = {"value": worst, "limit": limits["logit_gap"]}
-    run = Run(m, setup_s, w1 - w0, calls, counters, peak, checks, failed,
+    run = Run(m, ref, setup_s, w1 - w0, calls, counters, peak, checks, failed,
               check_batch=batch, spans=spans)
     if tracing is not None:
         from benchlib import xtrace

@@ -41,23 +41,35 @@ def make_tree(tmp: Path, gap_limit: float = 0.5) -> Path:
     root = tmp / "root"
     shutil.copytree(BENCH, root / "bench",
                     ignore=shutil.ignore_patterns("__pycache__", "tests"))
-    doc = json.loads((ROOT / "BENCHMARK.json").read_text())
-    (root / "bench" / "configs" / "tiny.json").write_text(json.dumps(TINY))
-    cells = []
+    shutil.copy(ROOT / "BENCHMARK.json", root / "BENCHMARK.json")
     for mix, body in MIXES.items():
         (root / "bench" / "traffic" / f"{mix}.json").write_text(
             json.dumps(body))
-        name = f"t.tiny.{mix}"
-        (root / "bench" / "limits" / f"{name}.json").write_text(
+    add_config(root, TINY, gap_limit)
+    return root
+
+
+def add_config(root: Path, config: dict, gap_limit: float = 0.5) -> list[str]:
+    """Adds ``config`` to a tree that ``make_tree`` made, and a cell
+    ``t.<name>.<mix>`` of it under each tiny mix, as new files and new
+    entries of ``BENCHMARK.json``; returns the cells' names."""
+    name = config["name"]
+    (root / "bench" / "configs" / f"{name}.json").write_text(
+        json.dumps(config))
+    cells = []
+    for mix in MIXES:
+        cell = f"t.{name}.{mix}"
+        (root / "bench" / "limits" / f"{cell}.json").write_text(
             json.dumps({"logit_gap": gap_limit}))
-        cells.append({"name": name, "config": "tiny", "traffic": mix,
+        cells.append({"name": cell, "config": name, "traffic": mix,
                       "chips": 1, "why": "CPU test"})
+    doc = json.loads((root / "BENCHMARK.json").read_text())
     doc["workloads"] += cells
     for m in doc["end_to_end"] + doc["per_layer"]:
         if "workloads" in m:
             m["workloads"] += [c["name"] for c in cells]
     (root / "BENCHMARK.json").write_text(json.dumps(doc))
-    return root
+    return [c["name"] for c in cells]
 
 
 def run(root: Path, cell: str, seed: int = 5, seconds: float = 2.0,
