@@ -16,6 +16,7 @@ ARCH_IDS = [
     "qwen2-0.5b",
     "grok-1-314b",
     "moonshot-v1-16b-a3b",
+    "moonlight-16b-a3b",
     "recurrentgemma-2b",
     "llava-next-34b",
     "whisper-large-v3",
@@ -61,6 +62,13 @@ def reduced_config(arch_id: str) -> ModelConfig:
     )
     if cfg.family == "moe":
         kw.update(num_experts=4, num_experts_per_tok=2)
+    if cfg.kv_lora_rank:
+        # latent attention, one dense layer, then expert layers that hold
+        # 4 of 8 routed experts (the second half), with shared experts
+        kw.update(num_layers=3, num_kv_heads=4, kv_lora_rank=32,
+                  qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+                  moe_d_ff=64, shared_d_ff=128, num_experts=8,
+                  num_experts_per_tok=3, experts_held=4, expert_offset=4)
     if cfg.family == "hybrid":
         kw.update(num_layers=3, d_rnn=128, local_window=32)
     if cfg.family == "ssm":

@@ -6,6 +6,13 @@ grid = (B, Hkv, nKV) with the KV axis innermost/sequential. All G query
 heads of a KV group are processed together so the cache is read ONCE per
 group (the GQA arithmetic-intensity win). Per-row cache lengths arrive via
 scalar prefetch (SMEM), letting one batch mix ragged sequence lengths.
+
+``latent_decode_attention`` is the same recurrence against a latent cache
+(MLA's absorbed decode): one (B, Smax, C) array of rows shared by every
+head, each row read once for all heads, scored over all C columns and
+summed as values over its first ``value_dim``. Blocks past a row's length
+map to its last needed block, so they are neither fetched again nor
+computed.
 """
 
 from __future__ import annotations
@@ -105,3 +112,84 @@ def decode_attention_kernel(q, k, v, kv_len, *, scale, block_kv, interpret):
         name="decode_attention",
     )(lens, qg, kt, vt)
     return out.reshape(b, h, hd)
+
+
+def _latent_kernel(lens_ref, q_ref, c_ref, o_ref, m_scr, l_scr, acc_scr, *,
+                   scale, nkv, bkv, dv):
+    ib = pl.program_id(0)
+    ik = pl.program_id(1)
+
+    @pl.when(ik == 0)
+    def _init():
+        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    kv_len = lens_ref[ib]
+
+    @pl.when((ik * bkv) < kv_len)
+    def _compute():
+        rows = c_ref[0]                                   # (bkv, C)
+        logits = lax.dot_general(q_ref[0], rows, (((1,), (1,)), ((), ())),
+                                 preferred_element_type=jnp.float32) * scale
+        pos = ik * bkv + lax.broadcasted_iota(jnp.int32, (1, bkv), 1)
+        logits = jnp.where(pos < kv_len, logits, NEG_INF)  # (H, bkv)
+        m_prev = m_scr[...]
+        m_new = jnp.maximum(m_prev, jnp.max(logits, axis=1))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(logits - m_new[:, None])
+        l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=1)
+        pv = lax.dot_general(p.astype(rows.dtype), rows[:, :dv],
+                             (((1,), (0,)), ((), ())),
+                             preferred_element_type=jnp.float32)
+        acc_scr[...] = acc_scr[...] * alpha[:, None] + pv
+        m_scr[...] = m_new
+
+    @pl.when(ik == nkv - 1)
+    def _finalize():
+        o_ref[0] = (acc_scr[...] /
+                    jnp.maximum(l_scr[...], 1e-30)[:, None]
+                    ).astype(o_ref.dtype)
+
+
+def latent_decode_attention_kernel(q, cache, kv_len, *, scale, value_dim,
+                                   block_kv, interpret):
+    """q: (B, H, C); cache: (B, Smax, C); kv_len: (B,) int32.
+    Returns (B, H, value_dim)."""
+    b, h, c = q.shape
+    smax = cache.shape[1]
+    bkv = min(block_kv, smax)
+    while smax % bkv:
+        bkv //= 2
+    nkv = smax // bkv
+    lens = jnp.broadcast_to(jnp.asarray(kv_len, jnp.int32), (b,))
+
+    def rows(ib, ik, lens):
+        # past the row's length, stay on its last needed block
+        last = jnp.maximum((lens[ib] + bkv - 1) // bkv - 1, 0)
+        return ib, jnp.minimum(ik, last), 0
+
+    kernel = functools.partial(_latent_kernel, scale=scale, nkv=nkv, bkv=bkv,
+                               dv=value_dim)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(b, nkv),
+        in_specs=[
+            pl.BlockSpec((1, h, c), lambda ib, ik, lens: (ib, 0, 0)),
+            pl.BlockSpec((1, bkv, c), rows),
+        ],
+        out_specs=pl.BlockSpec((1, h, value_dim),
+                               lambda ib, ik, lens: (ib, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((h,), jnp.float32),
+            pltpu.VMEM((h,), jnp.float32),
+            pltpu.VMEM((h, value_dim), jnp.float32),
+        ],
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, h, value_dim), q.dtype),
+        interpret=interpret,
+        name="latent_decode_attention",
+    )(lens, q, cache)

@@ -20,3 +20,15 @@ def decode_attention(q, k, v, kv_len, *, scale: float | None = None,
     return K.decode_attention_kernel(q, k, v, kv_len, scale=float(scale),
                                      block_kv=int(block_kv),
                                      interpret=bool(interpret))
+
+
+def latent_decode_attention(q, cache, kv_len, *, scale: float,
+                            value_dim: int, block_kv: int = 512,
+                            interpret: bool | None = None) -> jax.Array:
+    """q: (B, H, C); cache: (B, Smax, C) latent rows; kv_len: (B,).
+    Scores over all C columns, values from the first ``value_dim``."""
+    if interpret is None:
+        interpret = interpret_default()
+    return K.latent_decode_attention_kernel(
+        q, cache, kv_len, scale=float(scale), value_dim=int(value_dim),
+        block_kv=int(block_kv), interpret=bool(interpret))

@@ -28,3 +28,20 @@ def decode_attention_ref(q: jax.Array, k: jax.Array, v: jax.Array,
     p = jax.nn.softmax(logits, axis=-1)
     out = jnp.einsum("bkgt,btkh->bkgh", p.astype(v.dtype), v)
     return out.reshape(b, h, hd).astype(q.dtype)
+
+
+def latent_decode_attention_ref(q: jax.Array, cache: jax.Array,
+                                kv_len: jax.Array | int, *, scale: float,
+                                value_dim: int) -> jax.Array:
+    """q: (B, H, C); cache: (B, Smax, C) latent rows shared by all heads;
+    scores over all C columns, values from the first ``value_dim``."""
+    smax = cache.shape[1]
+    logits = jnp.einsum("bhc,btc->bht", q.astype(jnp.float32),
+                        cache.astype(jnp.float32)) * scale
+    lens = jnp.broadcast_to(jnp.asarray(kv_len), (q.shape[0],))
+    ok = jnp.arange(smax)[None, :] < lens[:, None]
+    logits = jnp.where(ok[:, None, :], logits, NEG_INF)
+    p = jax.nn.softmax(logits, axis=-1)
+    out = jnp.einsum("bht,btr->bhr", p,
+                     cache[..., :value_dim].astype(jnp.float32))
+    return out.astype(q.dtype)

@@ -43,6 +43,8 @@ NEG_INF = -2.0e30
 # ---------------------------------------------------------------------------
 
 def make_attn_specs(cfg: ModelConfig, *, cross: bool = False) -> dict[str, ParamSpec]:
+    if cfg.kv_lora_rank:
+        return make_mla_specs(cfg)
     d, h, hkv, hd = cfg.d_model, cfg.num_heads, cfg.num_kv_heads, cfg.hd
     specs: dict[str, ParamSpec] = {
         "wq": ParamSpec((d, h, hd), ("embed", "heads", "head_dim")),
@@ -141,7 +143,7 @@ def _direct_attention(cfg: ModelConfig, q, k, v, q_pos, k_pos, *, causal,
                                  kv_len=kv_len)[None, None, None]
     probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
     out = jnp.einsum("bkgst,btkh->bskgh", probs, v)
-    return out.reshape(b, sq, h, hd)
+    return out.reshape(b, sq, h, v.shape[-1])
 
 
 def _chunked_attention(cfg: ModelConfig, q, k, v, q_pos, k_pos, *, causal,
@@ -178,11 +180,11 @@ def _chunked_attention(cfg: ModelConfig, q, k, v, q_pos, k_pos, *, causal,
 
     m0 = jnp.full((b, hkv, g, sq), NEG_INF, jnp.float32)
     l0 = jnp.zeros((b, hkv, g, sq), jnp.float32)
-    a0 = jnp.zeros((b, hkv, g, sq, hd), jnp.float32)
+    a0 = jnp.zeros((b, hkv, g, sq, v.shape[-1]), jnp.float32)
     (m, l, acc), _ = lax.scan(body, (m0, l0, a0), jnp.arange(nblk))
     out = acc / jnp.maximum(l[..., None], 1e-30)
-    # (b, hkv, g, sq, hd) -> (b, sq, h, hd)
-    out = jnp.moveaxis(out, 3, 1).reshape(b, sq, h, hd)
+    # (b, hkv, g, sq, hd_v) -> (b, sq, h, hd_v)
+    out = jnp.moveaxis(out, 3, 1).reshape(b, sq, h, v.shape[-1])
     return out.astype(q.dtype)
 
     # NOTE: scale was already folded into qg before the scan.
@@ -220,6 +222,8 @@ def attn_forward(cfg: ModelConfig, p: dict[str, jax.Array], x: jax.Array,
                  window: int = 0, kv_x: jax.Array | None = None,
                  kv_positions: jax.Array | None = None) -> jax.Array:
     """Full (train/prefill) attention. x: (B, S, D)."""
+    if cfg.kv_lora_rank:
+        return mla_forward(cfg, p, x, positions)
     q, k, v = _project_qkv(cfg, p, x, kv_x)
     if cfg.use_rope and kv_x is None:
         q, k = rope(q, k, positions, cfg.rope_theta)
@@ -258,6 +262,9 @@ def init_kv_cache(cfg: ModelConfig, batch: int, max_len: int,
     shape = (batch, max_len, hkv, hd)
     if layers is not None:
         shape = (layers, *shape)
+    if cfg.kv_lora_rank:
+        return {"latent": jnp.zeros(shape[:-2] + (cfg.latent_width,),
+                                    cfg.activation_dtype)}
     if cfg.kv_cache_dtype == "int8":
         sshape = shape[:-1] + (1,)
         return {
@@ -275,6 +282,10 @@ def init_kv_cache(cfg: ModelConfig, batch: int, max_len: int,
 def kv_cache_axes(cfg: ModelConfig, *, layers: bool = True) -> dict[str, tuple]:
     """Logical axes of the cache (leading 'layers' when stacked)."""
     lead = ("layers",) if layers else ()
+    if cfg.kv_lora_rank:
+        # one latent row per position, shared by every head
+        seq = None if cfg.attn_sharding == "heads" else "kv_seq_sharded"
+        return {"latent": lead + ("kv_batch", seq, None)}
     if cfg.attn_sharding == "heads":
         ax = lead + ("kv_batch", None, "kv_heads_sharded", None)
     else:
@@ -286,6 +297,16 @@ def kv_cache_axes(cfg: ModelConfig, *, layers: bool = True) -> dict[str, tuple]:
     return out
 
 
+def _put_rows(buf: jax.Array, upd: jax.Array, pos: jax.Array) -> jax.Array:
+    """Write (B, 1, ...) rows into a (B, Smax, ...) cache at ``pos``, a
+    scalar or one position per row."""
+    if getattr(pos, "ndim", 0) == 1:
+        return jax.vmap(
+            lambda c, u, p: lax.dynamic_update_slice_in_dim(c, u, p, axis=0)
+        )(buf, upd, pos)
+    return lax.dynamic_update_slice_in_dim(buf, upd, pos, axis=1)
+
+
 def _cache_write(cache: dict[str, jax.Array], k: jax.Array, v: jax.Array,
                  pos: jax.Array, quantized: bool) -> dict[str, jax.Array]:
     """Write one new (B, 1, Hkv, hd) k/v at index pos (ring handled upstream).
@@ -293,15 +314,8 @@ def _cache_write(cache: dict[str, jax.Array], k: jax.Array, v: jax.Array,
     ``pos`` may be a scalar (all rows at the same depth) or a (B,) vector —
     the continuous-batching case where every slot sits at its own position.
     """
-    per_row = getattr(pos, "ndim", 0) == 1
-
     def put(buf: jax.Array, upd: jax.Array) -> jax.Array:
-        if per_row:
-            return jax.vmap(
-                lambda c, u, p: lax.dynamic_update_slice_in_dim(c, u, p,
-                                                                axis=0)
-            )(buf, upd, pos)
-        return lax.dynamic_update_slice_in_dim(buf, upd, pos, axis=1)
+        return _put_rows(buf, upd, pos)
 
     if quantized:
         kq, ks = quantize_kv(k)
@@ -337,6 +351,8 @@ def attn_decode(cfg: ModelConfig, p: dict[str, jax.Array], x: jax.Array,
     entries are written at ``pos % window`` and masked by recency. Ring
     buffers require a scalar ``pos`` (all rows advance in lockstep).
     """
+    if cfg.kv_lora_rank:
+        return mla_decode(cfg, p, x, cache, pos)
     b = x.shape[0]
     per_row = getattr(pos, "ndim", 0) == 1
     if per_row and window > 0:
@@ -447,6 +463,8 @@ def prefill_into_cache(cfg: ModelConfig, p: dict[str, jax.Array], x: jax.Array,
                        positions: jax.Array, cache: dict[str, jax.Array], *,
                        window: int = 0):
     """Prefill attention that also populates the cache for later decode."""
+    if cfg.kv_lora_rank:
+        return mla_prefill(cfg, p, x, positions, cache)
     q, k, v = _project_qkv(cfg, p, x)
     if cfg.use_rope:
         q, k = rope(q, k, positions, cfg.rope_theta)
@@ -496,3 +514,130 @@ def prefill_into_cache(cfg: ModelConfig, p: dict[str, jax.Array], x: jax.Array,
                causal=True, window=window)
     dt = x.dtype
     return jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(dt)), cache
+
+
+# ---------------------------------------------------------------------------
+# Latent attention (MLA: deepseek-v2/v3 with q_lora_rank null)
+# ---------------------------------------------------------------------------
+#
+#   q            = x W_q                         H x (nope + rope)
+#   [c, k_pe]    = x W_kv_a; c = RMSNorm(c)      r + rope, k_pe one head
+#   [k_nope, v]  = c W_kv_b                      H x (nope + v)
+#   rope on q_pe and k_pe over interleaved pairs; scale (nope + rope)^-1/2
+#
+# The cache holds the latent row [c, k_pe] of each position, not per-head
+# keys and values. Prefill and training expand c into k and v; decode
+# absorbs W_kv_b into the query and the output instead: q_nope W_uk^T
+# scores against c, and the softmax-weighted sum of c goes through W_uv.
+
+def make_mla_specs(cfg: ModelConfig) -> dict[str, ParamSpec]:
+    d, h, r = cfg.d_model, cfg.num_heads, cfg.kv_lora_rank
+    nope, rope_d, vd = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+                        cfg.v_head_dim)
+    return {
+        "wq": ParamSpec((d, h, nope + rope_d), ("embed", "heads", "head_dim")),
+        "wkv_a": ParamSpec((d, r + rope_d), ("embed", None)),
+        "kv_norm": ParamSpec((r,), (None,), init="ones", f32_at_use=True),
+        "wkv_b": ParamSpec((r, h, nope + vd), (None, "heads", "head_dim")),
+        "wo": ParamSpec((h, vd, d), ("heads", "head_dim", "embed")),
+    }
+
+
+def _deinterleave(x: jax.Array) -> jax.Array:
+    """(x0, x1, x2, x3, ...) -> (x0, x2, ..., x1, x3, ...): the published
+    code's rotary pairs are interleaved; de-interleaved they are the two
+    halves that ``rope`` rotates."""
+    *lead, n = x.shape
+    return x.reshape(*lead, n // 2, 2).swapaxes(-1, -2).reshape(*lead, n)
+
+
+def _mla_project(cfg: ModelConfig, p: dict[str, jax.Array], x: jax.Array,
+                 positions: jax.Array):
+    """q_nope (B,S,H,nope), q_pe (B,S,H,rope), latent rows (B,S,r+rope):
+    the normed latent and the rotated rope key."""
+    dt = x.dtype
+    nope, r = cfg.qk_nope_head_dim, cfg.kv_lora_rank
+    q = jnp.einsum("bsd,dhk->bshk", x, p["wq"].astype(dt))
+    kv = jnp.einsum("bsd,dk->bsk", x, p["wkv_a"].astype(dt))
+    c = rms_norm(kv[..., :r], p["kv_norm"], cfg.latent_norm_eps)
+    q_pe, k_pe = rope(_deinterleave(q[..., nope:]),
+                      _deinterleave(kv[..., None, r:]), positions,
+                      cfg.rope_theta)
+    return q[..., :nope], q_pe, jnp.concatenate([c, k_pe[:, :, 0]], -1)
+
+
+def _mla_scale(cfg: ModelConfig) -> float:
+    return 1.0 / float(cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** 0.5
+
+
+def _mla_attend(cfg: ModelConfig, p, q_nope, q_pe, latent, positions):
+    """Expanded causal attention over a whole sequence; (B, S, D)."""
+    dt = q_nope.dtype
+    nope, r, h = cfg.qk_nope_head_dim, cfg.kv_lora_rank, cfg.num_heads
+    kv = jnp.einsum("bsr,rhk->bshk", latent[..., :r], p["wkv_b"].astype(dt))
+    k_pe = jnp.broadcast_to(latent[..., None, r:],
+                            (*latent.shape[:2], h, cfg.qk_rope_head_dim))
+    q = jnp.concatenate([q_nope, q_pe], -1)
+    k = jnp.concatenate([kv[..., :nope], k_pe], -1)
+    v = kv[..., nope:]
+    q, k, v = _shard_qkv(cfg, q, k, v)
+    # scale: 1/sqrt(q's width), nope + rope (no attention multiplier)
+    out = _IMPLS[cfg.attn_impl](cfg, q, k, v, positions, positions,
+                                causal=True, window=0)
+    return jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(dt))
+
+
+def mla_forward(cfg: ModelConfig, p: dict[str, jax.Array], x: jax.Array,
+                positions: jax.Array) -> jax.Array:
+    """Full (train/prefill) latent attention. x: (B, S, D)."""
+    with jax.named_scope("mla"):
+        q_nope, q_pe, latent = _mla_project(cfg, p, x, positions)
+        return _mla_attend(cfg, p, q_nope, q_pe, latent, positions)
+
+
+def mla_prefill(cfg: ModelConfig, p: dict[str, jax.Array], x: jax.Array,
+                positions: jax.Array, cache: dict[str, jax.Array]):
+    """Prefill that writes the prompt's latent rows to the cache."""
+    with jax.named_scope("mla"):
+        q_nope, q_pe, latent = _mla_project(cfg, p, x, positions)
+        cache = {"latent": lax.dynamic_update_slice_in_dim(
+            cache["latent"], latent.astype(cache["latent"].dtype), 0, 1)}
+        pos = positions[0] if positions.ndim > 1 else positions
+        return _mla_attend(cfg, p, q_nope, q_pe, latent, pos), cache
+
+
+def mla_decode(cfg: ModelConfig, p: dict[str, jax.Array], x: jax.Array,
+               cache: dict[str, jax.Array], pos: jax.Array):
+    """One-token absorbed decode against the latent cache. x: (B, 1, D);
+    pos: scalar, or (B,) per-row positions (continuous batching)."""
+    b = x.shape[0]
+    dt = x.dtype
+    per_row = getattr(pos, "ndim", 0) == 1
+    nope, r = cfg.qk_nope_head_dim, cfg.kv_lora_rank
+    with jax.named_scope("mla"):
+        posv = (pos.astype(jnp.int32)[:, None] if per_row
+                else jnp.full((1, 1), pos, jnp.int32))
+        q_nope, q_pe, latent = _mla_project(cfg, p, x, posv)
+        buf = _put_rows(cache["latent"], latent.astype(cache["latent"].dtype),
+                        pos)
+        buf = shard(buf, "kv_batch", None, None)
+        wkv_b = p["wkv_b"].astype(dt)
+        q_lat = jnp.einsum("bhk,rhk->bhr", q_nope[:, 0], wkv_b[..., :nope])
+        q = jnp.concatenate([q_lat, q_pe[:, 0]], -1)        # (B, H, r+rope)
+        kv_len = (pos if per_row else jnp.broadcast_to(pos, (b,))) + 1
+        if cfg.decode_impl == "pallas":
+            from repro.kernels.decode_attention import ops as da_ops
+            o = da_ops.latent_decode_attention(
+                q, buf, kv_len.astype(jnp.int32), scale=_mla_scale(cfg),
+                value_dim=r, block_kv=cfg.attn_kv_block)
+        else:
+            logits = jnp.einsum("bhc,btc->bht", q, buf,
+                                preferred_element_type=jnp.float32)
+            valid = jnp.arange(buf.shape[1])[None, :] < kv_len[:, None]
+            logits = jnp.where(valid[:, None, :], logits * _mla_scale(cfg),
+                               NEG_INF)
+            probs = jax.nn.softmax(logits, axis=-1).astype(dt)
+            o = jnp.einsum("bht,btr->bhr", probs, buf[..., :r])
+        o = jnp.einsum("bhr,rhv->bhv", o.astype(dt), wkv_b[..., nope:])
+        out = jnp.einsum("bhv,hvd->bd", o, p["wo"].astype(dt))
+    return out[:, None], {"latent": buf}

@@ -80,10 +80,31 @@ class ModelConfig:
     norm_eps: float = 1e-6
 
     # --- MoE ----------------------------------------------------------------
-    num_experts: int = 0
+    num_experts: int = 0             # the router's width: every routed expert
     num_experts_per_tok: int = 0
     moe_group_size: int = 1024       # GShard-style dispatch group size
     moe_capacity_factor: float = 1.25
+    # 'gshard': softmax routing, capacity-bounded dispatch over all
+    # experts (drops tokens past capacity). 'ragged': deepseek-v3's layer,
+    # sigmoid scores selected with a correction bias (noaux_tc) and gated
+    # without it, the k gates normalised; sorted (token, choice) pairs
+    # through a grouped matmul over the experts held here, dropping nothing
+    moe_impl: str = "gshard"
+    moe_d_ff: int = 0                # routed expert width (0 -> d_ff)
+    shared_d_ff: int = 0             # shared experts as one MLP (0 -> none)
+    moe_routed_scale: float = 1.0    # routed_scaling_factor (ragged)
+    # expert parallelism as one chip sees it: this layer holds routed
+    # experts [expert_offset, expert_offset + experts_held); 0 -> all
+    experts_held: int = 0
+    expert_offset: int = 0
+    first_dense_layers: int = 0      # leading layers with a dense MLP of d_ff
+
+    # --- latent attention (MLA, deepseek-v2/v3; q_lora_rank null) -----------
+    kv_lora_rank: int = 0            # > 0: latent attention, latent cache
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0        # one rope key head shared by all heads
+    v_head_dim: int = 0
+    latent_norm_eps: float = 1e-6    # the latent's RMSNorm (kv_a_layernorm)
 
     # --- hybrid (recurrentgemma / griffin) ----------------------------------
     block_pattern: tuple[str, ...] = ()   # e.g. ('rglru', 'rglru', 'attn')
@@ -138,6 +159,16 @@ class ModelConfig:
     @property
     def padded_vocab(self) -> int:
         return pad_vocab(self.vocab_size)
+
+    @property
+    def held_experts(self) -> int:
+        """Routed experts whose weights this layer holds."""
+        return self.experts_held or self.num_experts
+
+    @property
+    def latent_width(self) -> int:
+        """Columns of a latent cache row: the latent, then the rope key."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
 
     @property
     def kv_heads_eff(self) -> int:

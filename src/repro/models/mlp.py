@@ -14,6 +14,13 @@ Two sharding strategies (resolved per architecture):
   (moonshot: 64 experts / 16). Dispatch einsums induce all_to_alls.
 * ``ffn`` (TP-in-expert): experts replicated, each expert's d_ff sharded
   (grok: 8 experts do not divide a 16-way axis, but d_ff=32768 does).
+
+``moe_impl='ragged'`` is the no-drop layer of one expert-parallel chip
+(deepseek-v3 / moonlight): it routes over all ``num_experts``, computes
+only the experts it holds (``experts_held`` from ``expert_offset``) with
+a grouped matmul over the (token, choice) pairs sorted by expert, and adds
+the shared experts whole. Pairs routed to experts held elsewhere add
+nothing here; on one chip the layer runs without its exchange.
 """
 
 from __future__ import annotations
@@ -31,8 +38,8 @@ from repro.models.common import ModelConfig, ParamSpec, act_fn, shard
 # Dense MLP (SwiGLU / GeGLU)
 # ---------------------------------------------------------------------------
 
-def make_mlp_specs(cfg: ModelConfig) -> dict[str, ParamSpec]:
-    d, f = cfg.d_model, cfg.d_ff
+def make_mlp_specs(cfg: ModelConfig, width: int = 0) -> dict[str, ParamSpec]:
+    d, f = cfg.d_model, width or cfg.d_ff
     return {
         "w_gate": ParamSpec((d, f), ("embed", "ffn")),
         "w_up": ParamSpec((d, f), ("embed", "ffn")),
@@ -55,6 +62,8 @@ def mlp_forward(cfg: ModelConfig, p: dict[str, jax.Array], x: jax.Array) -> jax.
 # ---------------------------------------------------------------------------
 
 def make_moe_specs(cfg: ModelConfig) -> dict[str, ParamSpec]:
+    if cfg.moe_impl == "ragged":
+        return _make_ragged_specs(cfg)
     d, f, e = cfg.d_model, cfg.d_ff, cfg.num_experts
     if cfg.moe_sharding == "expert":
         # EP: the expert dim takes the model axis; per-expert ffn replicated.
@@ -80,6 +89,8 @@ def _capacity(cfg: ModelConfig, group: int) -> int:
 def moe_forward(cfg: ModelConfig, p: dict[str, jax.Array], x: jax.Array
                 ) -> tuple[jax.Array, jax.Array]:
     """Returns (output, aux_load_balance_loss). x: (B, S, D)."""
+    if cfg.moe_impl == "ragged":
+        return moe_ragged_forward(cfg, p, x), jnp.zeros((), jnp.float32)
     dt = x.dtype
     b, s, d = x.shape
     e, k = cfg.num_experts, cfg.num_experts_per_tok
@@ -146,3 +157,87 @@ def moe_forward(cfg: ModelConfig, p: dict[str, jax.Array], x: jax.Array
     out = jnp.einsum("gtec,egcd->gtd", combine.astype(dt), expert_out)
     out = shard(out, "moe_groups", None, None)
     return out.reshape(b, s, d), aux_loss.astype(jnp.float32)
+
+
+# ---------------------------------------------------------------------------
+# No-drop expert layer over the experts held here
+# ---------------------------------------------------------------------------
+
+def _make_ragged_specs(cfg: ModelConfig) -> dict:
+    d, e = cfg.d_model, cfg.num_experts
+    f, n = cfg.moe_d_ff or cfg.d_ff, cfg.held_experts
+    specs = {
+        "router": ParamSpec((d, e), ("embed", None), f32_at_use=True),
+        "router_bias": ParamSpec((e,), (None,), init="zeros",
+                                 f32_at_use=True),
+        "w_gate": ParamSpec((n, d, f), ("expert_sharded", "embed", "moe_ffn")),
+        "w_up": ParamSpec((n, d, f), ("expert_sharded", "embed", "moe_ffn")),
+        "w_down": ParamSpec((n, f, d), ("expert_sharded", "moe_ffn", "embed")),
+    }
+    if cfg.shared_d_ff:
+        specs["shared"] = make_mlp_specs(cfg, cfg.shared_d_ff)
+    return specs
+
+
+def route(cfg: ModelConfig, p: dict[str, jax.Array], xt: jax.Array
+          ) -> tuple[jax.Array, jax.Array]:
+    """(experts (T, k), gate weights (T, k) float32) of tokens xt (T, D),
+    computed in float32: sigmoid scores, the k chosen by score plus the
+    correction bias, gated by their unbiased scores normalised over the k
+    and scaled by ``moe_routed_scale``. The router's product runs at
+    ``HIGHEST``: a TPU's default precision would round its float32
+    operands to bfloat16 and flip choices that are no near-ties."""
+    logits = jnp.einsum("td,de->te", xt.astype(jnp.float32),
+                        p["router"].astype(jnp.float32),
+                        precision=lax.Precision.HIGHEST)
+    scores = jax.nn.sigmoid(logits)
+    _, idx = lax.top_k(scores + p["router_bias"].astype(jnp.float32),
+                       cfg.num_experts_per_tok)
+    w = jnp.take_along_axis(scores, idx, axis=-1)
+    w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+    return idx, w * cfg.moe_routed_scale
+
+
+def moe_routed(cfg: ModelConfig, p: dict[str, jax.Array], xt: jax.Array
+               ) -> jax.Array:
+    """The held experts' part of the routed output, (T, D) float32.
+
+    Every (token, choice) pair is sorted by expert, the held experts'
+    pairs first, so each held expert's rows are one contiguous group of
+    a grouped matmul (``lax.ragged_dot``); the pairs of experts held
+    elsewhere trail the groups and are not multiplied. No capacity: a
+    group is as long as its expert's pairs."""
+    dt = xt.dtype
+    t, d = xt.shape
+    k, n = cfg.num_experts_per_tok, cfg.held_experts
+    idx, w = route(cfg, p, xt)
+    local = idx.reshape(-1) - cfg.expert_offset
+    held = (local >= 0) & (local < n)
+    group = jnp.where(held, local, n)                  # elsewhere sorts last
+    order = jnp.argsort(group, stable=True)
+    sizes = jnp.sum(group[None, :] == jnp.arange(n)[:, None], axis=1,
+                    dtype=jnp.int32)
+    tok = order // k
+    rows = xt[tok]                                     # (T*k, D)
+    act = act_fn(cfg.mlp_act)
+    g = lax.ragged_dot(rows, p["w_gate"].astype(dt), sizes)
+    u = lax.ragged_dot(rows, p["w_up"].astype(dt), sizes)
+    y = lax.ragged_dot(act(g) * u, p["w_down"].astype(dt), sizes)
+    gate = w.reshape(-1)[order]
+    # rows past the groups are not defined by the grouped matmul: select
+    y = jnp.where(held[order][:, None], y.astype(jnp.float32) * gate[:, None],
+                  0.0)
+    return jnp.zeros((t, d), jnp.float32).at[tok].add(y)
+
+
+def moe_ragged_forward(cfg: ModelConfig, p: dict[str, jax.Array],
+                       x: jax.Array) -> jax.Array:
+    """No-drop expert layer: the held experts' part plus the shared
+    experts. x: (B, S, D)."""
+    b, s, d = x.shape
+    with jax.named_scope("moe"):
+        out = moe_routed(cfg, p, x.reshape(b * s, d)).reshape(b, s, d)
+        out = out.astype(x.dtype)
+        if cfg.shared_d_ff:
+            out = out + mlp_forward(cfg, p["shared"], x)
+    return out

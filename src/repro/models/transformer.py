@@ -1,9 +1,14 @@
 """Decoder-only LM covering the dense, MoE and VLM families.
 
-The layer stack is homogeneous and executed with ``jax.lax.scan`` over
+Each homogeneous run of layers is executed with ``jax.lax.scan`` over
 parameters stacked along a leading ``layers`` dimension: the lowered HLO
-contains a single layer body regardless of depth, which keeps 512-way GSPMD
-compiles tractable and is the standard production pattern (MaxText et al.).
+contains one layer body per run regardless of depth, which keeps 512-way
+GSPMD compiles tractable and is the standard production pattern (MaxText
+et al.). A model with ``first_dense_layers`` (deepseek-v3, moonlight) has
+two runs: ``dense_layers`` with a dense MLP, then ``layers`` with the
+expert layer; its cache holds one stacked tree per run under the same
+names. Every other model has the single ``layers`` run and a cache that is
+that run's tree.
 
 Remat (activation checkpointing) wraps the scanned body; the policy is a
 config knob so the §Perf iterations can trade recompute for memory.
@@ -24,6 +29,7 @@ from repro.models.common import (
     ParamSpec,
     maybe_remat,
     rms_norm,
+    scan_or_unroll,
     shard,
     softmax_cross_entropy,
     stack_specs,
@@ -34,7 +40,9 @@ from repro.models.common import (
 # Parameter specs
 # ---------------------------------------------------------------------------
 
-def make_layer_specs(cfg: ModelConfig) -> dict[str, Any]:
+def make_layer_specs(cfg: ModelConfig, *, dense: bool = False
+                     ) -> dict[str, Any]:
+    """One layer; ``dense`` for a leading dense layer of an MoE model."""
     specs: dict[str, Any] = {
         "ln_attn": ParamSpec((cfg.d_model,), ("embed",), init="ones",
                              f32_at_use=True),
@@ -42,7 +50,7 @@ def make_layer_specs(cfg: ModelConfig) -> dict[str, Any]:
                             f32_at_use=True),
         "attn": attn.make_attn_specs(cfg),
     }
-    if cfg.family == "moe":
+    if cfg.family == "moe" and not dense:
         specs["moe"] = mlp_mod.make_moe_specs(cfg)
     else:
         specs["mlp"] = mlp_mod.make_mlp_specs(cfg)
@@ -51,12 +59,16 @@ def make_layer_specs(cfg: ModelConfig) -> dict[str, Any]:
 
 def make_lm_specs(cfg: ModelConfig) -> dict[str, Any]:
     vp = cfg.padded_vocab
+    n0 = cfg.first_dense_layers
     specs: dict[str, Any] = {
         "embedding": ParamSpec((vp, cfg.d_model), ("vocab", "embed")),
-        "layers": stack_specs(make_layer_specs(cfg), cfg.num_layers),
+        "layers": stack_specs(make_layer_specs(cfg), cfg.num_layers - n0),
         "ln_final": ParamSpec((cfg.d_model,), ("embed",), init="ones",
                               f32_at_use=True),
     }
+    if n0:
+        specs["dense_layers"] = stack_specs(
+            make_layer_specs(cfg, dense=True), n0)
     if not cfg.tie_embeddings:
         specs["lm_head"] = ParamSpec((cfg.d_model, vp), ("embed", "vocab"))
     if cfg.family == "vlm":
@@ -77,14 +89,22 @@ def _layer_forward(cfg: ModelConfig, p: dict[str, Any], x: jax.Array,
     a = attn.attn_forward(cfg, p["attn"], h, positions, causal=True)
     x = x + rm * a
     h = rms_norm(x, p["ln_mlp"], cfg.norm_eps)
-    aux = jnp.zeros((), jnp.float32)
-    if cfg.family == "moe":
-        m, aux = mlp_mod.moe_forward(cfg, p["moe"], h)
-    else:
-        m = mlp_mod.mlp_forward(cfg, p["mlp"], h)
+    m, aux = _ffn(cfg, p, h)
     x = x + rm * m
     x = shard(x, "batch", "act_seq", None)
     return x, aux
+
+
+def _ffn(cfg: ModelConfig, p: dict[str, Any], h: jax.Array
+         ) -> tuple[jax.Array, jax.Array]:
+    """The layer's MLP or expert layer; (output, aux loss)."""
+    if "moe" in p:
+        return mlp_mod.moe_forward(cfg, p["moe"], h)
+    return mlp_mod.mlp_forward(cfg, p["mlp"], h), jnp.zeros((), jnp.float32)
+
+
+#: the stacked runs of layers, in the order they run
+STACKS = ("dense_layers", "layers")
 
 
 def _stack_forward(cfg: ModelConfig, params: dict[str, Any], x: jax.Array,
@@ -96,13 +116,11 @@ def _stack_forward(cfg: ModelConfig, params: dict[str, Any], x: jax.Array,
 
     body = maybe_remat(body, cfg.remat_policy)
     carry = (x, jnp.zeros((), jnp.float32))
-    if cfg.unroll_layers:
-        for i in range(cfg.num_layers):
-            lp = jax.tree.map(lambda t: t[i], params["layers"])
-            carry, _ = body(carry, lp)
-        return carry
-    (x, aux), _ = lax.scan(body, carry, params["layers"])
-    return x, aux
+    for name in STACKS:
+        if name in params:
+            carry, _ = scan_or_unroll(body, carry, params[name],
+                                      unroll=cfg.unroll_layers)
+    return carry
 
 
 # ---------------------------------------------------------------------------
@@ -223,11 +241,31 @@ def lm_loss(cfg: ModelConfig, params: dict[str, Any],
 # ---------------------------------------------------------------------------
 
 def init_lm_cache(cfg: ModelConfig, batch: int, max_len: int) -> dict[str, Any]:
-    return attn.init_kv_cache(cfg, batch, max_len, layers=cfg.num_layers)
+    n0 = cfg.first_dense_layers
+    if not n0:
+        return attn.init_kv_cache(cfg, batch, max_len, layers=cfg.num_layers)
+    return {"dense_layers": attn.init_kv_cache(cfg, batch, max_len, layers=n0),
+            "layers": attn.init_kv_cache(cfg, batch, max_len,
+                                         layers=cfg.num_layers - n0)}
 
 
 def lm_cache_axes(cfg: ModelConfig) -> dict[str, Any]:
-    return attn.kv_cache_axes(cfg, layers=True)
+    axes = attn.kv_cache_axes(cfg, layers=True)
+    if not cfg.first_dense_layers:
+        return axes
+    return {name: axes for name in STACKS}
+
+
+def _run_stacks(cfg: ModelConfig, params: dict[str, Any], cache, body, x):
+    """``body(h, (layer_params, layer_cache))`` over every run of layers
+    with its cache; returns (x, the new cache)."""
+    runs = cache if cfg.first_dense_layers else {"layers": cache}
+    new = {}
+    for name in STACKS:
+        if name in params:
+            x, new[name] = scan_or_unroll(body, x, (params[name], runs[name]),
+                                          unroll=cfg.unroll_layers)
+    return x, (new if cfg.first_dense_layers else new["layers"])
 
 
 def lm_prefill(cfg: ModelConfig, params: dict[str, Any],
@@ -251,24 +289,13 @@ def lm_prefill(cfg: ModelConfig, params: dict[str, Any],
             cfg, layer_params["attn"], hn, positions, layer_cache)
         h = h + cfg.residual_multiplier * a
         hn = rms_norm(h, layer_params["ln_mlp"], cfg.norm_eps)
-        if cfg.family == "moe":
-            m, _ = mlp_mod.moe_forward(cfg, layer_params["moe"], hn)
-        else:
-            m = mlp_mod.mlp_forward(cfg, layer_params["mlp"], hn)
+        m, _ = _ffn(cfg, layer_params, hn)
         h = h + cfg.residual_multiplier * m
         h = shard(h, "batch", "act_seq", None)
         return h, new_cache
 
     body = maybe_remat(body, cfg.remat_policy)
-    if cfg.unroll_layers:
-        new_layers = []
-        for i in range(cfg.num_layers):
-            xs = jax.tree.map(lambda t: t[i], (params["layers"], cache))
-            x, nc = body(x, xs)
-            new_layers.append(nc)
-        new_cache = jax.tree.map(lambda *ls: jnp.stack(ls), *new_layers)
-    else:
-        x, new_cache = lax.scan(body, x, (params["layers"], cache))
+    x, new_cache = _run_stacks(cfg, params, cache, body, x)
     logits = lm_logits(cfg, params, x[:, -1:])
     return logits, new_cache
 
@@ -287,21 +314,10 @@ def lm_decode_step(cfg: ModelConfig, params: dict[str, Any],
                                         layer_cache, pos)
         h = h + cfg.residual_multiplier * a
         hn = rms_norm(h, layer_params["ln_mlp"], cfg.norm_eps)
-        if cfg.family == "moe":
-            m, _ = mlp_mod.moe_forward(cfg, layer_params["moe"], hn)
-        else:
-            m = mlp_mod.mlp_forward(cfg, layer_params["mlp"], hn)
+        m, _ = _ffn(cfg, layer_params, hn)
         h = h + cfg.residual_multiplier * m
         return h, new_cache
 
-    if cfg.unroll_layers:
-        new_layers = []
-        for i in range(cfg.num_layers):
-            xs = jax.tree.map(lambda t: t[i], (params["layers"], cache))
-            x, nc = body(x, xs)
-            new_layers.append(nc)
-        new_cache = jax.tree.map(lambda *ls: jnp.stack(ls), *new_layers)
-    else:
-        x, new_cache = lax.scan(body, x, (params["layers"], cache))
+    x, new_cache = _run_stacks(cfg, params, cache, body, x)
     logits = lm_logits(cfg, params, x)
     return logits, new_cache

@@ -18,6 +18,9 @@ building blocks (also lowered by the dry-run for ``decode_*`` cells).
 * **weights** — every tree given to the scheduler is stored in the
   compute dtype (``registry.serving_params``), so the step converts no
   weight; ``serving.weight_bytes`` reads the tree's bytes;
+* **cache** — the model's own cache tree (per-head K/V, or one latent
+  row a position for latent attention), one row per slot;
+  ``serving.cache_bytes`` reads its bytes;
 * **metrics** — per-request latency and token counts land in the
   process-wide observability registry (``serving.*``).
 * **spans** — ``serving.admit`` per request; ``serving.step`` and its
@@ -123,6 +126,8 @@ class BatchScheduler:
         self.prefill_step = jax.jit(make_prefill_step(bundle))
         self._insert_row = jax.jit(self._insert_row_impl, donate_argnums=(0,))
         self.cache = bundle.init_cache(batch_size, max_len)
+        get_registry().gauge("serving.cache_bytes").set(sum(
+            leaf.nbytes for leaf in jax.tree.leaves(self.cache)))
         # host-side control state: last token + cache depth per slot. Empty
         # slots keep a frozen pos — their rows are never read, and admission
         # overwrites the whole row before re-activating one.

@@ -449,12 +449,14 @@ def test_generate_timeline_persists_request_spans_not_step_spans(runner):
 # weights stored in the compute dtype
 # ---------------------------------------------------------------------------
 
-#: one reduced config per served family; qwen3-4b adds the q/k norms
+#: one reduced config per served family; qwen3-4b adds the q/k norms,
+#: moonlight the latent attention, the leading dense layer and the
+#: no-drop expert layer over held experts
 SERVED_ARCHS = ("qwen2-0.5b", "qwen3-4b", "moonshot-v1-16b-a3b",
-                "llava-next-34b")
+                "llava-next-34b", "moonlight-16b-a3b")
 #: leaves the forward reads in float32, by name (independent of the specs)
 F32_LEAF_NAMES = ("ln_attn", "ln_mlp", "ln_final", "q_norm", "k_norm",
-                  "router")
+                  "router", "router_bias", "kv_norm")
 
 
 def _named_leaves(tree):

@@ -77,6 +77,24 @@ def test_decode_attention_compiles(one_chip, arch, b, h, hkv, hd, smax):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+@pytest.mark.parametrize("block_kv", [128, 512])
+def test_latent_decode_attention_compiles_at_moonlight(one_chip, block_kv):
+    """Moonlight's latent rows (512 + 64) for 16 heads, 4 slots of 2048:
+    a width that is no multiple of 128 as a whole block, values sliced
+    from its first 512 columns, block indices clamped by the lengths."""
+    from repro.kernels.decode_attention.ops import latent_decode_attention
+
+    def fn(q, cache, lens):
+        return latent_decode_attention(q, cache, lens, scale=192 ** -0.5,
+                                       value_dim=512, block_kv=block_kv,
+                                       interpret=False)
+
+    compiled = _compile(fn, _sds((4, 16, 576), BF16, one_chip),
+                        _sds((4, 2048, 576), BF16, one_chip),
+                        _sds((4,), I32, one_chip))
+    assert "latent_decode_attention" in compiled.as_text()
+
+
 def test_sharded_decode_step_compiles_on_four_chips(topo, monkeypatch):
     """aiida-demo-110m at its published width, heads sharded over
     model=4: the decode kernel must sit inside the partitioned program."""
