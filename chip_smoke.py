@@ -133,7 +133,8 @@ class CompileMeter:
 # ---------------------------------------------------------------------------
 
 def check_decode_kernel(cfg, batch: int, max_len: int, seed: int) -> None:
-    """The flash-decode kernel against its reference at the served shapes."""
+    """The flash-decode kernel against its reference at the served shapes,
+    reading the last layer of a two-layer stacked cache."""
     import jax
     import jax.numpy as jnp
 
@@ -145,11 +146,13 @@ def check_decode_kernel(cfg, batch: int, max_len: int, seed: int) -> None:
     kq, kk, kv = jax.random.split(jax.random.PRNGKey(seed), 3)
     dt = cfg.activation_dtype
     q = jax.random.normal(kq, (batch, cfg.num_heads, cfg.hd), dt)
-    k = jax.random.normal(kk, (batch, max_len, cfg.kv_heads_eff, cfg.hd), dt)
-    v = jax.random.normal(kv, (batch, max_len, cfg.kv_heads_eff, cfg.hd), dt)
+    stack = (2, batch, cfg.kv_heads_eff, cfg.hd, max_len)
+    k = jax.random.normal(kk, stack, dt)
+    v = jax.random.normal(kv, stack, dt)
     lens = jnp.asarray([1, 37, max_len - 28, max_len][:batch], jnp.int32)
-    got = decode_attention(q, k, v, lens, block_kv=cfg.attn_kv_block)
-    want = decode_attention_ref(q, k, v, lens)
+    got = decode_attention(q, k, v, lens, layer=1,
+                           block_kv=cfg.attn_kv_block)
+    want = decode_attention_ref(q, k, v, lens, layer=1)
     err = float(jnp.max(jnp.abs(got.astype(jnp.float32) -
                                 want.astype(jnp.float32))))
     check(err <= KERNEL_ATOL, f"decode kernel vs ref at (B={batch}, "

@@ -7,10 +7,20 @@ heads of a KV group are processed together so the cache is read ONCE per
 group (the GQA arithmetic-intensity win). Per-row cache lengths arrive via
 scalar prefetch (SMEM), letting one batch mix ragged sequence lengths.
 
+Both kernels take the model's whole stacked cache, positions last:
+(L, B, Hkv, hd, Smax) or (L, B, C, Smax), and the index of the layer to
+read, a second scalar prefetch that the k/v index maps use. Each layer's
+blocks are read straight from the stack in HBM, so the decode step passes
+the buffer it updates in place and nothing slices a layer out for the
+call. With positions last, the TPU's default layout of the buffer is the
+row-major one the kernel's call needs (a last axis of hd = 64 or
+C = 576 makes XLA lay positions out minor instead, and the call would
+then need a relayout of the whole cache).
+
 ``latent_decode_attention`` is the same recurrence against a latent cache
-(MLA's absorbed decode): one (B, Smax, C) array of rows shared by every
-head, each row read once for all heads, scored over all C columns and
-summed as values over its first ``value_dim``. Blocks past a row's length
+(MLA's absorbed decode): one latent row of C per position, shared by
+every head, each read once for all heads, scored over all C and summed as
+values over its first ``value_dim``. Blocks past a row's length
 map to its last needed block, so they are neither fetched again nor
 computed.
 """
@@ -28,8 +38,9 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1.0e30
 
 
-def _kernel(lens_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
-            scale, nkv, bkv):
+def _kernel(layer_ref, lens_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr,
+            acc_scr, *, scale, nkv, bkv):
+    del layer_ref                                         # read by the index maps
     ib = pl.program_id(0)
     ik = pl.program_id(2)
 
@@ -45,8 +56,8 @@ def _kernel(lens_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
     @pl.when(needed)
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32) * scale       # (G, hd)
-        k = k_ref[0, 0].astype(jnp.float32)               # (bkv, hd)
-        logits = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+        k = k_ref[0, 0, 0].astype(jnp.float32)            # (hd, bkv)
+        logits = lax.dot_general(q, k, (((1,), (0,)), ((), ())),
                                  preferred_element_type=jnp.float32)
         pos = ik * bkv + lax.broadcasted_iota(jnp.int32, (1, bkv), 1)
         logits = jnp.where(pos < kv_len, logits, NEG_INF)  # (G, bkv)
@@ -55,8 +66,8 @@ def _kernel(lens_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
         alpha = jnp.exp(m_prev - m_new)
         p = jnp.exp(logits - m_new[:, None])
         l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=1)
-        pv = lax.dot_general(p.astype(v_ref.dtype), v_ref[0, 0],
-                             (((1,), (0,)), ((), ())),
+        pv = lax.dot_general(p.astype(v_ref.dtype), v_ref[0, 0, 0],
+                             (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)
         acc_scr[...] = acc_scr[...] * alpha[:, None] + pv
         m_scr[...] = m_new
@@ -68,10 +79,19 @@ def _kernel(lens_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
                        ).astype(o_ref.dtype)
 
 
-def decode_attention_kernel(q, k, v, kv_len, *, scale, block_kv, interpret):
-    """q: (B, H, hd); k/v: (B, Smax, Hkv, hd); kv_len: (B,) int32."""
+def _prefetch(layer, kv_len, b):
+    """The scalar-prefetch operands: the layer index as (1,), the
+    lengths as (B,), both int32."""
+    return (jnp.reshape(jnp.asarray(layer, jnp.int32), (1,)),
+            jnp.broadcast_to(jnp.asarray(kv_len, jnp.int32), (b,)))
+
+
+def decode_attention_kernel(q, k, v, kv_len, layer, *, scale, block_kv,
+                            interpret):
+    """q: (B, H, hd); k/v: (L, B, Hkv, hd, Smax) stacked; kv_len: (B,)
+    int32; layer: the index of the layer to read."""
     b, h, hd = q.shape
-    smax, hkv = k.shape[1], k.shape[2]
+    hkv, smax = k.shape[2], k.shape[4]
     g = h // hkv
     bkv = min(block_kv, smax)
     while smax % bkv:
@@ -79,25 +99,22 @@ def decode_attention_kernel(q, k, v, kv_len, *, scale, block_kv, interpret):
     nkv = smax // bkv
 
     qg = q.reshape(b, hkv, g, hd)
-    kt = k.transpose(0, 2, 1, 3)    # (B, Hkv, Smax, hd)
-    vt = v.transpose(0, 2, 1, 3)
-    lens = jnp.broadcast_to(jnp.asarray(kv_len, jnp.int32), (b,))
 
     kernel = functools.partial(_kernel, scale=scale, nkv=nkv, bkv=bkv)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,
         grid=(b, hkv, nkv),
         in_specs=[
-            # index maps receive the scalar-prefetch ref as a trailing arg
+            # index maps receive the scalar-prefetch refs as trailing args
             pl.BlockSpec((1, 1, g, hd),
-                         lambda ib, ih, ik, lens: (ib, ih, 0, 0)),
-            pl.BlockSpec((1, 1, bkv, hd),
-                         lambda ib, ih, ik, lens: (ib, ih, ik, 0)),
-            pl.BlockSpec((1, 1, bkv, hd),
-                         lambda ib, ih, ik, lens: (ib, ih, ik, 0)),
+                         lambda ib, ih, ik, at, lens: (ib, ih, 0, 0)),
+            pl.BlockSpec((1, 1, 1, hd, bkv),
+                         lambda ib, ih, ik, at, lens: (at[0], ib, ih, 0, ik)),
+            pl.BlockSpec((1, 1, 1, hd, bkv),
+                         lambda ib, ih, ik, at, lens: (at[0], ib, ih, 0, ik)),
         ],
         out_specs=pl.BlockSpec((1, 1, g, hd),
-                               lambda ib, ih, ik, lens: (ib, ih, 0, 0)),
+                               lambda ib, ih, ik, at, lens: (ib, ih, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((g,), jnp.float32),
             pltpu.VMEM((g,), jnp.float32),
@@ -110,12 +127,13 @@ def decode_attention_kernel(q, k, v, kv_len, *, scale, block_kv, interpret):
         out_shape=jax.ShapeDtypeStruct((b, hkv, g, hd), q.dtype),
         interpret=interpret,
         name="decode_attention",
-    )(lens, qg, kt, vt)
+    )(*_prefetch(layer, kv_len, b), qg, k, v)
     return out.reshape(b, h, hd)
 
 
-def _latent_kernel(lens_ref, q_ref, c_ref, o_ref, m_scr, l_scr, acc_scr, *,
-                   scale, nkv, bkv, dv):
+def _latent_kernel(layer_ref, lens_ref, q_ref, c_ref, o_ref, m_scr, l_scr,
+                   acc_scr, *, scale, nkv, bkv, dv):
+    del layer_ref                                         # read by the index map
     ib = pl.program_id(0)
     ik = pl.program_id(1)
 
@@ -129,8 +147,8 @@ def _latent_kernel(lens_ref, q_ref, c_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
     @pl.when((ik * bkv) < kv_len)
     def _compute():
-        rows = c_ref[0]                                   # (bkv, C)
-        logits = lax.dot_general(q_ref[0], rows, (((1,), (1,)), ((), ())),
+        rows = c_ref[0, 0]                                # (C, bkv)
+        logits = lax.dot_general(q_ref[0], rows, (((1,), (0,)), ((), ())),
                                  preferred_element_type=jnp.float32) * scale
         pos = ik * bkv + lax.broadcasted_iota(jnp.int32, (1, bkv), 1)
         logits = jnp.where(pos < kv_len, logits, NEG_INF)  # (H, bkv)
@@ -139,8 +157,8 @@ def _latent_kernel(lens_ref, q_ref, c_ref, o_ref, m_scr, l_scr, acc_scr, *,
         alpha = jnp.exp(m_prev - m_new)
         p = jnp.exp(logits - m_new[:, None])
         l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=1)
-        pv = lax.dot_general(p.astype(rows.dtype), rows[:, :dv],
-                             (((1,), (0,)), ((), ())),
+        pv = lax.dot_general(p.astype(rows.dtype), rows[:dv],
+                             (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)
         acc_scr[...] = acc_scr[...] * alpha[:, None] + pv
         m_scr[...] = m_new
@@ -152,34 +170,33 @@ def _latent_kernel(lens_ref, q_ref, c_ref, o_ref, m_scr, l_scr, acc_scr, *,
                     ).astype(o_ref.dtype)
 
 
-def latent_decode_attention_kernel(q, cache, kv_len, *, scale, value_dim,
-                                   block_kv, interpret):
-    """q: (B, H, C); cache: (B, Smax, C); kv_len: (B,) int32.
-    Returns (B, H, value_dim)."""
+def latent_decode_attention_kernel(q, cache, kv_len, layer, *, scale,
+                                   value_dim, block_kv, interpret):
+    """q: (B, H, C); cache: (L, B, C, Smax) stacked; kv_len: (B,) int32;
+    layer: the index of the layer to read. Returns (B, H, value_dim)."""
     b, h, c = q.shape
-    smax = cache.shape[1]
+    smax = cache.shape[3]
     bkv = min(block_kv, smax)
     while smax % bkv:
         bkv //= 2
     nkv = smax // bkv
-    lens = jnp.broadcast_to(jnp.asarray(kv_len, jnp.int32), (b,))
 
-    def rows(ib, ik, lens):
+    def rows(ib, ik, at, lens):
         # past the row's length, stay on its last needed block
         last = jnp.maximum((lens[ib] + bkv - 1) // bkv - 1, 0)
-        return ib, jnp.minimum(ik, last), 0
+        return at[0], ib, 0, jnp.minimum(ik, last)
 
     kernel = functools.partial(_latent_kernel, scale=scale, nkv=nkv, bkv=bkv,
                                dv=value_dim)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,
         grid=(b, nkv),
         in_specs=[
-            pl.BlockSpec((1, h, c), lambda ib, ik, lens: (ib, 0, 0)),
-            pl.BlockSpec((1, bkv, c), rows),
+            pl.BlockSpec((1, h, c), lambda ib, ik, at, lens: (ib, 0, 0)),
+            pl.BlockSpec((1, 1, c, bkv), rows),
         ],
         out_specs=pl.BlockSpec((1, h, value_dim),
-                               lambda ib, ik, lens: (ib, 0, 0)),
+                               lambda ib, ik, at, lens: (ib, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((h,), jnp.float32),
             pltpu.VMEM((h,), jnp.float32),
@@ -192,4 +209,4 @@ def latent_decode_attention_kernel(q, cache, kv_len, *, scale, value_dim,
         out_shape=jax.ShapeDtypeStruct((b, h, value_dim), q.dtype),
         interpret=interpret,
         name="latent_decode_attention",
-    )(lens, q, cache)
+    )(*_prefetch(layer, kv_len, b), q, cache)

@@ -17,7 +17,6 @@ the math is unchanged — the repeat is purely a layout transformation.
 
 from __future__ import annotations
 
-import functools
 from typing import Any
 
 import jax
@@ -257,16 +256,24 @@ def dequantize_kv(q: jax.Array, scale: jax.Array, dtype) -> jax.Array:
 
 def init_kv_cache(cfg: ModelConfig, batch: int, max_len: int,
                   *, layers: int | None = None) -> dict[str, Any]:
-    """Cache pytree (ShapeDtypeStruct-compatible via jax.eval_shape)."""
-    hkv, hd = cfg.kv_heads_eff, cfg.hd
-    shape = (batch, max_len, hkv, hd)
-    if layers is not None:
-        shape = (layers, *shape)
+    """Cache pytree (ShapeDtypeStruct-compatible via jax.eval_shape).
+
+    Positions are the last axis: per-head k and v are (B, Hkv, hd, S),
+    latent rows (B, C, S), int8 scales (B, Hkv, 1, S). The decode kernel
+    streams (hd, block) tiles of a head without a transpose, and on the
+    TPU the buffer's default layout is the row-major one its call takes
+    (with hd = 64 or C = 576 last, XLA would lay positions out minor and
+    the call would relayout the whole cache). ``layers`` stacks a leading
+    layer axis, which prefill and decode carry whole and update in place
+    (the ``layer`` arguments below).
+    """
+    lead = (batch,) if layers is None else (layers, batch)
     if cfg.kv_lora_rank:
-        return {"latent": jnp.zeros(shape[:-2] + (cfg.latent_width,),
+        return {"latent": jnp.zeros(lead + (cfg.latent_width, max_len),
                                     cfg.activation_dtype)}
+    shape = lead + (cfg.kv_heads_eff, cfg.hd, max_len)
     if cfg.kv_cache_dtype == "int8":
-        sshape = shape[:-1] + (1,)
+        sshape = shape[:-2] + (1, max_len)
         return {
             "k": jnp.zeros(shape, jnp.int8),
             "v": jnp.zeros(shape, jnp.int8),
@@ -285,74 +292,95 @@ def kv_cache_axes(cfg: ModelConfig, *, layers: bool = True) -> dict[str, tuple]:
     if cfg.kv_lora_rank:
         # one latent row per position, shared by every head
         seq = None if cfg.attn_sharding == "heads" else "kv_seq_sharded"
-        return {"latent": lead + ("kv_batch", seq, None)}
+        return {"latent": lead + ("kv_batch", None, seq)}
     if cfg.attn_sharding == "heads":
-        ax = lead + ("kv_batch", None, "kv_heads_sharded", None)
+        ax = lead + ("kv_batch", "kv_heads_sharded", None, None)
     else:
-        ax = lead + ("kv_batch", "kv_seq_sharded", None, None)
+        ax = lead + ("kv_batch", None, None, "kv_seq_sharded")
     out = {"k": ax, "v": ax}
     if cfg.kv_cache_dtype == "int8":
-        out["k_scale"] = ax[:-1] + (None,)
-        out["v_scale"] = ax[:-1] + (None,)
+        out["k_scale"] = ax
+        out["v_scale"] = ax
     return out
 
 
-def _put_rows(buf: jax.Array, upd: jax.Array, pos: jax.Array) -> jax.Array:
-    """Write (B, 1, ...) rows into a (B, Smax, ...) cache at ``pos``, a
-    scalar or one position per row."""
-    if getattr(pos, "ndim", 0) == 1:
-        return jax.vmap(
-            lambda c, u, p: lax.dynamic_update_slice_in_dim(c, u, p, axis=0)
-        )(buf, upd, pos)
-    return lax.dynamic_update_slice_in_dim(buf, upd, pos, axis=1)
+def _stack_of_one(cache: dict[str, jax.Array]) -> dict[str, jax.Array]:
+    return jax.tree.map(lambda t: t[None], cache)
+
+
+def _only_layer(cache: dict[str, jax.Array]) -> dict[str, jax.Array]:
+    return jax.tree.map(lambda t: t[0], cache)
+
+
+def _put_rows(buf: jax.Array, rows: jax.Array, layer, pos) -> jax.Array:
+    """Write ``rows``, a layer's (B, ..., S') update, into layer ``layer``
+    of the stacked cache ``buf`` (L, B, ..., S) in place, at positions
+    ``pos`` on: a scalar, or one position per row, then written row by row
+    with one ``dynamic_update_slice`` each (a scatter of the rows is not
+    kept in place: it copies the whole stack), so no layer is rebuilt."""
+    rows = rows.astype(buf.dtype)[None]
+    start = [layer] + [0] * (buf.ndim - 1)
+    if getattr(pos, "ndim", 0) == 0:
+        start[-1] = pos
+        return lax.dynamic_update_slice(buf, rows, start)
+    for b in range(rows.shape[1]):
+        start[1], start[-1] = b, pos[b]
+        buf = lax.dynamic_update_slice(buf, rows[:, b:b + 1], start)
+    return buf
 
 
 def _cache_write(cache: dict[str, jax.Array], k: jax.Array, v: jax.Array,
-                 pos: jax.Array, quantized: bool) -> dict[str, jax.Array]:
-    """Write one new (B, 1, Hkv, hd) k/v at index pos (ring handled upstream).
+                 layer, pos, quantized: bool) -> dict[str, jax.Array]:
+    """Write k/v as projected, (B, S', Hkv, hd), from position ``pos`` on
+    into layer ``layer`` of the stacked cache (ring order handled
+    upstream).
 
     ``pos`` may be a scalar (all rows at the same depth) or a (B,) vector —
     the continuous-batching case where every slot sits at its own position.
     """
-    def put(buf: jax.Array, upd: jax.Array) -> jax.Array:
-        return _put_rows(buf, upd, pos)
-
+    new = {"k": k, "v": v}
     if quantized:
-        kq, ks = quantize_kv(k)
-        vq, vs = quantize_kv(v)
-        return {
-            "k": put(cache["k"], kq),
-            "v": put(cache["v"], vq),
-            "k_scale": put(cache["k_scale"], ks),
-            "v_scale": put(cache["v_scale"], vs),
-        }
-    return {
-        "k": put(cache["k"], k),
-        "v": put(cache["v"], v),
-    }
+        new["k"], new["k_scale"] = quantize_kv(k)
+        new["v"], new["v_scale"] = quantize_kv(v)
+    return {name: _put_rows(cache[name], rows.transpose(0, 2, 3, 1), layer,
+                            pos)
+            for name, rows in new.items()}
 
 
-def _cache_read(cfg: ModelConfig, cache: dict[str, jax.Array]):
+def _cache_read(cfg: ModelConfig, cache: dict[str, jax.Array], layer):
+    """Layer ``layer``'s k and v, (B, Hkv, hd, S), dequantized if int8."""
+    def at(name):
+        return lax.dynamic_index_in_dim(cache[name], layer, keepdims=False)
+
     if cfg.kv_cache_dtype == "int8":
-        k = dequantize_kv(cache["k"], cache["k_scale"], cfg.activation_dtype)
-        v = dequantize_kv(cache["v"], cache["v_scale"], cfg.activation_dtype)
+        k = dequantize_kv(at("k"), at("k_scale"), cfg.activation_dtype)
+        v = dequantize_kv(at("v"), at("v_scale"), cfg.activation_dtype)
         return k, v
-    return cache["k"], cache["v"]
+    return at("k"), at("v")
 
 
 def attn_decode(cfg: ModelConfig, p: dict[str, jax.Array], x: jax.Array,
                 cache: dict[str, jax.Array], pos: jax.Array, *,
-                window: int = 0) -> tuple[jax.Array, dict[str, jax.Array]]:
+                layer=None, window: int = 0
+                ) -> tuple[jax.Array, dict[str, jax.Array]]:
     """One-token decode. x: (B, 1, D); pos: scalar int32 current position,
     or a (B,) int32 vector of *per-row* positions (continuous batching —
     each slot writes its k/v at, and attends up to, its own depth).
+
+    ``cache`` is one layer's tree, or with ``layer`` (the layer's index)
+    the model's stacked tree: the new row is written into that layer of
+    the stack in place and the kernel reads the layer from the stack.
 
     For ``window > 0`` the cache is a ring buffer of length ``window`` —
     entries are written at ``pos % window`` and masked by recency. Ring
     buffers require a scalar ``pos`` (all rows advance in lockstep).
     """
+    if layer is None:
+        out, cache = attn_decode(cfg, p, x, _stack_of_one(cache), pos,
+                                 layer=0, window=window)
+        return out, _only_layer(cache)
     if cfg.kv_lora_rank:
-        return mla_decode(cfg, p, x, cache, pos)
+        return mla_decode(cfg, p, x, cache, pos, layer)
     b = x.shape[0]
     per_row = getattr(pos, "ndim", 0) == 1
     if per_row and window > 0:
@@ -366,48 +394,52 @@ def attn_decode(cfg: ModelConfig, p: dict[str, jax.Array], x: jax.Array,
             posv = jnp.full((1,), pos, jnp.int32)[None, :]  # (1, 1)
         q, k = rope(q, k, posv, cfg.rope_theta)
 
-    max_len = cache["k"].shape[1]
+    max_len = cache["k"].shape[-1]
+    quantized = cfg.kv_cache_dtype == "int8"
     write_pos = (pos % window) if window > 0 else pos
-    cache = _cache_write(cache, k, v, write_pos, cfg.kv_cache_dtype == "int8")
-    ck, cv = _cache_read(cfg, cache)
+    cache = _cache_write(cache, k, v, layer, write_pos, quantized)
+    axes = kv_cache_axes(cfg)
+    cache = {name: shard(buf, *axes[name]) for name, buf in cache.items()}
 
     # decode activations follow the CACHE's batch sharding (kv_batch): in
     # serve2d mode the residual stream is replicated but attention must run
     # batch-sharded against the sharded cache (GSPMD otherwise gathers it).
     if cfg.attn_sharding == "heads":
-        ck = shard(ck, "kv_batch", None, "kv_heads_sharded", None)
-        cv = shard(cv, "kv_batch", None, "kv_heads_sharded", None)
         q = shard(q, "kv_batch", None, "heads_sharded", None)
     else:
-        ck = shard(ck, "kv_batch", "kv_seq_sharded", None, None)
-        cv = shard(cv, "kv_batch", "kv_seq_sharded", None, None)
         q = shard(q, "kv_batch", None, None, None)
 
-    hkv = ck.shape[2]
+    hkv = cache["k"].shape[2]
     h = q.shape[2]
     g = h // hkv
     hd = q.shape[-1]
     scale = cfg.attention_multiplier or (1.0 / float(hd) ** 0.5)
 
     # Flash-decode Pallas kernel path: ragged per-row lengths land directly
-    # on the kernel's scalar-prefetch lens argument. Ring buffers and
-    # soft-capping stay on the masked-einsum path below.
+    # on the kernel's scalar-prefetch lens argument, and it reads the layer
+    # from the stack. Ring buffers and soft-capping stay on the
+    # masked-einsum path below.
     if cfg.decode_impl == "pallas" and window == 0 and not cfg.attn_softcap:
         kv_len = (pos if per_row else jnp.broadcast_to(pos, (b,))) + 1
+        if quantized:   # the kernel reads the dequantized layer
+            ck, cv = (t[None] for t in _cache_read(cfg, cache, layer))
+            at = 0
+        else:
+            ck, cv, at = cache["k"], cache["v"], layer
         out = _decode_kernel(cfg, q[:, 0], ck, cv, kv_len.astype(jnp.int32),
-                             float(scale))
+                             at, float(scale))
         out = out[:, None]                                  # (B, 1, H, hd)
         out = shard(out, "kv_batch", None, "heads_sharded", None)
         dt = x.dtype
         return jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(dt)), cache
 
+    ck, cv = _cache_read(cfg, cache, layer)
     # slot -> absolute position (ring buffers wrap)
     slots = jnp.arange(max_len, dtype=jnp.int32)
     if window > 0:
         cycle = (pos // window) * window
         k_pos = jnp.where(slots <= (pos % window), cycle + slots,
                           cycle - window + slots)
-        kv_len = None
         valid = (k_pos >= 0) & (k_pos > pos - window) & (k_pos <= pos)
     elif per_row:
         valid = slots[None, :] <= pos[:, None]              # (B, Smax)
@@ -415,7 +447,7 @@ def attn_decode(cfg: ModelConfig, p: dict[str, jax.Array], x: jax.Array,
         valid = slots <= pos
 
     qg = q.reshape(b, 1, hkv, g, hd)
-    logits = jnp.einsum("bskgh,btkh->bkgst", qg, ck).astype(jnp.float32) * scale
+    logits = jnp.einsum("bskgh,bkht->bkgst", qg, ck).astype(jnp.float32) * scale
     logits = _softcap(logits, cfg.attn_softcap)
     bias = jnp.where(valid, 0.0, NEG_INF).astype(jnp.float32)
     if per_row:
@@ -423,14 +455,16 @@ def attn_decode(cfg: ModelConfig, p: dict[str, jax.Array], x: jax.Array,
     else:
         logits = logits + bias[None, None, None, None, :]
     probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
-    out = jnp.einsum("bkgst,btkh->bskgh", probs, cv).reshape(b, 1, h, hd)
+    out = jnp.einsum("bkgst,bkht->bskgh", probs, cv).reshape(b, 1, h, hd)
     out = shard(out, "kv_batch", None, "heads_sharded", None)
     dt = x.dtype
     return jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(dt)), cache
 
 
-def _decode_kernel(cfg: ModelConfig, q, ck, cv, kv_len, scale: float):
-    """The flash-decode kernel; under a mesh, run per shard via shard_map.
+def _decode_kernel(cfg: ModelConfig, q, ck, cv, kv_len, layer, scale: float):
+    """The flash-decode kernel over the stacked cache ``ck``/``cv``
+    (L, B, Hkv, hd, S) at ``layer``; under a mesh, run per shard via
+    shard_map.
 
     A Pallas kernel cannot be partitioned automatically, so with axis
     rules active it runs on each device's block of the cache: batch rows
@@ -442,72 +476,55 @@ def _decode_kernel(cfg: ModelConfig, q, ck, cv, kv_len, scale: float):
     """
     from repro.kernels.decode_attention import ops as da_ops
 
-    call = functools.partial(da_ops.decode_attention, scale=scale,
-                             block_kv=cfg.attn_kv_block)
+    def call(q, ck, cv, kv_len, layer):
+        return da_ops.decode_attention(q, ck, cv, kv_len, layer=layer,
+                                       scale=scale,
+                                       block_kv=cfg.attn_kv_block)
+
+    layer = jnp.asarray(layer, jnp.int32)
     mesh = active_mesh()
     if mesh is None:
-        return call(q, ck, cv, kv_len)
-    cache_axes = kv_cache_axes(cfg, layers=False)["k"]
-    batch, seq, heads, _ = logical_to_spec(cache_axes)
+        return call(q, ck, cv, kv_len, layer)
+    _, batch, heads, _, seq = logical_to_spec(kv_cache_axes(cfg)["k"])
     seq_axes = (seq,) if isinstance(seq, str) else tuple(seq or ())
     if any(mesh.shape[a] > 1 for a in seq_axes):
-        return call(q, ck, cv, kv_len)
-    kv_spec = P(batch, None, heads, None)
+        return call(q, ck, cv, kv_len, layer)
+    kv_spec = P(None, batch, heads, None, None)
     q_spec = P(batch, heads, None)
     return jax.shard_map(call, mesh=mesh,
-                         in_specs=(q_spec, kv_spec, kv_spec, P(batch)),
-                         out_specs=q_spec, check_vma=False)(q, ck, cv, kv_len)
+                         in_specs=(q_spec, kv_spec, kv_spec, P(batch), P()),
+                         out_specs=q_spec, check_vma=False)(
+                             q, ck, cv, kv_len, layer)
 
 
 def prefill_into_cache(cfg: ModelConfig, p: dict[str, jax.Array], x: jax.Array,
                        positions: jax.Array, cache: dict[str, jax.Array], *,
-                       window: int = 0):
-    """Prefill attention that also populates the cache for later decode."""
+                       layer=None, window: int = 0):
+    """Prefill attention that also populates the cache for later decode:
+    one layer's cache, or with ``layer`` that layer of the stacked cache,
+    written in place."""
+    if layer is None:
+        out, cache = prefill_into_cache(cfg, p, x, positions,
+                                        _stack_of_one(cache), layer=0,
+                                        window=window)
+        return out, _only_layer(cache)
     if cfg.kv_lora_rank:
-        return mla_prefill(cfg, p, x, positions, cache)
+        return mla_prefill(cfg, p, x, positions, cache, layer)
     q, k, v = _project_qkv(cfg, p, x)
     if cfg.use_rope:
         q, k = rope(q, k, positions, cfg.rope_theta)
     q, k, v = _shard_qkv(cfg, q, k, v)
     s = x.shape[1]
-    quantized = cfg.kv_cache_dtype == "int8"
+    ks, vs = k, v
     if window > 0:
-        # keep the last `window` entries in ring order
+        # keep the last `window` entries in ring order: slot (pos % window);
+        # since we write a contiguous tail, roll so that slot indices line up
         w = min(window, s)
-        ks, vs = k[:, s - w:], v[:, s - w:]
-        start = (s - w) % window if window else 0
-        # ring layout: slot (pos % window); since we write a contiguous tail,
-        # roll so that slot indices line up.
         idx = (jnp.arange(w) + (s - w)) % window
         order = jnp.argsort(idx)
-        ks, vs = ks[:, order], vs[:, order]
-        if quantized:
-            kq, ksc = quantize_kv(ks)
-            vq, vsc = quantize_kv(vs)
-            cache = dict(cache)
-            cache["k"] = lax.dynamic_update_slice_in_dim(cache["k"], kq, 0, 1)
-            cache["v"] = lax.dynamic_update_slice_in_dim(cache["v"], vq, 0, 1)
-            cache["k_scale"] = lax.dynamic_update_slice_in_dim(cache["k_scale"], ksc, 0, 1)
-            cache["v_scale"] = lax.dynamic_update_slice_in_dim(cache["v_scale"], vsc, 0, 1)
-        else:
-            cache = dict(cache)
-            cache["k"] = lax.dynamic_update_slice_in_dim(cache["k"], ks, 0, 1)
-            cache["v"] = lax.dynamic_update_slice_in_dim(cache["v"], vs, 0, 1)
-    else:
-        if quantized:
-            kq, ksc = quantize_kv(k)
-            vq, vsc = quantize_kv(v)
-            cache = {
-                "k": lax.dynamic_update_slice_in_dim(cache["k"], kq, 0, 1),
-                "v": lax.dynamic_update_slice_in_dim(cache["v"], vq, 0, 1),
-                "k_scale": lax.dynamic_update_slice_in_dim(cache["k_scale"], ksc, 0, 1),
-                "v_scale": lax.dynamic_update_slice_in_dim(cache["v_scale"], vsc, 0, 1),
-            }
-        else:
-            cache = {
-                "k": lax.dynamic_update_slice_in_dim(cache["k"], k, 0, 1),
-                "v": lax.dynamic_update_slice_in_dim(cache["v"], v, 0, 1),
-            }
+        ks, vs = k[:, s - w:][:, order], v[:, s - w:][:, order]
+    cache = _cache_write(cache, ks, vs, layer, 0,
+                         cfg.kv_cache_dtype == "int8")
     impl = _IMPLS[cfg.attn_impl]
     out = impl(cfg, q, k, v, positions[0] if positions.ndim > 1 else positions,
                positions[0] if positions.ndim > 1 else positions,
@@ -596,20 +613,22 @@ def mla_forward(cfg: ModelConfig, p: dict[str, jax.Array], x: jax.Array,
 
 
 def mla_prefill(cfg: ModelConfig, p: dict[str, jax.Array], x: jax.Array,
-                positions: jax.Array, cache: dict[str, jax.Array]):
-    """Prefill that writes the prompt's latent rows to the cache."""
+                positions: jax.Array, cache: dict[str, jax.Array], layer):
+    """Prefill that writes the prompt's latent rows into layer ``layer``
+    of the stacked cache."""
     with jax.named_scope("mla"):
         q_nope, q_pe, latent = _mla_project(cfg, p, x, positions)
-        cache = {"latent": lax.dynamic_update_slice_in_dim(
-            cache["latent"], latent.astype(cache["latent"].dtype), 0, 1)}
+        cache = {"latent": _put_rows(cache["latent"], latent.swapaxes(1, 2),
+                                     layer, 0)}
         pos = positions[0] if positions.ndim > 1 else positions
         return _mla_attend(cfg, p, q_nope, q_pe, latent, pos), cache
 
 
 def mla_decode(cfg: ModelConfig, p: dict[str, jax.Array], x: jax.Array,
-               cache: dict[str, jax.Array], pos: jax.Array):
-    """One-token absorbed decode against the latent cache. x: (B, 1, D);
-    pos: scalar, or (B,) per-row positions (continuous batching)."""
+               cache: dict[str, jax.Array], pos: jax.Array, layer):
+    """One-token absorbed decode against layer ``layer`` of the stacked
+    latent cache, written and read in place. x: (B, 1, D); pos: scalar,
+    or (B,) per-row positions (continuous batching)."""
     b = x.shape[0]
     dt = x.dtype
     per_row = getattr(pos, "ndim", 0) == 1
@@ -618,9 +637,8 @@ def mla_decode(cfg: ModelConfig, p: dict[str, jax.Array], x: jax.Array,
         posv = (pos.astype(jnp.int32)[:, None] if per_row
                 else jnp.full((1, 1), pos, jnp.int32))
         q_nope, q_pe, latent = _mla_project(cfg, p, x, posv)
-        buf = _put_rows(cache["latent"], latent.astype(cache["latent"].dtype),
-                        pos)
-        buf = shard(buf, "kv_batch", None, None)
+        buf = _put_rows(cache["latent"], latent.swapaxes(1, 2), layer, pos)
+        buf = shard(buf, "layers", "kv_batch", None, None)
         wkv_b = p["wkv_b"].astype(dt)
         q_lat = jnp.einsum("bhk,rhk->bhr", q_nope[:, 0], wkv_b[..., :nope])
         q = jnp.concatenate([q_lat, q_pe[:, 0]], -1)        # (B, H, r+rope)
@@ -629,15 +647,16 @@ def mla_decode(cfg: ModelConfig, p: dict[str, jax.Array], x: jax.Array,
             from repro.kernels.decode_attention import ops as da_ops
             o = da_ops.latent_decode_attention(
                 q, buf, kv_len.astype(jnp.int32), scale=_mla_scale(cfg),
-                value_dim=r, block_kv=cfg.attn_kv_block)
+                value_dim=r, layer=layer, block_kv=cfg.attn_kv_block)
         else:
-            logits = jnp.einsum("bhc,btc->bht", q, buf,
+            rows = lax.dynamic_index_in_dim(buf, layer, keepdims=False)
+            logits = jnp.einsum("bhc,bct->bht", q, rows,
                                 preferred_element_type=jnp.float32)
-            valid = jnp.arange(buf.shape[1])[None, :] < kv_len[:, None]
+            valid = jnp.arange(rows.shape[-1])[None, :] < kv_len[:, None]
             logits = jnp.where(valid[:, None, :], logits * _mla_scale(cfg),
                                NEG_INF)
             probs = jax.nn.softmax(logits, axis=-1).astype(dt)
-            o = jnp.einsum("bht,btr->bhr", probs, buf[..., :r])
+            o = jnp.einsum("bht,brt->bhr", probs, rows[:, :r])
         o = jnp.einsum("bhr,rhv->bhv", o.astype(dt), wkv_b[..., nope:])
         out = jnp.einsum("bhv,hvd->bd", o, p["wo"].astype(dt))
     return out[:, None], {"latent": buf}

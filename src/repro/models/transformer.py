@@ -8,7 +8,8 @@ et al.). A model with ``first_dense_layers`` (deepseek-v3, moonlight) has
 two runs: ``dense_layers`` with a dense MLP, then ``layers`` with the
 expert layer; its cache holds one stacked tree per run under the same
 names. Every other model has the single ``layers`` run and a cache that is
-that run's tree.
+that run's tree. Prefill and decode carry each run's stacked cache through
+its scan and update it in place, layer by layer.
 
 Remat (activation checkpointing) wraps the scanned body; the policy is a
 config knob so the §Perf iterations can trade recompute for memory.
@@ -20,6 +21,7 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from repro.models import attention as attn
@@ -257,15 +259,21 @@ def lm_cache_axes(cfg: ModelConfig) -> dict[str, Any]:
 
 
 def _run_stacks(cfg: ModelConfig, params: dict[str, Any], cache, body, x):
-    """``body(h, (layer_params, layer_cache))`` over every run of layers
-    with its cache; returns (x, the new cache)."""
-    runs = cache if cfg.first_dense_layers else {"layers": cache}
-    new = {}
+    """``body((h, cache), (layer_params, layer))`` over every run of
+    layers. The run's whole stacked cache rides in the scan's carry, and
+    ``layer`` (the layer's index, beside its parameters) tells the body
+    which layer of it to write and read in place: the donated buffer stays
+    one buffer, and no layer is sliced out or stacked back. Returns
+    (x, the new cache)."""
+    runs = dict(cache) if cfg.first_dense_layers else {"layers": cache}
     for name in STACKS:
         if name in params:
-            x, new[name] = scan_or_unroll(body, x, (params[name], runs[name]),
-                                          unroll=cfg.unroll_layers)
-    return x, (new if cfg.first_dense_layers else new["layers"])
+            n = jax.tree.leaves(params[name])[0].shape[0]
+            (x, runs[name]), _ = scan_or_unroll(
+                lambda carry, xs: (body(carry, xs), None), (x, runs[name]),
+                (params[name], np.arange(n, dtype=np.int32)),
+                unroll=cfg.unroll_layers)
+    return x, (runs if cfg.first_dense_layers else runs["layers"])
 
 
 def lm_prefill(cfg: ModelConfig, params: dict[str, Any],
@@ -282,17 +290,17 @@ def lm_prefill(cfg: ModelConfig, params: dict[str, Any],
     positions = jnp.arange(x.shape[1], dtype=jnp.int32)
 
     def body(carry, xs):
-        h = carry
-        layer_params, layer_cache = xs
+        h, cache = carry
+        layer_params, layer = xs
         hn = rms_norm(h, layer_params["ln_attn"], cfg.norm_eps)
-        a, new_cache = attn.prefill_into_cache(
-            cfg, layer_params["attn"], hn, positions, layer_cache)
+        a, cache = attn.prefill_into_cache(
+            cfg, layer_params["attn"], hn, positions, cache, layer=layer)
         h = h + cfg.residual_multiplier * a
         hn = rms_norm(h, layer_params["ln_mlp"], cfg.norm_eps)
         m, _ = _ffn(cfg, layer_params, hn)
         h = h + cfg.residual_multiplier * m
         h = shard(h, "batch", "act_seq", None)
-        return h, new_cache
+        return h, cache
 
     body = maybe_remat(body, cfg.remat_policy)
     x, new_cache = _run_stacks(cfg, params, cache, body, x)
@@ -307,16 +315,17 @@ def lm_decode_step(cfg: ModelConfig, params: dict[str, Any],
     x = embed_tokens(cfg, params, tokens)
     x = shard(x, "batch", None, None)
 
-    def body(h, xs):
-        layer_params, layer_cache = xs
+    def body(carry, xs):
+        h, cache = carry
+        layer_params, layer = xs
         hn = rms_norm(h, layer_params["ln_attn"], cfg.norm_eps)
-        a, new_cache = attn.attn_decode(cfg, layer_params["attn"], hn,
-                                        layer_cache, pos)
+        a, cache = attn.attn_decode(cfg, layer_params["attn"], hn, cache, pos,
+                                    layer=layer)
         h = h + cfg.residual_multiplier * a
         hn = rms_norm(h, layer_params["ln_mlp"], cfg.norm_eps)
         m, _ = _ffn(cfg, layer_params, hn)
         h = h + cfg.residual_multiplier * m
-        return h, new_cache
+        return h, cache
 
     x, new_cache = _run_stacks(cfg, params, cache, body, x)
     logits = lm_logits(cfg, params, x)

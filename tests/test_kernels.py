@@ -98,8 +98,8 @@ def test_flash_attention_softcap():
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_decode_attention_shapes(b, h, hkv, hd, smax, dtype):
     q = jnp.asarray(RNG.normal(0, 1, (b, h, hd)), dtype)
-    k = jnp.asarray(RNG.normal(0, 1, (b, smax, hkv, hd)), dtype)
-    v = jnp.asarray(RNG.normal(0, 1, (b, smax, hkv, hd)), dtype)
+    k = jnp.asarray(RNG.normal(0, 1, (b, hkv, hd, smax)), dtype)
+    v = jnp.asarray(RNG.normal(0, 1, (b, hkv, hd, smax)), dtype)
     lens = jnp.asarray(RNG.integers(1, smax + 1, (b,)), jnp.int32)
     out = decode_attention(q, k, v, lens, block_kv=64)
     ref = decode_attention_ref(q, k, v, lens)
@@ -114,11 +114,11 @@ def test_decode_attention_ragged_lengths_property(kv_len):
     """Cache entries beyond kv_len never influence the output."""
     b, h, hd, smax = 1, 2, 32, 256
     q = jnp.asarray(RNG.normal(0, 1, (b, h, hd)), jnp.float32)
-    k = np.asarray(RNG.normal(0, 1, (b, smax, h, hd)), np.float32)
-    v = np.asarray(RNG.normal(0, 1, (b, smax, h, hd)), np.float32)
+    k = np.asarray(RNG.normal(0, 1, (b, h, hd, smax)), np.float32)
+    v = np.asarray(RNG.normal(0, 1, (b, h, hd, smax)), np.float32)
     k2, v2 = k.copy(), v.copy()
-    k2[:, kv_len:] = 999.0      # poison the dead region
-    v2[:, kv_len:] = -999.0
+    k2[..., kv_len:] = 999.0    # poison the dead region
+    v2[..., kv_len:] = -999.0
     out1 = decode_attention(q, jnp.asarray(k), jnp.asarray(v),
                             jnp.int32(kv_len), block_kv=64)
     out2 = decode_attention(q, jnp.asarray(k2), jnp.asarray(v2),
@@ -134,8 +134,8 @@ def test_decode_attention_gqa_headdim_sweep(h, hkv, hd, dtype):
     head dims and dtypes, with ragged per-row lengths."""
     b, smax = 2, 128
     q = jnp.asarray(RNG.normal(0, 1, (b, h, hd)), dtype)
-    k = jnp.asarray(RNG.normal(0, 1, (b, smax, hkv, hd)), dtype)
-    v = jnp.asarray(RNG.normal(0, 1, (b, smax, hkv, hd)), dtype)
+    k = jnp.asarray(RNG.normal(0, 1, (b, hkv, hd, smax)), dtype)
+    v = jnp.asarray(RNG.normal(0, 1, (b, hkv, hd, smax)), dtype)
     lens = jnp.asarray([31, smax], jnp.int32)
     out = decode_attention(q, k, v, lens, block_kv=64)
     ref = decode_attention_ref(q, k, v, lens)
@@ -149,15 +149,15 @@ def test_decode_attention_kvlen_edge_cases():
     length that is no multiple of block_kv, Smax-1 and exactly Smax."""
     b, h, hd, smax = 4, 4, 32, 256
     q = jnp.asarray(RNG.normal(0, 1, (b, h, hd)), jnp.float32)
-    k = jnp.asarray(RNG.normal(0, 1, (b, smax, h, hd)), jnp.float32)
-    v = jnp.asarray(RNG.normal(0, 1, (b, smax, h, hd)), jnp.float32)
+    k = jnp.asarray(RNG.normal(0, 1, (b, h, hd, smax)), jnp.float32)
+    v = jnp.asarray(RNG.normal(0, 1, (b, h, hd, smax)), jnp.float32)
     lens = jnp.asarray([1, 130, smax - 1, smax], jnp.int32)
     out = decode_attention(q, k, v, lens, block_kv=128)
     ref = decode_attention_ref(q, k, v, lens)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=5e-5)
     # kv_len=1 must reproduce v[:, 0] exactly (softmax over one entry)
-    np.testing.assert_allclose(np.asarray(out[0]), np.asarray(v[0, 0]),
-                               atol=5e-5)
+    np.testing.assert_allclose(np.asarray(out[0]),
+                               np.asarray(v[0, :, :, 0]), atol=5e-5)
 
 
 def test_decode_attention_kvlen_zero_is_zero_output():
@@ -166,8 +166,8 @@ def test_decode_attention_kvlen_zero_is_zero_output():
     row and returns garbage for length 0, so there is nothing to diff."""
     b, h, hd, smax = 2, 4, 32, 128
     q = jnp.asarray(RNG.normal(0, 1, (b, h, hd)), jnp.float32)
-    k = jnp.asarray(RNG.normal(0, 1, (b, smax, h, hd)), jnp.float32)
-    v = jnp.asarray(RNG.normal(0, 1, (b, smax, h, hd)), jnp.float32)
+    k = jnp.asarray(RNG.normal(0, 1, (b, h, hd, smax)), jnp.float32)
+    v = jnp.asarray(RNG.normal(0, 1, (b, h, hd, smax)), jnp.float32)
     lens = jnp.asarray([0, 64], jnp.int32)
     out = np.asarray(decode_attention(q, k, v, lens, block_kv=64))
     assert np.all(np.isfinite(out))
@@ -182,12 +182,36 @@ def test_decode_attention_block_kv_invariance(block_kv):
     oracle bit-for-tolerance at every block_kv."""
     b, h, hkv, hd, smax = 2, 4, 2, 64, 512
     q = jnp.asarray(RNG.normal(0, 1, (b, h, hd)), jnp.float32)
-    k = jnp.asarray(RNG.normal(0, 1, (b, smax, hkv, hd)), jnp.float32)
-    v = jnp.asarray(RNG.normal(0, 1, (b, smax, hkv, hd)), jnp.float32)
+    k = jnp.asarray(RNG.normal(0, 1, (b, hkv, hd, smax)), jnp.float32)
+    v = jnp.asarray(RNG.normal(0, 1, (b, hkv, hd, smax)), jnp.float32)
     lens = jnp.asarray([200, 511], jnp.int32)
     out = decode_attention(q, k, v, lens, block_kv=block_kv)
     ref = decode_attention_ref(q, k, v, lens)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=5e-5)
+
+
+@pytest.mark.parametrize("layer", [0, 1, 2])
+def test_decode_attention_reads_its_layer_of_the_stack(layer):
+    """The stacked cache (L, B, Hkv, hd, Smax) and a layer index, ragged
+    lengths: the kernel reads that layer and no other (each layer holds
+    other values), the last included, whether the index is a constant or
+    traced, as the scan gives it."""
+    n, b, h, hkv, hd, smax = 3, 3, 8, 2, 32, 256
+    q = jnp.asarray(RNG.normal(0, 1, (b, h, hd)), jnp.float32)
+    k = jnp.asarray(RNG.normal(0, 1, (n, b, hkv, hd, smax)), jnp.float32)
+    v = jnp.asarray(RNG.normal(0, 1, (n, b, hkv, hd, smax)), jnp.float32)
+    lens = jnp.asarray([1, 130, smax], jnp.int32)
+    out = decode_attention(q, k, v, lens, layer=layer, block_kv=64)
+    traced = jax.jit(lambda at: decode_attention(q, k, v, lens, layer=at,
+                                                 block_kv=64))(layer)
+    alone = decode_attention(q, k[layer], v[layer], lens, block_kv=64)
+    ref = decode_attention_ref(q, k, v, lens, layer=layer)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(alone))
+    np.testing.assert_array_equal(np.asarray(traced), np.asarray(out))
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=5e-5)
+    for other in set(range(n)) - {layer}:
+        assert not np.allclose(np.asarray(out), np.asarray(
+            decode_attention_ref(q, k, v, lens, layer=other)), atol=1e-2)
 
 
 # ---------------------------------------------------------------------------

@@ -98,7 +98,7 @@ def test_prefill_then_latent_decode_matches_reference(model, impl):
     bundle = build(cfg)
     cache = bundle.init_cache(B, 64)
     assert set(cache) == {"dense_layers", "layers"}
-    assert cache["layers"]["latent"].shape == (2, B, 64, 40)
+    assert cache["layers"]["latent"].shape == (2, B, 40, 64)   # positions last
     start = (24, 20)
     for row, n in enumerate(start):
         one = bundle.init_cache(1, 64)
@@ -227,7 +227,7 @@ def test_latent_kernel_matches_plain_absorbed_decode(block):
     rng = np.random.default_rng(0)
     b, h, c, dv, smax = 3, 4, 40, 32, 64
     q = jnp.asarray(rng.standard_normal((b, h, c)), jnp.bfloat16)
-    cache = jnp.asarray(rng.standard_normal((b, smax, c)), jnp.bfloat16)
+    cache = jnp.asarray(rng.standard_normal((b, c, smax)), jnp.bfloat16)
     lens = jnp.asarray([5, 32, 64], jnp.int32)
     got = da_ops.latent_decode_attention(q, cache, lens, scale=c ** -0.5,
                                          value_dim=dv, block_kv=block,
@@ -239,11 +239,34 @@ def test_latent_kernel_matches_plain_absorbed_decode(block):
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32), atol=2e-2)
     # rows past a length, stale in a serving cache, change nothing
-    junk = cache.at[0, 5:].set(1e4).at[1, 32:].set(-3e4)
+    junk = cache.at[0, :, 5:].set(1e4).at[1, :, 32:].set(-3e4)
     again = da_ops.latent_decode_attention(q, junk, lens, scale=c ** -0.5,
                                            value_dim=dv, block_kv=block,
                                            interpret=True)
     np.testing.assert_array_equal(np.asarray(again), np.asarray(got))
+
+
+@pytest.mark.parametrize("layer", [0, 1])
+def test_latent_kernel_reads_its_layer_of_the_stack(layer):
+    """The stacked latent cache (L, B, C, Smax) and a layer index, ragged
+    lengths: the interpreted kernel reads that layer and no other, the
+    last included, as the oracle does on the stack."""
+    rng = np.random.default_rng(1)
+    n, b, h, c, dv, smax = 2, 3, 4, 40, 32, 64
+    q = jnp.asarray(rng.standard_normal((b, h, c)), jnp.float32)
+    cache = jnp.asarray(rng.standard_normal((n, b, c, smax)), jnp.float32)
+    lens = jnp.asarray([5, 32, 64], jnp.int32)
+    kw = dict(scale=c ** -0.5, value_dim=dv)
+    got = da_ops.latent_decode_attention(q, cache, lens, layer=layer,
+                                         block_kv=16, interpret=True, **kw)
+    alone = da_ops.latent_decode_attention(q, cache[layer], lens,
+                                           block_kv=16, interpret=True, **kw)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(alone))
+    want = latent_decode_attention_ref(q, cache, lens, layer=layer, **kw)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=5e-5)
+    other = latent_decode_attention_ref(q, cache, lens, layer=1 - layer,
+                                        **kw)
+    assert not np.allclose(np.asarray(got), np.asarray(other), atol=1e-2)
 
 
 def test_latent_cache_bytes_per_token():

@@ -635,3 +635,102 @@ def test_engine_keeps_no_float32_weights():
     for name, leaf in _named_leaves(eng.params):
         want = jnp.float32 if name in F32_LEAF_NAMES else jnp.bfloat16
         assert leaf.dtype == want, name
+
+
+# ---------------------------------------------------------------------------
+# the stacked cache, updated in place: kernel path against masked einsum
+# ---------------------------------------------------------------------------
+
+#: each case differs from ``per_row_pos`` in one way; activations and
+#: cache are float32 unless a case names a cache dtype
+IN_PLACE_CASES = {
+    "scalar_pos": {"per_row": False},
+    "per_row_pos": {},
+    "bfloat16_cache": {"dtype": "bfloat16", "kv_cache_dtype": "bfloat16"},
+    "int8_cache": {"kv_cache_dtype": "int8"},
+    "unrolled_layers": {"unroll_layers": True},
+    "slot_spliced_mid_run": {"splice_at": 4},
+}
+
+
+def _in_place_steps(case):
+    """Prefill two rows, then 8 decode steps fed fixed tokens. Yields, per
+    step, the positions, the cache before it and (logits, cache) of the
+    kernel path, the masked-einsum path and the float32 masked-einsum
+    path, all three from the same cache."""
+    opts = {"dtype": "float32", "kv_cache_dtype": "float32",
+            **IN_PLACE_CASES[case]}
+    per_row = opts.pop("per_row", True)
+    splice_at = opts.pop("splice_at", None)
+    cfg = reduced_config(ARCH).replace(attn_kv_block=16, **opts)
+    kernel = build(cfg.replace(decode_impl="pallas"))
+    params = kernel.init_params(jax.random.PRNGKey(3))
+    f32 = {"dtype": "float32"}
+    if cfg.kv_cache_dtype != "int8":
+        f32["kv_cache_dtype"] = "float32"
+    paths = [jax.jit(build(cfg.replace(decode_impl=impl, **over)).decode_fn)
+             for impl, over in (("direct", {}), ("direct", f32))]
+    step = jax.jit(kernel.decode_fn, donate_argnums=(1,))
+    prefill = jax.jit(make_prefill_step(kernel))
+    insert = jax.jit(BatchScheduler._insert_row_impl, donate_argnums=(0,))
+    rng = np.random.default_rng(11)
+    fed = jnp.asarray(rng.integers(1, 500, (8, 2, 1)), jnp.int32)
+    prompts = rng.integers(1, 500, (3, 9))
+    lengths = np.asarray([9, 5] if per_row else [9, 9], np.int32)
+    cache = kernel.init_cache(2, 32)
+
+    def admit(cache, slot, prompt):
+        _, row = prefill(params, {"tokens": jnp.asarray(prompt)[None]},
+                         kernel.init_cache(1, 32))
+        return insert(cache, row, jnp.asarray(slot, jnp.int32))
+
+    def host(tree):
+        return jax.tree.map(lambda t: np.asarray(t, np.float32), tree)
+
+    for slot in range(2):
+        cache = admit(cache, slot, prompts[slot, :lengths[slot]])
+    for i in range(8):
+        if i == splice_at:
+            cache = admit(cache, 1, prompts[2, :6])
+            lengths[1] = 6 - i
+        pos = np.broadcast_to(lengths + i if per_row else lengths[0] + i, (2,))
+        arg = jnp.asarray(pos if per_row else pos[0], jnp.int32)
+        einsum, einsum32 = (
+            host(path(params, jax.tree.map(jnp.asarray, c), fed[i], arg))
+            for path, c in zip(paths, (cache, host(cache))))
+        before = host(cache)
+        logits, cache = step(params, cache, fed[i], arg)
+        yield pos, before, host((logits, cache)), einsum, einsum32
+
+
+@pytest.mark.parametrize("case", list(IN_PLACE_CASES))
+def test_in_place_kernel_decode_matches_masked_einsum(case):
+    """Prefill, then 8 decode steps in which every layer writes its new
+    row into the carried stack in place and the kernel reads its layer
+    from the stack, against the masked-einsum path from the same cache at
+    the same positions: every entry of the stack but the new rows is left
+    exactly as it was, and logits and new rows agree within rounding. In
+    float32 that is 1e-5 of the logits' size; in bfloat16 it is how far
+    bfloat16 moves the masked-einsum path itself from its float32 run over
+    these steps. Cases: one position for all rows or one per row, a
+    bfloat16 or int8 cache, scanned or unrolled layers, and a slot spliced
+    in by the scheduler's ``_insert_row`` mid-run."""
+    steps = list(_in_place_steps(case))
+    bf16 = IN_PLACE_CASES[case].get("dtype") == "bfloat16"
+    spread = max(np.abs(e[0] - e32[0]).max() for *_, e, e32 in steps)
+    for pos, before, (got, got_cache), (want, want_cache), _ in steps:
+        tol = spread if bf16 else 1e-5 * np.abs(want).max()
+        np.testing.assert_allclose(got, want, rtol=0, atol=tol)
+        written = np.zeros((2, 32), bool)
+        written[np.arange(2), pos] = True
+
+        def at(leaf, mask):    # (L, B, ..., S) -> (L, positions, ...)
+            return np.moveaxis(leaf, -1, 2)[:, mask]
+
+        for name, leaf in got_cache.items():
+            np.testing.assert_array_equal(at(leaf, ~written),
+                                          at(before[name], ~written))
+            new, ref = at(leaf, written), at(want_cache[name], written)
+            np.testing.assert_allclose(new, ref, rtol=0,
+                                       atol=tol / np.abs(want).max()
+                                       * np.abs(ref).max())

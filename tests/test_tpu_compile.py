@@ -71,8 +71,8 @@ def test_decode_attention_compiles(one_chip, arch, b, h, hkv, hd, smax):
         return decode_attention(q, k, v, lens, block_kv=512, interpret=False)
 
     compiled = _compile(fn, _sds((b, h, hd), BF16, one_chip),
-                        _sds((b, smax, hkv, hd), BF16, one_chip),
-                        _sds((b, smax, hkv, hd), BF16, one_chip),
+                        _sds((b, hkv, hd, smax), BF16, one_chip),
+                        _sds((b, hkv, hd, smax), BF16, one_chip),
                         _sds((b,), I32, one_chip))
     assert "tpu_custom_call" in compiled.as_text()
 
@@ -90,9 +90,79 @@ def test_latent_decode_attention_compiles_at_moonlight(one_chip, block_kv):
                                        interpret=False)
 
     compiled = _compile(fn, _sds((4, 16, 576), BF16, one_chip),
-                        _sds((4, 2048, 576), BF16, one_chip),
+                        _sds((4, 576, 2048), BF16, one_chip),
                         _sds((4,), I32, one_chip))
     assert "latent_decode_attention" in compiled.as_text()
+
+
+#: (arch, layers): granite and qwen at their served widths (granite's 16
+#: stored KV heads, qwen's 2), moonlight's latent cache with one dense and
+#: one expert layer; 4 slots of 2048 positions each
+IN_PLACE_STEPS = [("granite-3-2b", 4), ("qwen2-0.5b", 4),
+                  ("moonlight-16b-a3b", 2)]
+
+#: ops that may yield a buffer of the cache's size: the donated cache,
+#: its in-place updates, and the loop and tuple plumbing around them.
+#: ``copy-start``/``copy-done`` stage a single-layer stack in the chip's
+#: fast memory and back, keeping its layout; a relayout is a ``copy``.
+_CACHE_PLUMBING = {"parameter", "get-tuple-element", "tuple", "while",
+                   "bitcast", "dynamic-update-slice", "copy-start",
+                   "copy-done"}
+
+
+@pytest.mark.parametrize("arch,layers", IN_PLACE_STEPS)
+def test_decode_step_updates_the_cache_in_place(one_chip, monkeypatch, arch,
+                                                layers):
+    """The decode step as the scheduler jits it (cache donated, one
+    position per slot): no op but an in-place update yields a buffer of
+    one layer's cache or of the whole stack (no copy, transpose or
+    dynamic-slice of it), the output aliases the donated cache, and the
+    step's temporaries are smaller than one layer's cache."""
+    import functools
+    import re
+
+    from repro.configs import get_config
+    from repro.kernels.decode_attention import ops as da_ops
+    from repro.models.registry import build, serving_params
+    from repro.serving.serve import make_decode_step
+
+    monkeypatch.setattr(da_ops, "interpret_default", lambda: False)
+    cfg = get_config(arch).replace(decode_impl="pallas", num_layers=layers)
+    if cfg.first_dense_layers:
+        cfg = cfg.replace(first_dense_layers=1)
+    bundle = build(cfg)
+    b, max_len = 4, 2048
+
+    def on_chip(tree):
+        return jax.tree.map(lambda s: _sds(s.shape, s.dtype, one_chip), tree)
+
+    params = on_chip(jax.eval_shape(functools.partial(serving_params, cfg),
+                                    bundle.param_shapes()))
+    cache = on_chip(jax.eval_shape(lambda: bundle.init_cache(b, max_len)))
+    compiled = jax.jit(make_decode_step(bundle), donate_argnums=(1,)).lower(
+        params, cache, _sds((b, 1), I32, one_chip),
+        _sds((b,), I32, one_chip)).compile()
+
+    stacks = [leaf.shape for leaf in jax.tree.leaves(cache)]
+    sizes = {tuple(sorted(d for d in s[i:] if d > 1))
+             for s in stacks for i in (0, 1)}
+    found = []
+    for line in compiled.as_text().splitlines():
+        m = re.match(r"\s*(?:ROOT )?%(\S+) = \w+\[([\d,]*)\]\S* ([\w-]+)\(",
+                     line)
+        if m and m.group(3) not in _CACHE_PLUMBING:
+            dims = tuple(sorted(int(d) for d in m.group(2).split(",")
+                                if d and int(d) > 1))
+            if dims in sizes:
+                found.append(f"{m.group(1)} {m.group(3)}[{m.group(2)}]")
+    assert not found, found
+    mem = compiled.memory_analysis()
+    cache_bytes = sum(leaf.size * leaf.dtype.itemsize
+                      for leaf in jax.tree.leaves(cache))
+    layer_bytes = min(leaf.size * leaf.dtype.itemsize // leaf.shape[0]
+                      for leaf in jax.tree.leaves(cache))
+    assert mem.alias_size_in_bytes == cache_bytes
+    assert mem.temp_size_in_bytes < layer_bytes
 
 
 def test_sharded_decode_step_compiles_on_four_chips(topo, monkeypatch):
