@@ -304,8 +304,7 @@ def sharded_decode_phase(seed: int) -> None:
     from repro.models.registry import build
 
     cfg = get_config(SHARDED_ARCH).replace(
-        num_layers=SHARDED_DEPTH, decode_impl="pallas", dtype="float32",
-        kv_cache_dtype="float32")
+        num_layers=SHARDED_DEPTH, decode_impl="pallas", dtype="float32")
     bundle = build(cfg)
     params = bundle.init_params(jax.random.PRNGKey(seed))
     prompt = jnp.asarray(np.random.default_rng(seed).integers(

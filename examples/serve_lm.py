@@ -9,7 +9,7 @@ drives prefill + per-slot-position decode: requests of different prompt
 lengths and budgets are co-batched, evicted on completion, and replaced
 from the FIFO queue mid-flight. ``--decode-impl pallas`` routes the
 decode inner product through the flash-decode kernel (interpreted off
-TPU); ``--int8-kv`` quantises the KV cache.
+TPU). The KV cache is stored in the activation dtype (bfloat16).
 """
 
 import argparse
@@ -35,7 +35,6 @@ def main():
     ap.add_argument("--new-tokens", type=int, default=24)
     ap.add_argument("--decode-impl", default="pallas",
                     choices=["direct", "pallas"])
-    ap.add_argument("--int8-kv", action="store_true")
     args = ap.parse_args()
 
     enable_compile_cache()
@@ -44,8 +43,7 @@ def main():
 
     cfg = get_config("aiida-demo-110m").replace(
         num_layers=4, d_model=256, num_heads=4, num_kv_heads=2, d_ff=704,
-        vocab_size=8192, decode_impl=args.decode_impl,
-        kv_cache_dtype="int8" if args.int8_kv else "bfloat16")
+        vocab_size=8192, decode_impl=args.decode_impl)
     bundle = build(cfg)
     params = bundle.init_params(jax.random.PRNGKey(0))
 
@@ -70,8 +68,7 @@ def main():
     toks = sum(len(r.generated) for r in finished)
     print(f"served {len(finished)} requests through {args.batch} slots in "
           f"{dt:.2f}s ({toks} tokens, {toks/dt:.0f} tok/s, "
-          f"decode_impl={args.decode_impl}, "
-          f"kv={'int8' if args.int8_kv else 'bf16'})")
+          f"decode_impl={args.decode_impl})")
     for r in finished[:4]:
         print(f"  req {r.rid}: prompt {len(r.prompt):3d} tok -> "
               f"{len(r.generated):2d} new [{r.finish_reason}] "

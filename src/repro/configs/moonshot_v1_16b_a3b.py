@@ -1,6 +1,6 @@
 """A synthetic 48-layer GShard MoE (64 experts, top-6, softmax routing,
 capacity-bounded dispatch, plain multi-head attention, no shared experts
-and no leading dense layer), kept for the sharding, dry-run and capacity
+and no leading dense layer), kept for the sharding, lowering and capacity
 tests: expert-parallel, 64/16 = 4 experts per model-axis shard. It is not
 Moonlight-16B-A3B, whose published shape (latent attention, one dense
 layer, 26 expert layers with shared experts and bias-corrected sigmoid

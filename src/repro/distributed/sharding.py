@@ -1,20 +1,24 @@
 """Logical-axis -> mesh-axis rules and PartitionSpec resolution.
 
 The model code annotates parameters and activations with *logical* axis
-names; this module maps them to physical mesh axes for a given mesh and
-strategy. Key strategy knobs (the §Perf levers):
+names; this module maps them to physical mesh axes for a given mesh.
+The batch dims are data parallel (over ``pod`` and ``data`` where the mesh
+has them), the ``model`` axis does tensor parallelism: heads where the
+config's ``attn_sharding`` is ``"heads"``, the sequence otherwise, and
+experts or the expert FFN dim as its ``moe_sharding`` says. Two options:
 
 * ``fsdp``          — shard the ``embed`` parameter dim over the in-pod data
-                      axis (FSDP). Off = paper-naive pure DP replication.
+                      axis (FSDP). Off = pure DP replication.
 * ``fsdp_over_pod`` — additionally shard parameters over the cross-pod axis
                       (cheap DCN traffic trade-off; off by default).
-* ``act_seq_shard`` — Megatron-style sequence sharding of the residual
-                      stream between blocks.
+
+The residual stream's sequence dims (``act_seq``, ``act_seq_rnn``) are
+never sharded.
 
 Every resolved PartitionSpec is validated against the actual tensor shape:
 a dim that does not divide evenly by its assigned mesh axes falls back to
-replication for that dim (recorded so the dry-run can report it). This is
-what makes e.g. the batch=1 ``long_500k`` cells lower cleanly.
+replication for that dim (noted in ``notes`` where the caller passes a
+list). This is what makes e.g. a batch of 1 lower cleanly.
 """
 
 from __future__ import annotations
@@ -35,46 +39,14 @@ def _mesh_sizes(mesh: Mesh) -> dict[str, int]:
 
 
 def make_rules(cfg: ModelConfig, mesh: Mesh, *, fsdp: bool = True,
-               fsdp_over_pod: bool = False,
-               act_seq_shard: bool = False,
-               parallelism: str = "tp") -> dict[str, AxisRule]:
-    """parallelism='tp' — model axis does tensor parallelism (baseline);
-    parallelism='zero3' — both in-pod axes do data parallelism and every
-    parameter is fully sharded on its embed dim (ZeRO-3 / pure-FSDP):
-    weights are all-gathered layer-by-layer, activations never cross chips;
-    parallelism='serve2d' — decode-optimised: weights stationary 2D
-    (embed x data, heads/ffn x model), KV cache batch-sharded over data,
-    decode activations replicated over data so GSPMD re-shards the (tiny)
-    token activations instead of all-gathering 8 GB weight shards per step.
-    """
+               fsdp_over_pod: bool = False) -> dict[str, AxisRule]:
+    """Logical axis -> mesh axes for ``cfg`` on ``mesh``."""
     sizes = _mesh_sizes(mesh)
     model_size = sizes.get("model", 1)
     has_pod = "pod" in sizes
 
-    if parallelism == "zero3":
-        data_axes = (("pod", "data", "model") if has_pod
-                     else ("data", "model"))
-        shard_axes = ("data", "model")
-        none_rules = {k: None for k in (
-            "vocab", "heads", "kv_heads_w", "head_dim", "ffn",
-            "ffn_sharded_w", "expert", "expert_sharded", "moe_ffn",
-            "moe_ffn_act", "rnn_tp", "rnn_blocks", "xlstm_inner",
-            "xlstm_hd", "xlstm_hd_out", "vocab_sharded", "heads_sharded",
-            "kv_heads_sharded", "seq_sharded", "kv_seq_sharded",
-            "ffn_sharded", "rnn_sharded", "xlstm_inner_sharded",
-            "xlstm_hd_sharded", "act_seq", "act_seq_rnn")}
-        return {
-            "batch": data_axes,
-            "kv_batch": data_axes,
-            "moe_groups": data_axes,
-            "layers": None,
-            "embed": shard_axes,
-            "embed_out": None,
-            **none_rules,
-        }
-
     data_axes = (("pod", "data") if has_pod else ("data",))
-    if fsdp or parallelism == "serve2d":
+    if fsdp:
         fsdp_axis: AxisRule = (("pod", "data") if (fsdp_over_pod and has_pod)
                                else ("data",))
     else:
@@ -84,13 +56,11 @@ def make_rules(cfg: ModelConfig, mesh: Mesh, *, fsdp: bool = True,
     kv_w_shardable = heads_tp and cfg.num_kv_heads % model_size == 0
     ep = cfg.moe_sharding == "expert"
 
-    serve2d = parallelism == "serve2d"
     rules: dict[str, AxisRule] = {
-        # data-parallel dims. serve2d replicates decode activations over
-        # data (tokens are tiny) while the KV cache stays batch-sharded.
-        "batch": None if serve2d else data_axes,
+        # data-parallel dims
+        "batch": data_axes,
         "kv_batch": data_axes,
-        "moe_groups": None if serve2d else data_axes,
+        "moe_groups": data_axes,
         # parameter dims
         "layers": None,
         "embed": fsdp_axis,
@@ -120,8 +90,8 @@ def make_rules(cfg: ModelConfig, mesh: Mesh, *, fsdp: bool = True,
         "rnn_sharded": "model",
         "xlstm_inner_sharded": None,
         "xlstm_hd_sharded": None,
-        "act_seq": "model" if act_seq_shard else None,
-        "act_seq_rnn": "model" if act_seq_shard else None,
+        "act_seq": None,
+        "act_seq_rnn": None,
     }
     return rules
 

@@ -9,7 +9,7 @@ per interval instead of O(N).
 
 Hardware adaptation note: inside a TPU pod there is no SSH rate limit; the
 scarce serialized resource is the cluster-controller RPC channel and the
-checkpoint-storage path, which is what the queue meters here (DESIGN.md §2).
+checkpoint-storage path, which is what the queue meters here.
 """
 
 from __future__ import annotations

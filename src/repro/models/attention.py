@@ -6,8 +6,13 @@ Three interchangeable implementations (``cfg.attn_impl``):
 * ``chunked`` — memory-efficient online-softmax scan over KV blocks
                 (flash-attention recurrence in pure JAX). This keeps the
                 lowered HLO's temporary footprint ``O(S · kv_block)`` instead
-                of ``O(S²)`` so the 32k prefill cells are roofline-sane.
+                of ``O(S²)`` for long prefills.
 * ``pallas``  — the fused Pallas TPU kernel (kernels/flash_attention).
+
+Decode reads a KV cache (``init_kv_cache``) stored in the activation
+dtype: one stacked buffer per run of layers, written in place, read by
+the masked einsum or the flash-decode kernel (``cfg.decode_impl``).
+Latent attention (MLA) caches one latent row per position instead.
 
 GQA KV-head *physical repetition*: when the KV-head count does not divide
 the model axis, k/v activations (and the KV cache) are tiled ``kv_repeat``
@@ -242,24 +247,13 @@ def attn_forward(cfg: ModelConfig, p: dict[str, jax.Array], x: jax.Array,
 # KV cache (decode path)
 # ---------------------------------------------------------------------------
 
-def quantize_kv(x: jax.Array) -> tuple[jax.Array, jax.Array]:
-    """Per (token, head) int8 symmetric quantisation along head_dim."""
-    amax = jnp.max(jnp.abs(x.astype(jnp.float32)), axis=-1, keepdims=True)
-    scale = jnp.maximum(amax, 1e-8) / 127.0
-    q = jnp.clip(jnp.round(x.astype(jnp.float32) / scale), -127, 127)
-    return q.astype(jnp.int8), scale.astype(jnp.float32)
-
-
-def dequantize_kv(q: jax.Array, scale: jax.Array, dtype) -> jax.Array:
-    return (q.astype(jnp.float32) * scale).astype(dtype)
-
-
 def init_kv_cache(cfg: ModelConfig, batch: int, max_len: int,
                   *, layers: int | None = None) -> dict[str, Any]:
     """Cache pytree (ShapeDtypeStruct-compatible via jax.eval_shape).
 
-    Positions are the last axis: per-head k and v are (B, Hkv, hd, S),
-    latent rows (B, C, S), int8 scales (B, Hkv, 1, S). The decode kernel
+    Entries are stored in the activation dtype. Positions are the last
+    axis: per-head k and v are (B, Hkv, hd, S), latent rows (B, C, S).
+    The decode kernel
     streams (hd, block) tiles of a head without a transpose, and on the
     TPU the buffer's default layout is the row-major one its call takes
     (with hd = 64 or C = 576 last, XLA would lay positions out minor and
@@ -272,14 +266,6 @@ def init_kv_cache(cfg: ModelConfig, batch: int, max_len: int,
         return {"latent": jnp.zeros(lead + (cfg.latent_width, max_len),
                                     cfg.activation_dtype)}
     shape = lead + (cfg.kv_heads_eff, cfg.hd, max_len)
-    if cfg.kv_cache_dtype == "int8":
-        sshape = shape[:-2] + (1, max_len)
-        return {
-            "k": jnp.zeros(shape, jnp.int8),
-            "v": jnp.zeros(shape, jnp.int8),
-            "k_scale": jnp.zeros(sshape, jnp.float32),
-            "v_scale": jnp.zeros(sshape, jnp.float32),
-        }
     return {
         "k": jnp.zeros(shape, cfg.activation_dtype),
         "v": jnp.zeros(shape, cfg.activation_dtype),
@@ -297,11 +283,7 @@ def kv_cache_axes(cfg: ModelConfig, *, layers: bool = True) -> dict[str, tuple]:
         ax = lead + ("kv_batch", "kv_heads_sharded", None, None)
     else:
         ax = lead + ("kv_batch", None, None, "kv_seq_sharded")
-    out = {"k": ax, "v": ax}
-    if cfg.kv_cache_dtype == "int8":
-        out["k_scale"] = ax
-        out["v_scale"] = ax
-    return out
+    return {"k": ax, "v": ax}
 
 
 def _stack_of_one(cache: dict[str, jax.Array]) -> dict[str, jax.Array]:
@@ -330,7 +312,7 @@ def _put_rows(buf: jax.Array, rows: jax.Array, layer, pos) -> jax.Array:
 
 
 def _cache_write(cache: dict[str, jax.Array], k: jax.Array, v: jax.Array,
-                 layer, pos, quantized: bool) -> dict[str, jax.Array]:
+                 layer, pos) -> dict[str, jax.Array]:
     """Write k/v as projected, (B, S', Hkv, hd), from position ``pos`` on
     into layer ``layer`` of the stacked cache (ring order handled
     upstream).
@@ -338,25 +320,15 @@ def _cache_write(cache: dict[str, jax.Array], k: jax.Array, v: jax.Array,
     ``pos`` may be a scalar (all rows at the same depth) or a (B,) vector —
     the continuous-batching case where every slot sits at its own position.
     """
-    new = {"k": k, "v": v}
-    if quantized:
-        new["k"], new["k_scale"] = quantize_kv(k)
-        new["v"], new["v_scale"] = quantize_kv(v)
     return {name: _put_rows(cache[name], rows.transpose(0, 2, 3, 1), layer,
                             pos)
-            for name, rows in new.items()}
+            for name, rows in {"k": k, "v": v}.items()}
 
 
-def _cache_read(cfg: ModelConfig, cache: dict[str, jax.Array], layer):
-    """Layer ``layer``'s k and v, (B, Hkv, hd, S), dequantized if int8."""
-    def at(name):
-        return lax.dynamic_index_in_dim(cache[name], layer, keepdims=False)
-
-    if cfg.kv_cache_dtype == "int8":
-        k = dequantize_kv(at("k"), at("k_scale"), cfg.activation_dtype)
-        v = dequantize_kv(at("v"), at("v_scale"), cfg.activation_dtype)
-        return k, v
-    return at("k"), at("v")
+def _cache_read(cache: dict[str, jax.Array], layer):
+    """Layer ``layer``'s k and v, (B, Hkv, hd, S)."""
+    return tuple(lax.dynamic_index_in_dim(cache[name], layer, keepdims=False)
+                 for name in ("k", "v"))
 
 
 def attn_decode(cfg: ModelConfig, p: dict[str, jax.Array], x: jax.Array,
@@ -395,15 +367,12 @@ def attn_decode(cfg: ModelConfig, p: dict[str, jax.Array], x: jax.Array,
         q, k = rope(q, k, posv, cfg.rope_theta)
 
     max_len = cache["k"].shape[-1]
-    quantized = cfg.kv_cache_dtype == "int8"
     write_pos = (pos % window) if window > 0 else pos
-    cache = _cache_write(cache, k, v, layer, write_pos, quantized)
+    cache = _cache_write(cache, k, v, layer, write_pos)
     axes = kv_cache_axes(cfg)
     cache = {name: shard(buf, *axes[name]) for name, buf in cache.items()}
 
-    # decode activations follow the CACHE's batch sharding (kv_batch): in
-    # serve2d mode the residual stream is replicated but attention must run
-    # batch-sharded against the sharded cache (GSPMD otherwise gathers it).
+    # decode activations follow the cache's batch sharding (kv_batch)
     if cfg.attn_sharding == "heads":
         q = shard(q, "kv_batch", None, "heads_sharded", None)
     else:
@@ -421,19 +390,14 @@ def attn_decode(cfg: ModelConfig, p: dict[str, jax.Array], x: jax.Array,
     # masked-einsum path below.
     if cfg.decode_impl == "pallas" and window == 0 and not cfg.attn_softcap:
         kv_len = (pos if per_row else jnp.broadcast_to(pos, (b,))) + 1
-        if quantized:   # the kernel reads the dequantized layer
-            ck, cv = (t[None] for t in _cache_read(cfg, cache, layer))
-            at = 0
-        else:
-            ck, cv, at = cache["k"], cache["v"], layer
-        out = _decode_kernel(cfg, q[:, 0], ck, cv, kv_len.astype(jnp.int32),
-                             at, float(scale))
+        out = _decode_kernel(cfg, q[:, 0], cache["k"], cache["v"],
+                             kv_len.astype(jnp.int32), layer, float(scale))
         out = out[:, None]                                  # (B, 1, H, hd)
         out = shard(out, "kv_batch", None, "heads_sharded", None)
         dt = x.dtype
         return jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(dt)), cache
 
-    ck, cv = _cache_read(cfg, cache, layer)
+    ck, cv = _cache_read(cache, layer)
     # slot -> absolute position (ring buffers wrap)
     slots = jnp.arange(max_len, dtype=jnp.int32)
     if window > 0:
@@ -523,8 +487,7 @@ def prefill_into_cache(cfg: ModelConfig, p: dict[str, jax.Array], x: jax.Array,
         idx = (jnp.arange(w) + (s - w)) % window
         order = jnp.argsort(idx)
         ks, vs = k[:, s - w:][:, order], v[:, s - w:][:, order]
-    cache = _cache_write(cache, ks, vs, layer, 0,
-                         cfg.kv_cache_dtype == "int8")
+    cache = _cache_write(cache, ks, vs, layer, 0)
     impl = _IMPLS[cfg.attn_impl]
     out = impl(cfg, q, k, v, positions[0] if positions.ndim > 1 else positions,
                positions[0] if positions.ndim > 1 else positions,

@@ -14,7 +14,7 @@ Design notes
   ``dtype`` once (``registry.serving_params``).
 * Homogeneous layer stacks carry a leading ``layers`` dimension and are
   executed with ``jax.lax.scan`` so the HLO contains one layer body
-  regardless of depth (essential for compile time at 512-way GSPMD).
+  regardless of depth, which keeps compile time flat in depth.
 * ``shard(x, *axes)`` inserts ``with_sharding_constraint`` with *logical*
   axes; it is a no-op outside a mesh context, so CPU unit tests run the
   exact same code path.
@@ -129,20 +129,10 @@ class ModelConfig:
     dtype: str = "bfloat16"
     param_dtype: str = "float32"
     remat_policy: str = "nothing_saveable"
-    # Unroll layer stacks into straight-line HLO instead of lax.scan.
-    # Used by the roofline measurement: XLA's cost analysis counts a scan
-    # body ONCE (not x trip count), so collective/flop extraction lowers
-    # small unrolled depths and extrapolates linearly in L.
-    unroll_layers: bool = False
-    # Chunked cross-entropy: compute logits+CE in sequence chunks of this
-    # size under remat, so the (B, S, vocab) fp32 logits tensor is never
-    # materialized. 0 = off.
-    ce_chunk: int = 0
     use_pallas: bool = False
     # decode-attention inner product: 'direct' (einsum over the full cache)
     # or 'pallas' (the flash-decode kernel, ragged per-row kv lengths).
     decode_impl: str = "direct"
-    kv_cache_dtype: str = "bfloat16"   # 'int8' enables quantised KV cache
     # Number of physical replications of KV heads so the KV-head dim divides
     # the model axis. 1 means no repetition. Set by the sharding resolver.
     kv_repeat: int = 1
@@ -180,6 +170,13 @@ class ModelConfig:
         return jnp.dtype(self.dtype)
 
     @property
+    def kv_cache_dtype(self) -> str:
+        """The KV cache's storage dtype, which is the activation dtype
+        (read-only: configuration files that state it are checked
+        against it)."""
+        return self.dtype
+
+    @property
     def weight_dtype(self) -> jnp.dtype:
         return jnp.dtype(self.param_dtype)
 
@@ -212,7 +209,7 @@ SpecTree = Any       # pytree of ParamSpec
 
 
 def spec_shapes(spec_tree: SpecTree, dtype: jnp.dtype) -> Any:
-    """ShapeDtypeStruct tree for a spec tree (used by the dry-run)."""
+    """ShapeDtypeStruct tree for a spec tree (no allocation)."""
     return jax.tree.map(
         lambda s: jax.ShapeDtypeStruct(s.shape, dtype),
         spec_tree,
@@ -432,25 +429,6 @@ def maybe_remat(fn, policy_name: str):
     if policy is None:
         return jax.checkpoint(fn)
     return jax.checkpoint(fn, policy=policy)
-
-
-def scan_or_unroll(body, carry, xs, *, unroll: bool):
-    """lax.scan, or an unrolled python loop with identical semantics.
-
-    Unrolling exists for roofline measurement (scan bodies are counted once
-    by XLA cost analysis) — see ModelConfig.unroll_layers.
-    """
-    if not unroll:
-        return lax.scan(body, carry, xs)
-    length = jax.tree.leaves(xs)[0].shape[0]
-    ys = []
-    for i in range(length):
-        carry, y = body(carry, jax.tree.map(lambda t: t[i], xs))
-        ys.append(y)
-    if ys and all(y is None for y in ys):
-        return carry, None
-    stacked = jax.tree.map(lambda *ls: jnp.stack(ls), *ys)
-    return carry, stacked
 
 
 # ---------------------------------------------------------------------------

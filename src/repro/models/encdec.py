@@ -6,7 +6,7 @@ encoder input. The backbone is faithful otherwise: LayerNorm (with bias),
 plain GELU MLPs (not gated), MHA with kv == heads, tied decoder embedding.
 Position embeddings are sinusoidal for both stacks (whisper uses learned
 decoder positions — swapped for table-free sinusoidal so one config serves
-arbitrary assigned sequence lengths; noted in DESIGN.md).
+arbitrary sequence lengths).
 
 Both stacks are homogeneous and scanned.
 """
@@ -25,7 +25,6 @@ from repro.models.common import (
     ParamSpec,
     layer_norm,
     maybe_remat,
-    scan_or_unroll,
     shard,
     sinusoidal_positions,
     softmax_cross_entropy,
@@ -123,8 +122,7 @@ def encode(cfg: ModelConfig, params: dict[str, Any], frames: jax.Array
         return h, None
 
     body = maybe_remat(body, cfg.remat_policy)
-    x, _ = scan_or_unroll(body, x, params["enc_layers"],
-                          unroll=cfg.unroll_layers)
+    x, _ = lax.scan(body, x, params["enc_layers"])
     return _ln(cfg, params["enc_ln"], x)
 
 
@@ -155,8 +153,7 @@ def decode_train(cfg: ModelConfig, params: dict[str, Any], tokens: jax.Array,
         return h, None
 
     body = maybe_remat(body, cfg.remat_policy)
-    x, _ = scan_or_unroll(body, x, params["dec_layers"],
-                          unroll=cfg.unroll_layers)
+    x, _ = lax.scan(body, x, params["dec_layers"])
     x = _ln(cfg, params["dec_ln"], x)
     logits = jnp.einsum("bsd,vd->bsv", x, params["embedding"].astype(dt))
     return shard(logits, "batch", "act_seq", "vocab_sharded")
@@ -231,9 +228,8 @@ def whisper_prefill(cfg: ModelConfig, params: dict[str, Any],
         h = h + _mlp(cfg, p["mlp"], _ln(cfg, p["ln3"], h))
         return h, (new_self, ck, cv)
 
-    x, (new_self, cross_k, cross_v) = scan_or_unroll(
-        body, x, (params["dec_layers"], cache["self"]),
-        unroll=cfg.unroll_layers)
+    x, (new_self, cross_k, cross_v) = lax.scan(
+        body, x, (params["dec_layers"], cache["self"]))
     x = _ln(cfg, params["dec_ln"], x[:, -1:])
     logits = jnp.einsum("bsd,vd->bsv", x, params["embedding"].astype(dt))
     return logits, {"self": new_self, "cross_k": cross_k, "cross_v": cross_v}
@@ -277,10 +273,9 @@ def whisper_decode_step(cfg: ModelConfig, params: dict[str, Any], cache: dict,
         h = h + _mlp(cfg, p["mlp"], _ln(cfg, p["ln3"], h))
         return h, new_self
 
-    x, new_self = scan_or_unroll(
+    x, new_self = lax.scan(
         body, x, (params["dec_layers"], cache["self"],
-                  cache["cross_k"], cache["cross_v"]),
-        unroll=cfg.unroll_layers)
+                  cache["cross_k"], cache["cross_v"]))
     x = _ln(cfg, params["dec_ln"], x)
     logits = jnp.einsum("bsd,vd->bsv", x, params["embedding"].astype(dt))
     return logits, {"self": new_self, "cross_k": cache["cross_k"],

@@ -1,7 +1,7 @@
 """Uniform model interface over all architecture families.
 
 Every family exposes the same five entry points so the training loop,
-serving loop, launcher and dry-run treat architectures opaquely (the same
+serving loop and launcher treat architectures opaquely (the same
 way AiiDA's engine treats simulation codes opaquely — criterion (ii) of the
 paper):
 
@@ -131,7 +131,7 @@ class ModelBundle:
         if cell.name == "long_500k" and \
                 self.cfg.family not in SUBQUADRATIC_FAMILIES:
             return False, "full attention is O(S^2); long_500k assigned to " \
-                          "sub-quadratic families only (see DESIGN.md)"
+                          "sub-quadratic families only"
         return True, ""
 
 

@@ -46,7 +46,7 @@ def make_rglru_block_specs(cfg: ModelConfig) -> dict[str, Any]:
     # Gates are block-diagonal (nb blocks) so the gate matmuls shard over the
     # model axis with zero communication. The official RecurrentGemma uses
     # num_heads(=10) diagonal blocks; we use 16 to align blocks with the
-    # model-axis shards (noted in DESIGN.md §hardware adaptation).
+    # model-axis shards.
     return {
         "ln": ParamSpec((d,), ("embed",), init="ones"),
         "w_y": ParamSpec((d, dr), ("embed", "rnn_tp")),        # gate branch

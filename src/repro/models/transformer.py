@@ -2,17 +2,17 @@
 
 Each homogeneous run of layers is executed with ``jax.lax.scan`` over
 parameters stacked along a leading ``layers`` dimension: the lowered HLO
-contains one layer body per run regardless of depth, which keeps 512-way
-GSPMD compiles tractable and is the standard production pattern (MaxText
-et al.). A model with ``first_dense_layers`` (deepseek-v3, moonlight) has
+contains one layer body per run regardless of depth, which keeps compile
+time flat in depth and is the standard production pattern (MaxText et
+al.). A model with ``first_dense_layers`` (deepseek-v3, moonlight) has
 two runs: ``dense_layers`` with a dense MLP, then ``layers`` with the
 expert layer; its cache holds one stacked tree per run under the same
 names. Every other model has the single ``layers`` run and a cache that is
 that run's tree. Prefill and decode carry each run's stacked cache through
 its scan and update it in place, layer by layer.
 
-Remat (activation checkpointing) wraps the scanned body; the policy is a
-config knob so the §Perf iterations can trade recompute for memory.
+Remat (activation checkpointing) wraps the scanned body in training;
+``cfg.remat_policy`` names the policy.
 """
 
 from __future__ import annotations
@@ -31,7 +31,6 @@ from repro.models.common import (
     ParamSpec,
     maybe_remat,
     rms_norm,
-    scan_or_unroll,
     shard,
     softmax_cross_entropy,
     stack_specs,
@@ -120,8 +119,7 @@ def _stack_forward(cfg: ModelConfig, params: dict[str, Any], x: jax.Array,
     carry = (x, jnp.zeros((), jnp.float32))
     for name in STACKS:
         if name in params:
-            carry, _ = scan_or_unroll(body, carry, params[name],
-                                      unroll=cfg.unroll_layers)
+            carry, _ = lax.scan(body, carry, params[name])
     return carry
 
 
@@ -180,60 +178,11 @@ def lm_forward(cfg: ModelConfig, params: dict[str, Any],
     return logits, aux
 
 
-def _chunked_ce(cfg: ModelConfig, params: dict[str, Any], x: jax.Array,
-                labels: jax.Array, mask: jax.Array | None
-                ) -> tuple[jax.Array, jax.Array]:
-    """Streamed CE: logits are computed per sequence chunk under remat so
-    the (B, S, Vp) fp32 tensor never exists — a large live-memory and
-    bytes-accessed win for big-vocab models."""
-    b, s, d = x.shape
-    c = min(cfg.ce_chunk, s)
-    while s % c:
-        c //= 2
-    n = s // c
-    xc = x.reshape(b, n, c, d).swapaxes(0, 1)           # (n, B, c, D)
-    lc = labels.reshape(b, n, c).swapaxes(0, 1)
-    mc = (mask.reshape(b, n, c).swapaxes(0, 1)
-          if mask is not None else None)
-
-    def chunk_loss(args):
-        xi, li, mi = args
-        logits = lm_logits(cfg, params, xi)
-        loss, denom = softmax_cross_entropy(logits, li, mi, cfg.vocab_size)
-        return loss * denom, denom                       # un-normalised sum
-
-    chunk_loss = jax.checkpoint(chunk_loss)
-
-    def body(carry, args):
-        tot, den = carry
-        ls, dn = chunk_loss(args)
-        return (tot + ls, den + dn), None
-
-    ms = mc if mc is not None else jnp.ones((n, b, c), jnp.float32)
-    (tot, den), _ = lax.scan(body, (jnp.zeros((), jnp.float32),
-                                    jnp.zeros((), jnp.float32)),
-                             (xc, lc, ms))
-    return tot / jnp.maximum(den, 1.0), den
-
-
 def lm_loss(cfg: ModelConfig, params: dict[str, Any],
             batch: dict[str, jax.Array]) -> tuple[jax.Array, dict[str, jax.Array]]:
-    labels = batch["labels"]
-    mask = batch.get("mask")
-    if cfg.ce_chunk:
-        tokens = batch["tokens"]
-        x = embed_tokens(cfg, params, tokens)
-        x = _maybe_prepend_patches(cfg, params, x, batch)
-        x = shard(x, "batch", "act_seq", None)
-        positions = jnp.arange(x.shape[1], dtype=jnp.int32)
-        x, aux = _stack_forward(cfg, params, x, positions)
-        if cfg.family == "vlm":
-            x = x[:, cfg.num_patches:]
-        loss, denom = _chunked_ce(cfg, params, x, labels, mask)
-    else:
-        logits, aux = lm_forward(cfg, params, batch)
-        loss, denom = softmax_cross_entropy(logits, labels, mask,
-                                            cfg.vocab_size)
+    logits, aux = lm_forward(cfg, params, batch)
+    loss, denom = softmax_cross_entropy(logits, batch["labels"],
+                                        batch.get("mask"), cfg.vocab_size)
     total = loss + 0.01 * aux
     return total, {"ce_loss": loss, "aux_loss": aux, "tokens": denom}
 
@@ -269,10 +218,9 @@ def _run_stacks(cfg: ModelConfig, params: dict[str, Any], cache, body, x):
     for name in STACKS:
         if name in params:
             n = jax.tree.leaves(params[name])[0].shape[0]
-            (x, runs[name]), _ = scan_or_unroll(
+            (x, runs[name]), _ = lax.scan(
                 lambda carry, xs: (body(carry, xs), None), (x, runs[name]),
-                (params[name], np.arange(n, dtype=np.int32)),
-                unroll=cfg.unroll_layers)
+                (params[name], np.arange(n, dtype=np.int32)))
     return x, (runs if cfg.first_dense_layers else runs["layers"])
 
 

@@ -270,7 +270,7 @@ def _mlstm_qkv_gates(cfg: ModelConfig, p: dict[str, Any], x: jax.Array,
     xm, z = up[..., :di], up[..., di:]
     # inner activations stay replicated on the model axis: the (B,S,di) ->
     # (B,S,H,hd) head reshape does not commute with a di-sharding, and this
-    # is the smallest assigned model (DP carries it; see DESIGN.md).
+    # is the smallest assigned model (data parallelism carries it).
     xm = shard(xm, "batch", "act_seq_rnn", None)
     xc, new_conv = _causal_conv(p["conv_w"], p["conv_b"], xm, conv_state)
     xc = jax.nn.silu(xc)

@@ -1,7 +1,7 @@
 """Serving steps and the continuous-batching request scheduler.
 
 ``make_prefill_step`` / ``make_decode_step`` are the single-program
-building blocks (also lowered by the dry-run for ``decode_*`` cells).
+building blocks.
 :class:`BatchScheduler` composes them into request-level micro-batching:
 
 * **admission** — FIFO queue; a free slot triggers a one-row prefill of

@@ -1,6 +1,6 @@
 """The jitted training step: microbatched grad accumulation, clipping,
 AdamW/Adafactor update. Works for every architecture family via the
-ModelBundle interface and is what the dry-run lowers for ``train_*`` cells."""
+ModelBundle interface."""
 
 from __future__ import annotations
 
@@ -34,7 +34,7 @@ def init_train_state(bundle: ModelBundle, tcfg: TrainConfig,
 
 
 def train_state_shapes(bundle: ModelBundle, tcfg: TrainConfig) -> dict[str, Any]:
-    """ShapeDtypeStruct tree (dry-run; no allocation)."""
+    """ShapeDtypeStruct tree (no allocation)."""
     pshapes = bundle.param_shapes()
     opt = jax.eval_shape(
         lambda p: optim_mod.opt_init(tcfg.optim, p), pshapes)

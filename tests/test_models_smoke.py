@@ -1,7 +1,7 @@
 """Per-architecture smoke tests: reduced configs of the SAME family run a
 forward/train step on CPU asserting output shapes + no NaNs; serving path
 (prefill + decode) is exercised for every arch. The FULL configs are only
-exercised via the dry-run (ShapeDtypeStruct, no allocation)."""
+exercised as ShapeDtypeStruct trees (no allocation)."""
 
 import jax
 import jax.numpy as jnp

@@ -28,8 +28,7 @@ RNG = np.random.default_rng(7)
 
 def _build(decode_impl="direct", **over):
     cfg = reduced_config(ARCH).replace(
-        dtype="float32", kv_cache_dtype="float32",
-        decode_impl=decode_impl, **over)
+        dtype="float32", decode_impl=decode_impl, **over)
     bundle = build(cfg)
     return bundle, bundle.init_params(jax.random.PRNGKey(0))
 
@@ -179,8 +178,7 @@ def test_prefill_equals_stepwise_decode(arch):
     """Prefilling N tokens must land in the same state as feeding those N
     tokens one decode step at a time: identical next token and identical
     greedy continuation."""
-    cfg = reduced_config(arch).replace(dtype="float32",
-                                       kv_cache_dtype="float32")
+    cfg = reduced_config(arch).replace(dtype="float32")
     bundle = build(cfg)
     params = bundle.init_params(jax.random.PRNGKey(1))
     prefill = jax.jit(make_prefill_step(bundle))
@@ -229,7 +227,7 @@ SHARDED_SERVE_PROG = textwrap.dedent("""
     from repro.serving.serve import make_decode_step, make_prefill_step
 
     cfg = reduced_config("aiida-demo-110m").replace(
-        dtype="float32", kv_cache_dtype="float32", decode_impl="pallas")
+        dtype="float32", decode_impl="pallas")
     bundle = build(cfg)
     params = bundle.init_params(jax.random.PRNGKey(0))
     rng = np.random.default_rng(0)
@@ -642,13 +640,12 @@ def test_engine_keeps_no_float32_weights():
 # ---------------------------------------------------------------------------
 
 #: each case differs from ``per_row_pos`` in one way; activations and
-#: cache are float32 unless a case names a cache dtype
+#: cache (stored in the activation dtype) are float32 unless a case names
+#: another dtype
 IN_PLACE_CASES = {
     "scalar_pos": {"per_row": False},
     "per_row_pos": {},
-    "bfloat16_cache": {"dtype": "bfloat16", "kv_cache_dtype": "bfloat16"},
-    "int8_cache": {"kv_cache_dtype": "int8"},
-    "unrolled_layers": {"unroll_layers": True},
+    "bfloat16_cache": {"dtype": "bfloat16"},
     "slot_spliced_mid_run": {"splice_at": 4},
 }
 
@@ -658,18 +655,15 @@ def _in_place_steps(case):
     step, the positions, the cache before it and (logits, cache) of the
     kernel path, the masked-einsum path and the float32 masked-einsum
     path, all three from the same cache."""
-    opts = {"dtype": "float32", "kv_cache_dtype": "float32",
-            **IN_PLACE_CASES[case]}
+    opts = {"dtype": "float32", **IN_PLACE_CASES[case]}
     per_row = opts.pop("per_row", True)
     splice_at = opts.pop("splice_at", None)
     cfg = reduced_config(ARCH).replace(attn_kv_block=16, **opts)
     kernel = build(cfg.replace(decode_impl="pallas"))
     params = kernel.init_params(jax.random.PRNGKey(3))
-    f32 = {"dtype": "float32"}
-    if cfg.kv_cache_dtype != "int8":
-        f32["kv_cache_dtype"] = "float32"
-    paths = [jax.jit(build(cfg.replace(decode_impl=impl, **over)).decode_fn)
-             for impl, over in (("direct", {}), ("direct", f32))]
+    paths = [jax.jit(build(cfg.replace(decode_impl="direct",
+                                       dtype=dt)).decode_fn)
+             for dt in (cfg.dtype, "float32")]
     step = jax.jit(kernel.decode_fn, donate_argnums=(1,))
     prefill = jax.jit(make_prefill_step(kernel))
     insert = jax.jit(BatchScheduler._insert_row_impl, donate_argnums=(0,))
@@ -713,8 +707,8 @@ def test_in_place_kernel_decode_matches_masked_einsum(case):
     float32 that is 1e-5 of the logits' size; in bfloat16 it is how far
     bfloat16 moves the masked-einsum path itself from its float32 run over
     these steps. Cases: one position for all rows or one per row, a
-    bfloat16 or int8 cache, scanned or unrolled layers, and a slot spliced
-    in by the scheduler's ``_insert_row`` mid-run."""
+    bfloat16 cache, and a slot spliced in by the scheduler's
+    ``_insert_row`` mid-run."""
     steps = list(_in_place_steps(case))
     bf16 = IN_PLACE_CASES[case].get("dtype") == "bfloat16"
     spread = max(np.abs(e[0] - e32[0]).max() for *_, e, e32 in steps)
