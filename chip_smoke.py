@@ -213,9 +213,8 @@ def serve_phase(workdir: Path, seed: int) -> None:
           f"{eng.cfg.d_model}, {eng.cfg.num_layers} layers, vocab "
           f"{eng.cfg.vocab_size})")
     sched = eng.scheduler
-    text = sched.decode_step.lower(eng.params, sched.cache,
-                                   jnp.asarray(sched.tokens),
-                                   jnp.asarray(sched.pos)).as_text()
+    text = sched.decode_step.lower(eng.params, sched.cache, sched.tokens,
+                                   sched.pos, sched.active).as_text()
     check("tpu_custom_call" in text,
           "engine decode step contains the Pallas kernel (tpu_custom_call)")
     check_decode_kernel(eng.cfg, sched.batch_size, sched.max_len, seed)

@@ -7,12 +7,27 @@ building blocks.
 * **admission** — FIFO queue; a free slot triggers a one-row prefill of
   the request's exact prompt (no padding, so the first sampled token is
   taken at the true last prompt position) whose KV rows are spliced into
-  the slot's row of the shared batch cache;
+  the slot's row of the shared batch cache, in the same jitted op that
+  sets the slot's token, position and mask on the device;
 * **per-slot positions** — every decode step runs ONE program over the
   whole batch with a ``(B,)`` position vector (``attn_decode``'s per-row
   path), so co-batched requests at different depths neither pad nor
   re-compile; with ``decode_impl='pallas'`` the ragged depths feed the
   flash-decode kernel's scalar-prefetch lengths directly;
+* **device-resident control** — each slot's last token, its position and
+  the active-row mask live on the device. A step takes its tokens from
+  the last step's output and advances the positions of active rows only;
+  an empty row's position stays frozen. The host sends the mask again
+  only when a slot is evicted;
+* **one step ahead** — ``step()`` launches decode step n+1 before it
+  fetches step n's tokens, so the device runs n+1 while the host fetches
+  and does its per-slot work. A slot that ends by ``max_new_tokens`` or
+  cache exhaustion is known from the host's counts when n+1 is launched
+  and is not launched past its end. An EOS is learned one step late: the
+  token of the step launched past it is dropped (``serving.overrun_steps``
+  counts such steps) and the step still counts as a decode step.
+  ``serving.steps_ahead`` counts the steps launched while the previous
+  step's tokens were still unfetched; ``run()`` drains the step in flight;
 * **eviction** — EOS, ``max_new_tokens`` or cache exhaustion frees the
   slot for the next queued request mid-flight;
 * **weights** — every tree given to the scheduler is stored in the
@@ -23,9 +38,11 @@ building blocks.
   ``serving.cache_bytes`` reads its bytes;
 * **metrics** — per-request latency and token counts land in the
   process-wide observability registry (``serving.*``).
-* **spans** — ``serving.admit`` per request; ``serving.step`` and its
-  ``serving.fetch`` per decode step, unpersisted (profiler only), so a
-  long generation adds no rows to its process timeline.
+* **spans** — ``serving.admit`` per request; ``serving.step`` per call of
+  ``step()``, holding the launch of the next decode step and then
+  ``serving.fetch``, the fetch of the step before it; unpersisted
+  (profiler only), so a long generation adds no rows to its process
+  timeline.
 
 Greedy decoding throughout: a given (model, prompt) pair always yields
 the same continuation, which is what lets generations participate in the
@@ -67,6 +84,18 @@ def make_decode_step(bundle: ModelBundle) -> Callable:
     return serve_step
 
 
+def make_scheduled_step(bundle: ModelBundle) -> Callable:
+    """The scheduler's decode step: ``make_decode_step``'s, which also
+    returns the positions advanced for the active rows."""
+    decode = make_decode_step(bundle)
+
+    def serve_step(params, cache, tokens, pos, active):
+        next_tok, cache = decode(params, cache, tokens, pos)
+        return next_tok, cache, pos + active.astype(pos.dtype)
+
+    return serve_step
+
+
 # ---------------------------------------------------------------------------
 # Continuous-batching scheduler (host-side control, one jitted decode step)
 # ---------------------------------------------------------------------------
@@ -86,6 +115,11 @@ class Request:
     submitted_at: float = 0.0
     started_at: float = 0.0
     finished_at: float = 0.0
+
+
+#: a launched decode step: its tokens on the device, and the (slot,
+#: request) rows it advances
+_Launched = tuple[jax.Array, list[tuple[int, Request]]]
 
 
 class BatchScheduler:
@@ -119,25 +153,31 @@ class BatchScheduler:
         self.max_pending = max_pending
         self.queue: deque[Request] = deque()
         self.slots: list[Request | None] = [None] * batch_size
-        self.decode_step = jax.jit(make_decode_step(bundle),
+        self.decode_step = jax.jit(make_scheduled_step(bundle),
                                    donate_argnums=(1,))
         # one-row prefill; retraces per distinct prompt length (serving
         # workloads draw from a small set of lengths — see docs/serving.md)
         self.prefill_step = jax.jit(make_prefill_step(bundle))
-        self._insert_row = jax.jit(self._insert_row_impl, donate_argnums=(0,))
+        self._insert_row = jax.jit(self._admit_row_impl, donate_argnums=(0,))
         self.cache = bundle.init_cache(batch_size, max_len)
         get_registry().gauge("serving.cache_bytes").set(sum(
             leaf.nbytes for leaf in jax.tree.leaves(self.cache)))
-        # host-side control state: last token + cache depth per slot. Empty
-        # slots keep a frozen pos — their rows are never read, and admission
-        # overwrites the whole row before re-activating one.
-        self.tokens = np.zeros((batch_size, 1), np.int32)
-        self.pos = np.zeros(batch_size, np.int32)
+        # device-resident control state: last token, cache depth and mask
+        # per slot. Empty slots keep a frozen pos — their rows are never
+        # read, and admission overwrites the whole row before re-activating
+        # one. ``_active`` is the host's copy of the device's mask.
+        self.tokens = jnp.zeros((batch_size, 1), jnp.int32)
+        self.pos = jnp.zeros(batch_size, jnp.int32)
+        self.active = jnp.zeros(batch_size, bool)
+        self._active = np.zeros(batch_size, bool)
+        self._in_flight: _Launched | None = None
         reg = get_registry()
         self._m_submitted = reg.counter("serving.requests_submitted")
         self._m_completed = reg.counter("serving.requests_completed")
         self._m_evicted = reg.counter("serving.slot_evictions")
         self._m_decode_steps = reg.counter("serving.decode_steps")
+        self._m_steps_ahead = reg.counter("serving.steps_ahead")
+        self._m_overrun = reg.counter("serving.overrun_steps")
         self._m_prefill_tokens = reg.counter("serving.prefill_tokens")
         self._m_tokens = reg.counter("serving.tokens_generated")
         self._g_active = reg.gauge("serving.slots_active")
@@ -182,6 +222,15 @@ class BatchScheduler:
                 f, r.astype(f.dtype), slot, axis=1),
             full_cache, row_cache)
 
+    @staticmethod
+    def _admit_row_impl(cache, tokens, pos, active, row_cache, first_tok,
+                        slot, length):
+        """Splice a prefilled row into the cache and start its slot: the
+        first token, the prompt's length as position, the row active."""
+        return (BatchScheduler._insert_row_impl(cache, row_cache, slot),
+                tokens.at[slot].set(first_tok[0]), pos.at[slot].set(length),
+                active.at[slot].set(True))
+
     def _prefill_into_slot(self, req: Request, slot: int) -> None:
         with trace.span("serving.admit"):
             req.started_at = time.monotonic()
@@ -189,12 +238,13 @@ class BatchScheduler:
             row_cache = self.bundle.init_cache(1, self.max_len)
             first_tok, row_cache = self.prefill_step(
                 self.params, {"tokens": prompt}, row_cache)
-            self.cache = self._insert_row(self.cache, row_cache,
-                                          jnp.asarray(slot, jnp.int32))
+            self.cache, self.tokens, self.pos, self.active = \
+                self._insert_row(self.cache, self.tokens, self.pos,
+                                 self.active, row_cache, first_tok,
+                                 np.int32(slot), np.int32(len(req.prompt)))
+            self._active[slot] = True
             self.slots[slot] = req
-            self.pos[slot] = len(req.prompt)
             tok = int(jax.device_get(first_tok)[0, 0])
-            self.tokens[slot, 0] = tok
             req.generated = [tok]
             self._m_prefill_tokens.inc(len(req.prompt))
             self._m_tokens.inc()
@@ -208,61 +258,104 @@ class BatchScheduler:
                 continue
             req = self.queue.popleft()
             self._prefill_into_slot(req, i)
-            if self._maybe_finish(i):
+            if self._maybe_finish(req):
+                self.slots[i] = None
                 finished.append(req)
         self._g_queue.set(len(self.queue))
         self._g_active.set(sum(s is not None for s in self.slots))
         return finished
 
     # -- eviction ------------------------------------------------------------
-    def _maybe_finish(self, slot: int) -> bool:
-        req = self.slots[slot]
-        if req.generated and req.generated[-1] == self.eos_id:
+    def _has_room(self, req: Request, tokens: int) -> bool:
+        """Whether a request that holds ``tokens`` tokens decodes another:
+        it wants more, and the cache has a position past the last one."""
+        return (tokens < req.max_new_tokens
+                and len(req.prompt) + tokens < self.max_len)
+
+    def _maybe_finish(self, req: Request) -> bool:
+        if req.generated[-1] == self.eos_id:
             req.finish_reason = "eos"
         elif len(req.generated) >= req.max_new_tokens:
             req.finish_reason = "length"
-        elif int(self.pos[slot]) >= self.max_len - 1:
+        elif not self._has_room(req, len(req.generated)):
             req.finish_reason = "cache_full"
         else:
             return False
         req.done = True
         req.finished_at = time.monotonic()
-        self.slots[slot] = None
         self._m_completed.inc()
         self._m_evicted.inc()
         self._h_latency.observe(req.finished_at - req.submitted_at)
         return True
 
+    def _release_ending(self) -> None:
+        """Free the slots whose request gets its last token from the step
+        in flight, so that no step is launched past its end."""
+        pending = dict(self._in_flight[1]) if self._in_flight else {}
+        for i, req in enumerate(self.slots):
+            if req is not None and not self._has_room(
+                    req, len(req.generated) + (pending.get(i) is req)):
+                self.slots[i] = None
+
     # -- the decode loop -----------------------------------------------------
+    def _launch(self) -> _Launched | None:
+        """Launch one decode step over the occupied slots, fed from the
+        device's tokens and positions; None when no slot is occupied."""
+        rows = [(i, r) for i, r in enumerate(self.slots) if r is not None]
+        if not rows:
+            return None
+        occupied = np.array([r is not None for r in self.slots])
+        if not np.array_equal(occupied, self._active):   # a slot was evicted
+            self._active = occupied
+            self.active = jnp.asarray(occupied)
+        if self._in_flight is not None:
+            self._m_steps_ahead.inc()
+        next_tok, self.cache, self.pos = self.decode_step(
+            self.params, self.cache, self.tokens, self.pos, self.active)
+        next_tok.copy_to_host_async()
+        self.tokens = next_tok
+        self._m_decode_steps.inc()
+        return next_tok, rows
+
+    def _collect(self, launched: _Launched) -> list[Request]:
+        """Fetch a launched step's tokens and hand them to its requests;
+        returns the requests that finished."""
+        next_tok, rows = launched
+        with trace.span("serving.fetch", persist=False):
+            next_host = jax.device_get(next_tok)[:, 0]
+        finished, dropped = [], False
+        for i, req in rows:
+            if req.done:          # ended by an EOS learned after the launch
+                dropped = True
+                continue
+            req.generated.append(int(next_host[i]))
+            self._m_tokens.inc()
+            if self._maybe_finish(req):
+                finished.append(req)
+                if self.slots[i] is req:
+                    self.slots[i] = None
+        if dropped:
+            self._m_overrun.inc()
+        return finished
+
     def step(self) -> list[Request]:
-        """Admit waiting requests, then run ONE decode step across all
-        active slots; returns the requests that finished this step."""
+        """Admit waiting requests, launch the next decode step across all
+        active slots, then fetch the step launched before it; returns the
+        requests that finished."""
         with trace.span("serving.step", persist=False):
+            self._release_ending()
             finished = self._admit()
-            active = [i for i, s in enumerate(self.slots) if s is not None]
-            if not active:
-                self._g_active.set(0)
-                return finished
-            next_tok, self.cache = self.decode_step(
-                self.params, self.cache, jnp.asarray(self.tokens),
-                jnp.asarray(self.pos, jnp.int32))
-            self._m_decode_steps.inc()
-            with trace.span("serving.fetch", persist=False):
-                next_host = jax.device_get(next_tok)[:, 0]
-            for i in active:
-                req = self.slots[i]
-                req.generated.append(int(next_host[i]))
-                self.pos[i] += 1
-                self.tokens[i, 0] = int(next_host[i])
-                self._m_tokens.inc()
-                if self._maybe_finish(i):
-                    finished.append(req)
+            before, self._in_flight = self._in_flight, self._launch()
+            if before is not None:
+                finished += self._collect(before)
             self._g_active.set(sum(s is not None for s in self.slots))
             return finished
 
     def run(self) -> list[Request]:
-        """Drain queue + slots to completion; finished in completion order."""
+        """Drain queue, slots and the step in flight to completion;
+        finished in completion order."""
         finished: list[Request] = []
-        while self.queue or any(s is not None for s in self.slots):
+        while (self.queue or self._in_flight is not None
+               or any(s is not None for s in self.slots)):
             finished.extend(self.step())
         return finished

@@ -170,6 +170,160 @@ def test_pallas_decode_matches_direct(lm):
 
 
 # ---------------------------------------------------------------------------
+# the one-step-ahead decode loop
+# ---------------------------------------------------------------------------
+
+def _counts():
+    from repro.observability.metrics import get_registry
+
+    reg = get_registry()
+    return {k: reg.counter(f"serving.{k}").value
+            for k in ("decode_steps", "steps_ahead", "overrun_steps")}
+
+
+def _since(before):
+    return {k: v - before[k] for k, v in _counts().items()}
+
+
+def _serial(bundle, params, prompt, new_tokens, *, max_len=64, eos_id=-1):
+    """One request alone: prefill, then ``make_decode_step`` with its token
+    fetched after every step, stopped by EOS, length or a full cache."""
+    prefill = jax.jit(make_prefill_step(bundle))
+    decode = jax.jit(make_decode_step(bundle))
+    tok, cache = prefill(params,
+                         {"tokens": jnp.asarray(prompt, jnp.int32)[None]},
+                         bundle.init_cache(1, max_len))
+    out, pos = [int(jax.device_get(tok)[0, 0])], len(prompt)
+    while (out[-1] != eos_id and len(out) < new_tokens
+           and pos < max_len - 1):
+        tok, cache = decode(params, cache, tok, jnp.asarray([pos], jnp.int32))
+        out.append(int(jax.device_get(tok)[0, 0]))
+        pos += 1
+    return out
+
+
+def _late_eos(tokens):
+    """A token first produced by a decode step (not by the prefill)."""
+    return next(t for k, t in enumerate(tokens) if k and t not in tokens[:k])
+
+
+@pytest.mark.parametrize("eos", [False, True], ids=["eos_off", "eos_on"])
+def test_scheduler_tokens_equal_a_serial_loop(lm, eos):
+    """Five prompts of mixed lengths over four slots: every request's
+    tokens equal a plain loop over ``make_decode_step`` that fetches after
+    every step; with EOS on, an EOS ends a request mid-batch and its slot
+    takes the fifth request while a step is in flight."""
+    bundle, params = lm
+    rng = np.random.default_rng(19)
+    prompts = [rng.integers(1, 500, n).tolist() for n in (3, 9, 5, 12, 7)]
+    news = [6, 10, 4, 8, 9]
+    eos_id = -1
+    if eos:
+        eos_id = _late_eos(_serial(bundle, params, prompts[0], news[0]))
+    want = [_serial(bundle, params, p, n, eos_id=eos_id)
+            for p, n in zip(prompts, news)]
+    sched = BatchScheduler(bundle, params, batch_size=4, max_len=64,
+                           eos_id=eos_id)
+    reqs = [Request(rid=i, prompt=p, max_new_tokens=n)
+            for i, (p, n) in enumerate(zip(prompts, news))]
+    before = _counts()
+    for r in reqs:
+        sched.submit(r)
+    sched.run()
+    got = _since(before)
+    assert [r.generated for r in reqs] == want
+    assert all(r.done for r in reqs) and sched._in_flight is None
+    # the loop starts from nothing once, and every later step is ahead
+    assert got["steps_ahead"] == got["decode_steps"] - 1
+    if eos:
+        assert reqs[0].finish_reason == "eos"
+        assert got["overrun_steps"] >= 1
+    else:
+        assert {r.finish_reason for r in reqs} == {"length"}
+        assert got["overrun_steps"] == 0
+
+
+def test_late_eos_drops_the_overrun_token_and_readmits(lm):
+    """One slot: the EOS of the first request is learned while the step
+    after it runs; that step's token is dropped and counted, and the
+    second request, admitted while it is in flight, gets its own tokens."""
+    bundle, params = lm
+    rng = np.random.default_rng(23)
+    prompts = [rng.integers(1, 500, 6).tolist() for _ in range(2)]
+    eos_id = _late_eos(_serial(bundle, params, prompts[0], 8))
+    want = [_serial(bundle, params, p, 8, eos_id=eos_id) for p in prompts]
+    before = _counts()
+    sched, reqs = _serve(bundle, params, prompts, 8, batch=1, eos_id=eos_id)
+    got = _since(before)
+    assert [r.generated for r in reqs] == want
+    assert reqs[0].finish_reason == "eos" and reqs[0].generated[-1] == eos_id
+    assert reqs[1].started_at >= reqs[0].finished_at
+    late = sum(r.finish_reason == "eos" and len(r.generated) < 8
+               for r in reqs)
+    assert got["overrun_steps"] == late >= 1
+    # the steps that ran: each request's, and one past each late EOS
+    assert got["decode_steps"] == sum(len(r.generated) - 1
+                                      for r in reqs) + late
+    # the second request's first step was launched with the overrun step
+    # still in flight, so the loop started from nothing only once
+    assert got["steps_ahead"] == got["decode_steps"] - 1
+
+
+@pytest.mark.parametrize("case", ["max_new_tokens_1", "cache_full"])
+def test_lookahead_launches_no_step_past_a_known_end(lm, case):
+    """A request that ends by length or a full cache ends as a serial loop
+    ends it, and no step is launched past it: the device positions stop
+    where the last step left them."""
+    bundle, params = lm
+    rng = np.random.default_rng(29)
+    if case == "max_new_tokens_1":
+        prompts, news, max_len = [rng.integers(1, 500, 5).tolist()
+                                  for _ in range(3)], [1, 1, 1], 64
+    else:   # one row fills the cache, its neighbour ends by length
+        prompts = [rng.integers(1, 500, n).tolist() for n in (12, 5)]
+        news, max_len = [100, 3], 16
+    sched = BatchScheduler(bundle, params, batch_size=2, max_len=max_len)
+    reqs = [Request(rid=i, prompt=p, max_new_tokens=n)
+            for i, (p, n) in enumerate(zip(prompts, news))]
+    before = _counts()
+    for r in reqs:
+        sched.submit(r)
+    sched.run()
+    got = _since(before)
+    assert [r.generated for r in reqs] == [
+        _serial(bundle, params, p, n, max_len=max_len)
+        for p, n in zip(prompts, news)]
+    assert got["overrun_steps"] == 0
+    if case == "max_new_tokens_1":
+        assert {r.finish_reason for r in reqs} == {"length"}
+        assert got["decode_steps"] == got["steps_ahead"] == 0
+    else:
+        assert [r.finish_reason for r in reqs] == ["cache_full", "length"]
+        assert len(reqs[0].generated) == max_len - len(prompts[0])
+        assert got["decode_steps"] == len(reqs[0].generated) - 1
+        assert np.asarray(sched.pos).tolist() == [
+            max_len - 1, len(prompts[1]) + news[1] - 1]
+
+
+def test_decode_steps_and_steps_ahead_per_request(lm):
+    """EOS off, one request at a time: a request of n tokens runs n - 1
+    decode steps, and all but its first are launched ahead."""
+    bundle, params = lm
+    rng = np.random.default_rng(31)
+    news = [2, 5, 9, 16]
+    sched = BatchScheduler(bundle, params, batch_size=4, max_len=64)
+    before = _counts()
+    for i, n in enumerate(news):
+        sched.submit(Request(rid=i, prompt=rng.integers(1, 500, 7).tolist(),
+                             max_new_tokens=n))
+        assert [len(r.generated) for r in sched.run()] == [n]
+    got = _since(before)
+    assert got["decode_steps"] == sum(n - 1 for n in news)
+    assert got["steps_ahead"] == got["decode_steps"] - len(news)
+    assert got["overrun_steps"] == 0
+
+
+# ---------------------------------------------------------------------------
 # prefill/decode parity
 # ---------------------------------------------------------------------------
 
@@ -581,13 +735,11 @@ def test_step_programs_convert_no_weight():
         if name not in F32_LEAF_NAMES:
             shapes.add("x".join(map(str, leaf.shape)))
             shapes.add("x".join(map(str, leaf.shape[1:])))
-    tokens = jnp.zeros((3, 1), jnp.int32)
-    pos = jnp.zeros(3, jnp.int32)
     prompt = {"tokens": jnp.zeros((1, 7), jnp.int32)}
 
     def lowered(tree):
-        return (sched.decode_step.lower(tree, sched.cache, tokens,
-                                        pos).as_text(),
+        return (sched.decode_step.lower(tree, sched.cache, sched.tokens,
+                                        sched.pos, sched.active).as_text(),
                 sched.prefill_step.lower(tree, prompt,
                                          bundle.init_cache(1, 64)).as_text())
 
