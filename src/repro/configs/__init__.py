@@ -17,6 +17,7 @@ ARCH_IDS = [
     "grok-1-314b",
     "moonshot-v1-16b-a3b",
     "moonlight-16b-a3b",
+    "granite-4.0-h-small",
     "recurrentgemma-2b",
     "llava-next-34b",
     "whisper-large-v3",
@@ -69,6 +70,15 @@ def reduced_config(arch_id: str) -> ModelConfig:
                   qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
                   moe_d_ff=64, shared_d_ff=128, num_experts=8,
                   num_experts_per_tok=3, experts_held=4, expert_offset=4)
+    if cfg.layer_types:
+        # Mamba-2 layers around one attention layer; 4 heads of 16 with
+        # d_state 16 and chunks of 8; expert layers that hold 4 of 8
+        # routed experts (the first half), with a shared expert
+        kw.update(num_layers=4, num_kv_heads=2,
+                  layer_types=("mamba", "mamba", "attention", "mamba"),
+                  ssm_heads=4, ssm_head_dim=16, ssm_state=16, ssm_chunk=8,
+                  moe_d_ff=64, shared_d_ff=128, num_experts=8,
+                  num_experts_per_tok=3, experts_held=4, expert_offset=0)
     if cfg.family == "hybrid":
         kw.update(num_layers=3, d_rnn=128, local_window=32)
     if cfg.family == "ssm":

@@ -99,6 +99,24 @@ class ModelConfig:
     expert_offset: int = 0
     first_dense_layers: int = 0      # leading layers with a dense MLP of d_ff
 
+    # softmax top-k scores (granitemoe: the gate is the softmax over the k
+    # largest router logits) or sigmoid scores with a correction bias
+    # (deepseek-v3); ragged layer only
+    moe_score: str = "sigmoid"
+
+    # --- layer kinds in order (granitemoehybrid) ------------------------------
+    # one of 'attention' | 'mamba' per layer, the first ``num_layers`` read;
+    # () -> every layer attention
+    layer_types: tuple[str, ...] = ()
+
+    # --- Mamba-2 mixer (models/mamba2.py) --------------------------------------
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_state: int = 0               # d_state: columns of B and C
+    ssm_groups: int = 1              # groups of heads that share B and C
+    ssm_conv: int = 4                # causal depthwise conv taps
+    ssm_chunk: int = 256             # chunk of the SSD prefill
+
     # --- latent attention (MLA, deepseek-v2/v3; q_lora_rank null) -----------
     kv_lora_rank: int = 0            # > 0: latent attention, latent cache
     qk_nope_head_dim: int = 0
@@ -154,6 +172,16 @@ class ModelConfig:
     def held_experts(self) -> int:
         """Routed experts whose weights this layer holds."""
         return self.experts_held or self.num_experts
+
+    @property
+    def ssm_inner(self) -> int:
+        """Channels of the Mamba-2 mixer: heads x head width."""
+        return self.ssm_heads * self.ssm_head_dim
+
+    @property
+    def ssm_conv_dim(self) -> int:
+        """Channels the causal conv runs over: x, then B and C."""
+        return self.ssm_inner + 2 * self.ssm_groups * self.ssm_state
 
     @property
     def latent_width(self) -> int:

@@ -16,7 +16,8 @@ Two sharding strategies (resolved per architecture):
   (grok: 8 experts do not divide a 16-way axis, but d_ff=32768 does).
 
 ``moe_impl='ragged'`` is the no-drop layer of one expert-parallel chip
-(deepseek-v3 / moonlight): it routes over all ``num_experts``, computes
+(deepseek-v3 / moonlight, granite-4.0-h; ``moe_score`` picks the
+router's score function): it routes over all ``num_experts``, computes
 only the experts it holds (``experts_held`` from ``expert_offset``) with
 a grouped matmul over the (token, choice) pairs sorted by expert, and adds
 the shared experts whole. Pairs routed to experts held elsewhere add
@@ -168,12 +169,13 @@ def _make_ragged_specs(cfg: ModelConfig) -> dict:
     f, n = cfg.moe_d_ff or cfg.d_ff, cfg.held_experts
     specs = {
         "router": ParamSpec((d, e), ("embed", None), f32_at_use=True),
-        "router_bias": ParamSpec((e,), (None,), init="zeros",
-                                 f32_at_use=True),
         "w_gate": ParamSpec((n, d, f), ("expert_sharded", "embed", "moe_ffn")),
         "w_up": ParamSpec((n, d, f), ("expert_sharded", "embed", "moe_ffn")),
         "w_down": ParamSpec((n, f, d), ("expert_sharded", "moe_ffn", "embed")),
     }
+    if cfg.moe_score == "sigmoid":
+        specs["router_bias"] = ParamSpec((e,), (None,), init="zeros",
+                                         f32_at_use=True)
     if cfg.shared_d_ff:
         specs["shared"] = make_mlp_specs(cfg, cfg.shared_d_ff)
     return specs
@@ -182,14 +184,19 @@ def _make_ragged_specs(cfg: ModelConfig) -> dict:
 def route(cfg: ModelConfig, p: dict[str, jax.Array], xt: jax.Array
           ) -> tuple[jax.Array, jax.Array]:
     """(experts (T, k), gate weights (T, k) float32) of tokens xt (T, D),
-    computed in float32: sigmoid scores, the k chosen by score plus the
-    correction bias, gated by their unbiased scores normalised over the k
-    and scaled by ``moe_routed_scale``. The router's product runs at
-    ``HIGHEST``: a TPU's default precision would round its float32
-    operands to bfloat16 and flip choices that are no near-ties."""
+    computed in float32. ``moe_score`` 'sigmoid': sigmoid scores, the k
+    chosen by score plus the correction bias, gated by their unbiased
+    scores normalised over the k and scaled by ``moe_routed_scale``.
+    'softmax': the k largest logits, gated by their softmax. The router's
+    product runs at ``HIGHEST``: a TPU's default precision would round its
+    float32 operands to bfloat16 and flip choices that are no
+    near-ties."""
     logits = jnp.einsum("td,de->te", xt.astype(jnp.float32),
                         p["router"].astype(jnp.float32),
                         precision=lax.Precision.HIGHEST)
+    if cfg.moe_score == "softmax":
+        top, idx = lax.top_k(logits, cfg.num_experts_per_tok)
+        return idx, jax.nn.softmax(top, axis=-1)
     scores = jax.nn.sigmoid(logits)
     _, idx = lax.top_k(scores + p["router_bias"].astype(jnp.float32),
                        cfg.num_experts_per_tok)

@@ -1,15 +1,29 @@
-"""Decoder-only LM covering the dense, MoE and VLM families.
+"""Decoder-only LM covering the dense, MoE and VLM families, and the
+granitemoehybrid layers that interleave Mamba-2 mixers with attention.
 
-Each homogeneous run of layers is executed with ``jax.lax.scan`` over
+The stack is a list of *runs* (``layer_runs``): maximal stretches of
+consecutive layers of one kind, in published order. A layer's kind is its
+mixer (attention, or a Mamba-2 mixer) and its feed-forward part (a dense
+MLP, or the expert layer). Each run executes with ``jax.lax.scan`` over
 parameters stacked along a leading ``layers`` dimension: the lowered HLO
 contains one layer body per run regardless of depth, which keeps compile
 time flat in depth and is the standard production pattern (MaxText et
-al.). A model with ``first_dense_layers`` (deepseek-v3, moonlight) has
-two runs: ``dense_layers`` with a dense MLP, then ``layers`` with the
-expert layer; its cache holds one stacked tree per run under the same
-names. Every other model has the single ``layers`` run and a cache that is
-that run's tree. Prefill and decode carry each run's stacked cache through
-its scan and update it in place, layer by layer.
+al.).
+
+* A model with neither ``layer_types`` nor ``first_dense_layers`` is one
+  run, ``layers``, and its cache is that run's tree.
+* ``first_dense_layers`` (deepseek-v3, moonlight) makes two runs:
+  ``dense_layers`` with a dense MLP, then ``layers`` with the expert layer.
+* ``layer_types`` (granite-4.0-h) names each layer's mixer; a run is named
+  by its mixer and the index of its first layer (``mamba0``,
+  ``attention5``, ``mamba6`` ...).
+
+With more than one run, the parameter tree and the cache hold one stacked
+tree per run under the run's name: the KV cache of an attention run, the
+recurrent state (``models/mamba2.py``: conv inputs and the SSM state) of a
+Mamba run, both with batch on axis 1. Prefill and decode carry each run's
+stacked cache or state through its scan and update it in place, layer by
+layer.
 
 Remat (activation checkpointing) wraps the scanned body in training;
 ``cfg.remat_policy`` names the policy.
@@ -17,7 +31,8 @@ Remat (activation checkpointing) wraps the scanned body in training;
 
 from __future__ import annotations
 
-from typing import Any
+import itertools
+from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -25,6 +40,7 @@ import numpy as np
 from jax import lax
 
 from repro.models import attention as attn
+from repro.models import mamba2
 from repro.models import mlp as mlp_mod
 from repro.models.common import (
     ModelConfig,
@@ -41,15 +57,52 @@ from repro.models.common import (
 # Parameter specs
 # ---------------------------------------------------------------------------
 
-def make_layer_specs(cfg: ModelConfig, *, dense: bool = False
-                     ) -> dict[str, Any]:
-    """One layer; ``dense`` for a leading dense layer of an MoE model."""
+class Run(NamedTuple):
+    """A run of consecutive layers of one kind."""
+
+    name: str           # its key in the parameter and cache trees
+    mixer: str          # 'attention' | 'mamba'
+    moe: bool           # the expert layer, or a dense MLP
+    layers: int
+
+
+#: each mixer's (norm key, parameter key) in a layer's tree
+MIXERS = {"attention": ("ln_attn", "attn"), "mamba": ("ln_ssm", "ssm")}
+
+
+def layer_runs(cfg: ModelConfig) -> list[Run]:
+    """The stack's runs in the order they run."""
+    moe = cfg.family == "moe"
+    if cfg.layer_types:
+        runs, first = [], 0
+        for mixer, group in itertools.groupby(
+                cfg.layer_types[:cfg.num_layers]):
+            n = len(tuple(group))
+            runs.append(Run(f"{mixer}{first}", mixer, moe, n))
+            first += n
+        return runs
+    n0 = cfg.first_dense_layers
+    runs = [Run("dense_layers", "attention", False, n0)] if n0 else []
+    return runs + [Run("layers", "attention", moe, cfg.num_layers - n0)]
+
+
+def _one_run(runs: list[Run]) -> bool:
+    """The stack is the single run ``layers``, whose cache is its tree."""
+    return len(runs) == 1 and runs[0].name == "layers"
+
+
+def make_layer_specs(cfg: ModelConfig, *, dense: bool = False,
+                     mixer: str = "attention") -> dict[str, Any]:
+    """One layer; ``dense`` for a layer with a dense MLP in an MoE
+    model."""
+    norm, key = MIXERS[mixer]
     specs: dict[str, Any] = {
-        "ln_attn": ParamSpec((cfg.d_model,), ("embed",), init="ones",
-                             f32_at_use=True),
+        norm: ParamSpec((cfg.d_model,), ("embed",), init="ones",
+                        f32_at_use=True),
         "ln_mlp": ParamSpec((cfg.d_model,), ("embed",), init="ones",
                             f32_at_use=True),
-        "attn": attn.make_attn_specs(cfg),
+        key: (attn.make_attn_specs(cfg) if mixer == "attention"
+              else mamba2.make_ssm_specs(cfg)),
     }
     if cfg.family == "moe" and not dense:
         specs["moe"] = mlp_mod.make_moe_specs(cfg)
@@ -60,16 +113,15 @@ def make_layer_specs(cfg: ModelConfig, *, dense: bool = False
 
 def make_lm_specs(cfg: ModelConfig) -> dict[str, Any]:
     vp = cfg.padded_vocab
-    n0 = cfg.first_dense_layers
     specs: dict[str, Any] = {
         "embedding": ParamSpec((vp, cfg.d_model), ("vocab", "embed")),
-        "layers": stack_specs(make_layer_specs(cfg), cfg.num_layers - n0),
         "ln_final": ParamSpec((cfg.d_model,), ("embed",), init="ones",
                               f32_at_use=True),
     }
-    if n0:
-        specs["dense_layers"] = stack_specs(
-            make_layer_specs(cfg, dense=True), n0)
+    for run in layer_runs(cfg):
+        specs[run.name] = stack_specs(
+            make_layer_specs(cfg, dense=not run.moe, mixer=run.mixer),
+            run.layers)
     if not cfg.tie_embeddings:
         specs["lm_head"] = ParamSpec((cfg.d_model, vp), ("embed", "vocab"))
     if cfg.family == "vlm":
@@ -86,8 +138,12 @@ def _layer_forward(cfg: ModelConfig, p: dict[str, Any], x: jax.Array,
                    positions: jax.Array) -> tuple[jax.Array, jax.Array]:
     """Pre-norm block. Returns (x, aux_loss)."""
     rm = cfg.residual_multiplier
-    h = rms_norm(x, p["ln_attn"], cfg.norm_eps)
-    a = attn.attn_forward(cfg, p["attn"], h, positions, causal=True)
+    if "ssm" in p:
+        h = rms_norm(x, p["ln_ssm"], cfg.norm_eps)
+        a, _, _ = mamba2.ssm_forward(cfg, p["ssm"], h)
+    else:
+        h = rms_norm(x, p["ln_attn"], cfg.norm_eps)
+        a = attn.attn_forward(cfg, p["attn"], h, positions, causal=True)
     x = x + rm * a
     h = rms_norm(x, p["ln_mlp"], cfg.norm_eps)
     m, aux = _ffn(cfg, p, h)
@@ -104,10 +160,6 @@ def _ffn(cfg: ModelConfig, p: dict[str, Any], h: jax.Array
     return mlp_mod.mlp_forward(cfg, p["mlp"], h), jnp.zeros((), jnp.float32)
 
 
-#: the stacked runs of layers, in the order they run
-STACKS = ("dense_layers", "layers")
-
-
 def _stack_forward(cfg: ModelConfig, params: dict[str, Any], x: jax.Array,
                    positions: jax.Array) -> tuple[jax.Array, jax.Array]:
     def body(carry, layer_params):
@@ -117,9 +169,8 @@ def _stack_forward(cfg: ModelConfig, params: dict[str, Any], x: jax.Array,
 
     body = maybe_remat(body, cfg.remat_policy)
     carry = (x, jnp.zeros((), jnp.float32))
-    for name in STACKS:
-        if name in params:
-            carry, _ = lax.scan(body, carry, params[name])
+    for run in layer_runs(cfg):
+        carry, _ = lax.scan(body, carry, params[run.name])
     return carry
 
 
@@ -192,36 +243,57 @@ def lm_loss(cfg: ModelConfig, params: dict[str, Any],
 # ---------------------------------------------------------------------------
 
 def init_lm_cache(cfg: ModelConfig, batch: int, max_len: int) -> dict[str, Any]:
-    n0 = cfg.first_dense_layers
-    if not n0:
-        return attn.init_kv_cache(cfg, batch, max_len, layers=cfg.num_layers)
-    return {"dense_layers": attn.init_kv_cache(cfg, batch, max_len, layers=n0),
-            "layers": attn.init_kv_cache(cfg, batch, max_len,
-                                         layers=cfg.num_layers - n0)}
+    def one(run: Run):
+        if run.mixer == "mamba":
+            return mamba2.init_state(cfg, batch, run.layers)
+        return attn.init_kv_cache(cfg, batch, max_len, layers=run.layers)
+
+    runs = layer_runs(cfg)
+    if _one_run(runs):
+        return one(runs[0])
+    return {run.name: one(run) for run in runs}
 
 
 def lm_cache_axes(cfg: ModelConfig) -> dict[str, Any]:
-    axes = attn.kv_cache_axes(cfg, layers=True)
-    if not cfg.first_dense_layers:
-        return axes
-    return {name: axes for name in STACKS}
+    def one(run: Run):
+        if run.mixer == "mamba":
+            return mamba2.state_axes(cfg)
+        return attn.kv_cache_axes(cfg, layers=True)
+
+    runs = layer_runs(cfg)
+    if _one_run(runs):
+        return one(runs[0])
+    return {run.name: one(run) for run in runs}
 
 
-def _run_stacks(cfg: ModelConfig, params: dict[str, Any], cache, body, x):
-    """``body((h, cache), (layer_params, layer))`` over every run of
-    layers. The run's whole stacked cache rides in the scan's carry, and
+def _run_stacks(cfg: ModelConfig, params: dict[str, Any], cache, bodies, x):
+    """``bodies[mixer]((h, cache), (layer_params, layer))`` over every run
+    of layers. The run's whole stacked cache rides in the scan's carry, and
     ``layer`` (the layer's index, beside its parameters) tells the body
     which layer of it to write and read in place: the donated buffer stays
     one buffer, and no layer is sliced out or stacked back. Returns
     (x, the new cache)."""
-    runs = dict(cache) if cfg.first_dense_layers else {"layers": cache}
-    for name in STACKS:
-        if name in params:
-            n = jax.tree.leaves(params[name])[0].shape[0]
-            (x, runs[name]), _ = lax.scan(
-                lambda carry, xs: (body(carry, xs), None), (x, runs[name]),
-                (params[name], np.arange(n, dtype=np.int32)))
-    return x, (runs if cfg.first_dense_layers else runs["layers"])
+    runs = layer_runs(cfg)
+    caches = {"layers": cache} if _one_run(runs) else dict(cache)
+    for run in runs:
+        n = jax.tree.leaves(params[run.name])[0].shape[0]
+        (x, caches[run.name]), _ = lax.scan(
+            lambda carry, xs, body=bodies[run.mixer]: (body(carry, xs), None),
+            (x, caches[run.name]),
+            (params[run.name], np.arange(n, dtype=np.int32)))
+    return x, (caches["layers"] if _one_run(runs) else caches)
+
+
+def _after_mixer(cfg: ModelConfig, layer_params, h, a, *, prefill):
+    """The rest of a layer after its mixer's output ``a``: the residual,
+    then the MLP or expert layer and its residual."""
+    h = h + cfg.residual_multiplier * a
+    hn = rms_norm(h, layer_params["ln_mlp"], cfg.norm_eps)
+    m, _ = _ffn(cfg, layer_params, hn)
+    h = h + cfg.residual_multiplier * m
+    if prefill:
+        h = shard(h, "batch", "act_seq", None)
+    return h
 
 
 def lm_prefill(cfg: ModelConfig, params: dict[str, Any],
@@ -243,15 +315,19 @@ def lm_prefill(cfg: ModelConfig, params: dict[str, Any],
         hn = rms_norm(h, layer_params["ln_attn"], cfg.norm_eps)
         a, cache = attn.prefill_into_cache(
             cfg, layer_params["attn"], hn, positions, cache, layer=layer)
-        h = h + cfg.residual_multiplier * a
-        hn = rms_norm(h, layer_params["ln_mlp"], cfg.norm_eps)
-        m, _ = _ffn(cfg, layer_params, hn)
-        h = h + cfg.residual_multiplier * m
-        h = shard(h, "batch", "act_seq", None)
-        return h, cache
+        return _after_mixer(cfg, layer_params, h, a, prefill=True), cache
 
-    body = maybe_remat(body, cfg.remat_policy)
-    x, new_cache = _run_stacks(cfg, params, cache, body, x)
+    def body_ssm(carry, xs):
+        h, cache = carry
+        layer_params, layer = xs
+        hn = rms_norm(h, layer_params["ln_ssm"], cfg.norm_eps)
+        a, cache = mamba2.ssm_prefill(cfg, layer_params["ssm"], hn, cache,
+                                      layer)
+        return _after_mixer(cfg, layer_params, h, a, prefill=True), cache
+
+    bodies = {"attention": maybe_remat(body, cfg.remat_policy),
+              "mamba": maybe_remat(body_ssm, cfg.remat_policy)}
+    x, new_cache = _run_stacks(cfg, params, cache, bodies, x)
     logits = lm_logits(cfg, params, x[:, -1:])
     return logits, new_cache
 
@@ -269,12 +345,17 @@ def lm_decode_step(cfg: ModelConfig, params: dict[str, Any],
         hn = rms_norm(h, layer_params["ln_attn"], cfg.norm_eps)
         a, cache = attn.attn_decode(cfg, layer_params["attn"], hn, cache, pos,
                                     layer=layer)
-        h = h + cfg.residual_multiplier * a
-        hn = rms_norm(h, layer_params["ln_mlp"], cfg.norm_eps)
-        m, _ = _ffn(cfg, layer_params, hn)
-        h = h + cfg.residual_multiplier * m
-        return h, cache
+        return _after_mixer(cfg, layer_params, h, a, prefill=False), cache
 
-    x, new_cache = _run_stacks(cfg, params, cache, body, x)
+    def body_ssm(carry, xs):
+        h, cache = carry
+        layer_params, layer = xs
+        hn = rms_norm(h, layer_params["ln_ssm"], cfg.norm_eps)
+        a, cache = mamba2.ssm_decode(cfg, layer_params["ssm"], hn, cache,
+                                     layer)
+        return _after_mixer(cfg, layer_params, h, a, prefill=False), cache
+
+    x, new_cache = _run_stacks(cfg, params, cache,
+                               {"attention": body, "mamba": body_ssm}, x)
     logits = lm_logits(cfg, params, x)
     return logits, new_cache

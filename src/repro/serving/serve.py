@@ -6,9 +6,10 @@ building blocks.
 
 * **admission** — FIFO queue; a free slot triggers a one-row prefill of
   the request's exact prompt (no padding, so the first sampled token is
-  taken at the true last prompt position) whose KV rows are spliced into
-  the slot's row of the shared batch cache, in the same jitted op that
-  sets the slot's token, position and mask on the device;
+  taken at the true last prompt position, and no pad token enters a
+  recurrent state) whose cache rows are spliced into the slot's row of
+  the shared batch cache, in the same jitted op that sets the slot's
+  token, position and mask on the device;
 * **per-slot positions** — every decode step runs ONE program over the
   whole batch with a ``(B,)`` position vector (``attn_decode``'s per-row
   path), so co-batched requests at different depths neither pad nor
@@ -33,9 +34,15 @@ building blocks.
 * **weights** — every tree given to the scheduler is stored in the
   compute dtype (``registry.serving_params``), so the step converts no
   weight; ``serving.weight_bytes`` reads the tree's bytes;
-* **cache** — the model's own cache tree (per-head K/V, or one latent
-  row a position for latent attention), one row per slot;
-  ``serving.cache_bytes`` reads its bytes;
+* **cache** — the model's own cache tree, one row per slot (batch on
+  axis 1 of every leaf): one stacked tree per run of layers
+  (``transformer.layer_runs``), per-head K/V or one latent row a position
+  for attention, and for the Mamba-2 runs of a hybrid model a fixed-size
+  recurrent state (the conv's last inputs and the SSM state) beside them.
+  Admission splices the prefilled row of every leaf into the slot, so a
+  re-admitted slot starts from the new request's state and KV rows, never
+  the last request's; ``serving.cache_bytes`` reads the tree's bytes and
+  ``serving.state_bytes`` the recurrent state's part of them;
 * **metrics** — per-request latency and token counts land in the
   process-wide observability registry (``serving.*``).
 * **spans** — ``serving.admit`` per request; ``serving.step`` per call of
@@ -61,6 +68,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from repro.models.mamba2 import STATE_LEAVES
 from repro.models.registry import LM_FAMILIES, ModelBundle, serving_params
 from repro.observability import trace
 from repro.observability.metrics import get_registry
@@ -136,9 +144,13 @@ class BatchScheduler:
                  max_pending: int | None = None):
         if bundle.cfg.family not in LM_FAMILIES:
             raise ValueError(
-                f"BatchScheduler drives KV-cache LM families {LM_FAMILIES}, "
-                f"not {bundle.cfg.family!r} (recurrent families have no "
-                f"per-slot cache rows to splice)")
+                f"BatchScheduler drives the LM families {LM_FAMILIES} (with "
+                f"Mamba-2 layers through layer_types), not "
+                f"{bundle.cfg.family!r}: the recurrent families 'hybrid' "
+                f"(RG-LRU) and 'ssm' (xLSTM) keep per-layer state lists "
+                f"with batch on axis 0, and RG-LRU's windowed ring buffers "
+                f"take one position for all rows, not the stacked tree "
+                f"with batch on axis 1 that admission splices")
         self.bundle = bundle
         self._g_weight_bytes = get_registry().gauge("serving.weight_bytes")
         self.params = params
@@ -162,6 +174,10 @@ class BatchScheduler:
         self.cache = bundle.init_cache(batch_size, max_len)
         get_registry().gauge("serving.cache_bytes").set(sum(
             leaf.nbytes for leaf in jax.tree.leaves(self.cache)))
+        get_registry().gauge("serving.state_bytes").set(sum(
+            leaf.nbytes for path, leaf in
+            jax.tree_util.tree_flatten_with_path(self.cache)[0]
+            if getattr(path[-1], "key", None) in STATE_LEAVES))
         # device-resident control state: last token, cache depth and mask
         # per slot. Empty slots keep a frozen pos — their rows are never
         # read, and admission overwrites the whole row before re-activating

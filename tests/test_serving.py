@@ -327,7 +327,8 @@ def test_decode_steps_and_steps_ahead_per_request(lm):
 # prefill/decode parity
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("arch", ["aiida-demo-110m", "recurrentgemma-2b"])
+@pytest.mark.parametrize("arch", ["aiida-demo-110m", "recurrentgemma-2b",
+                                  "granite-4.0-h-small"])
 def test_prefill_equals_stepwise_decode(arch):
     """Prefilling N tokens must land in the same state as feeding those N
     tokens one decode step at a time: identical next token and identical
@@ -603,12 +604,14 @@ def test_generate_timeline_persists_request_spans_not_step_spans(runner):
 
 #: one reduced config per served family; qwen3-4b adds the q/k norms,
 #: moonlight the latent attention, the leading dense layer and the
-#: no-drop expert layer over held experts
+#: no-drop expert layer over held experts, granite-4.0-h the Mamba-2
+#: layers and softmax routing
 SERVED_ARCHS = ("qwen2-0.5b", "qwen3-4b", "moonshot-v1-16b-a3b",
-                "llava-next-34b", "moonlight-16b-a3b")
+                "llava-next-34b", "moonlight-16b-a3b", "granite-4.0-h-small")
 #: leaves the forward reads in float32, by name (independent of the specs)
 F32_LEAF_NAMES = ("ln_attn", "ln_mlp", "ln_final", "q_norm", "k_norm",
-                  "router", "router_bias", "kv_norm")
+                  "router", "router_bias", "kv_norm", "ln_ssm", "dt_bias",
+                  "A_log", "D", "norm")
 
 
 def _named_leaves(tree):

@@ -165,6 +165,62 @@ def test_decode_step_updates_the_cache_in_place(one_chip, monkeypatch, arch,
     assert mem.temp_size_in_bytes < layer_bytes
 
 
+def test_hybrid_decode_step_updates_its_state_in_place(one_chip,
+                                                      monkeypatch):
+    """granite-4.0-h-small at its published widths, cut to its first six
+    layers (Mamba 0-4, attention 5) with 9 held experts, 4 slots of 2048,
+    as the scheduler jits the step: the Mamba decode kernel compiles
+    inside it, the output aliases the whole donated cache (the float32
+    state, the conv inputs and the KV rows), no op but an in-place update
+    yields a buffer of one layer's state or of the stack, and the step's
+    temporaries are smaller than one layer's state."""
+    import functools
+    import re
+
+    from repro.configs import get_config
+    from repro.kernels.decode_attention import ops as da_ops
+    from repro.kernels.ssm_decode import ops as sd_ops
+    from repro.models.registry import build, serving_params
+    from repro.serving.serve import make_scheduled_step
+
+    monkeypatch.setattr(da_ops, "interpret_default", lambda: False)
+    monkeypatch.setattr(sd_ops, "interpret_default", lambda: False)
+    cfg = get_config("granite-4.0-h-small").replace(
+        decode_impl="pallas", num_layers=6, experts_held=9)
+    bundle = build(cfg)
+    b, max_len = 4, 2048
+
+    def on_chip(tree):
+        return jax.tree.map(lambda s: _sds(s.shape, s.dtype, one_chip), tree)
+
+    params = on_chip(jax.eval_shape(functools.partial(serving_params, cfg),
+                                    bundle.param_shapes()))
+    cache = on_chip(jax.eval_shape(lambda: bundle.init_cache(b, max_len)))
+    assert cache["mamba0"]["ssm"].shape == (5, b, 128, 8192)
+    compiled = jax.jit(make_scheduled_step(bundle), donate_argnums=(1,)) \
+        .lower(params, cache, _sds((b, 1), I32, one_chip),
+               _sds((b,), I32, one_chip),
+               _sds((b,), jnp.bool_, one_chip)).compile()
+    text = compiled.as_text()
+    assert "ssm_decode" in text
+    state = cache["mamba0"]["ssm"].shape
+    sizes = {tuple(sorted(d for d in state[i:] if d > 1)) for i in (0, 1)}
+    found = []
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%(\S+) = \w+\[([\d,]*)\]\S* ([\w-]+)\(",
+                     line)
+        if m and m.group(3) not in _CACHE_PLUMBING | {"custom-call"}:
+            dims = tuple(sorted(int(d) for d in m.group(2).split(",")
+                                if d and int(d) > 1))
+            if dims in sizes:
+                found.append(f"{m.group(1)} {m.group(3)}[{m.group(2)}]")
+    assert not found, found
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == sum(
+        leaf.size * leaf.dtype.itemsize for leaf in jax.tree.leaves(cache))
+    assert mem.temp_size_in_bytes < b * 128 * 8192 * 4
+
+
 def test_sharded_decode_step_compiles_on_four_chips(topo, monkeypatch):
     """aiida-demo-110m at its published width, heads sharded over
     model=4: the decode kernel must sit inside the partitioned program."""
