@@ -21,7 +21,10 @@ router's score function): it routes over all ``num_experts``, computes
 only the experts it holds (``experts_held`` from ``expert_offset``) with
 a grouped matmul over the (token, choice) pairs sorted by expert, and adds
 the shared experts whole. Pairs routed to experts held elsewhere add
-nothing here; on one chip the layer runs without its exchange.
+nothing here; on one chip the layer runs without its exchange. In decode
+with ``decode_impl='pallas'`` the ``moe_decode`` kernel takes the place of
+the grouped matmul: it reads only the held experts some row routes to,
+in place from the run's stacked matrices.
 """
 
 from __future__ import annotations
@@ -91,7 +94,7 @@ def moe_forward(cfg: ModelConfig, p: dict[str, jax.Array], x: jax.Array
                 ) -> tuple[jax.Array, jax.Array]:
     """Returns (output, aux_load_balance_loss). x: (B, S, D)."""
     if cfg.moe_impl == "ragged":
-        return moe_ragged_forward(cfg, p, x), jnp.zeros((), jnp.float32)
+        return moe_ragged_forward(cfg, p, x)[0], jnp.zeros((), jnp.float32)
     dt = x.dtype
     b, s, d = x.shape
     e, k = cfg.num_experts, cfg.num_experts_per_tok
@@ -238,13 +241,27 @@ def moe_routed(cfg: ModelConfig, p: dict[str, jax.Array], xt: jax.Array
 
 
 def moe_ragged_forward(cfg: ModelConfig, p: dict[str, jax.Array],
-                       x: jax.Array) -> jax.Array:
+                       x: jax.Array, layer=None) -> tuple[jax.Array, jax.Array]:
     """No-drop expert layer: the held experts' part plus the shared
-    experts. x: (B, S, D)."""
+    experts. x: (B, S, D). Returns (output, the held experts read, int32).
+
+    Without ``layer``, p's expert matrices are the layer's and
+    ``moe_routed`` reads all of them. With ``layer`` (decode), they are the
+    run's whole stacks, and the ``moe_decode`` kernel reads, in place at
+    ``layer``, only the held experts that some row routes to."""
+    from repro.kernels.moe_decode import ops as md_ops
+
     b, s, d = x.shape
+    xt = x.reshape(b * s, d)
     with jax.named_scope("moe"):
-        out = moe_routed(cfg, p, x.reshape(b * s, d)).reshape(b, s, d)
-        out = out.astype(x.dtype)
+        if layer is None:
+            out, read = moe_routed(cfg, p, xt), jnp.int32(cfg.held_experts)
+        else:
+            idx, w = route(cfg, p, xt)
+            out, read = md_ops.moe_decode(
+                xt, idx - cfg.expert_offset, w, p["w_gate"], p["w_up"],
+                p["w_down"], layer, act=cfg.mlp_act)
+        out = out.reshape(b, s, d).astype(x.dtype)
         if cfg.shared_d_ff:
             out = out + mlp_forward(cfg, p["shared"], x)
-    return out
+    return out, read

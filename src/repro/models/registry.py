@@ -10,6 +10,10 @@ paper):
     decode_fn(params, cache, tokens, pos)   -> (logits, cache)
     init_cache(batch_size, max_len)         -> cache pytree
     cache_axes()                            -> logical-axis pytree for cache
+
+An LM's ``decode_fn`` also takes ``experts_read=True``, and then returns
+the held experts its expert layers read as a third output
+(``transformer.lm_decode_step``).
 """
 
 from __future__ import annotations
@@ -177,8 +181,8 @@ def build(cfg: ModelConfig) -> ModelBundle:
             specs=transformer.make_lm_specs(cfg),
             loss_fn=lambda p, b: transformer.lm_loss(cfg, p, b),
             prefill_fn=lambda p, b, c: transformer.lm_prefill(cfg, p, b, c),
-            decode_fn=lambda p, c, t, pos: transformer.lm_decode_step(
-                cfg, p, c, t, pos),
+            decode_fn=lambda p, c, t, pos, **kw: transformer.lm_decode_step(
+                cfg, p, c, t, pos, **kw),
             init_cache=lambda bsz, ml: transformer.init_lm_cache(cfg, bsz, ml),
             cache_axes=lambda: transformer.lm_cache_axes(cfg),
         )

@@ -23,7 +23,9 @@ tree per run under the run's name: the KV cache of an attention run, the
 recurrent state (``models/mamba2.py``: conv inputs and the SSM state) of a
 Mamba run, both with batch on axis 1. Prefill and decode carry each run's
 stacked cache or state through its scan and update it in place, layer by
-layer.
+layer. Decode with ``decode_impl='pallas'`` keeps the held-expert layer's
+matrices whole the same way: the ``moe_decode`` kernel reads each layer's
+routed experts from the run's stacks, and nothing slices a layer out.
 
 Remat (activation checkpointing) wraps the scanned body in training;
 ``cfg.remat_policy`` names the policy.
@@ -266,34 +268,71 @@ def lm_cache_axes(cfg: ModelConfig) -> dict[str, Any]:
     return {run.name: one(run) for run in runs}
 
 
-def _run_stacks(cfg: ModelConfig, params: dict[str, Any], cache, bodies, x):
-    """``bodies[mixer]((h, cache), (layer_params, layer))`` over every run
-    of layers. The run's whole stacked cache rides in the scan's carry, and
-    ``layer`` (the layer's index, beside its parameters) tells the body
-    which layer of it to write and read in place: the donated buffer stays
-    one buffer, and no layer is sliced out or stacked back. Returns
-    (x, the new cache)."""
+#: the expert layer's matrices that decode reads in place from the stack
+EXPERT_MATRICES = ("w_gate", "w_up", "w_down")
+
+
+def expert_layers(cfg: ModelConfig) -> int:
+    """Layers with the held-expert layer (``moe_impl='ragged'``), 0 for a
+    model without one."""
+    if cfg.moe_impl != "ragged":
+        return 0
+    return sum(run.layers for run in layer_runs(cfg) if run.moe)
+
+
+def _run_stacks(cfg: ModelConfig, params: dict[str, Any], cache, bodies, x,
+                *, whole_experts: bool = False):
+    """``bodies[mixer]((h, cache), (layer_params, layer))``, a scan body
+    that returns ((h, cache), y), over every run of layers. The run's
+    whole stacked cache rides in the scan's carry, and ``layer`` (the
+    layer's index, beside its parameters) tells the body which layer of it
+    to write and read in place: the donated buffer stays one buffer, and
+    no layer is sliced out or stacked back. With ``whole_experts`` the
+    expert matrices of a run with the expert layer stay out of the scan's
+    xs the same way: the body finds the run's whole stacks in the layer's
+    ``moe`` tree and reads layer ``layer`` of them. Returns (x, the new
+    cache, each run's stacked ys by name)."""
     runs = layer_runs(cfg)
     caches = {"layers": cache} if _one_run(runs) else dict(cache)
+    ys = {}
     for run in runs:
-        n = jax.tree.leaves(params[run.name])[0].shape[0]
-        (x, caches[run.name]), _ = lax.scan(
-            lambda carry, xs, body=bodies[run.mixer]: (body(carry, xs), None),
-            (x, caches[run.name]),
-            (params[run.name], np.arange(n, dtype=np.int32)))
-    return x, (caches["layers"] if _one_run(runs) else caches)
+        p, whole = params[run.name], {}
+        if whole_experts and run.moe:
+            whole = {k: p["moe"][k] for k in EXPERT_MATRICES}
+            p = {**p, "moe": {k: v for k, v in p["moe"].items()
+                              if k not in whole}}
+
+        def step(carry, xs, body=bodies[run.mixer], whole=whole):
+            if whole:
+                layer_params, layer = xs
+                xs = ({**layer_params,
+                       "moe": {**layer_params["moe"], **whole}}, layer)
+            return body(carry, xs)
+
+        n = jax.tree.leaves(p)[0].shape[0]
+        (x, caches[run.name]), ys[run.name] = lax.scan(
+            step, (x, caches[run.name]), (p, np.arange(n, dtype=np.int32)))
+    return x, (caches["layers"] if _one_run(runs) else caches), ys
 
 
-def _after_mixer(cfg: ModelConfig, layer_params, h, a, *, prefill):
+def _after_mixer(cfg: ModelConfig, layer_params, h, a, *, prefill,
+                 layer=None):
     """The rest of a layer after its mixer's output ``a``: the residual,
-    then the MLP or expert layer and its residual."""
+    then the MLP or expert layer and its residual. Returns (h, the held
+    experts the expert layer read, or None without one). ``layer`` is
+    given where the held-expert layer's matrices are the run's whole
+    stacks (``_run_stacks``' ``whole_experts``): the layer to read."""
     h = h + cfg.residual_multiplier * a
     hn = rms_norm(h, layer_params["ln_mlp"], cfg.norm_eps)
-    m, _ = _ffn(cfg, layer_params, hn)
+    if "moe" in layer_params and cfg.moe_impl == "ragged":
+        m, read = mlp_mod.moe_ragged_forward(cfg, layer_params["moe"], hn,
+                                             layer)
+    else:
+        (m, _), read = _ffn(cfg, layer_params, hn), None
     h = h + cfg.residual_multiplier * m
     if prefill:
         h = shard(h, "batch", "act_seq", None)
-    return h
+    return h, read
 
 
 def lm_prefill(cfg: ModelConfig, params: dict[str, Any],
@@ -315,7 +354,8 @@ def lm_prefill(cfg: ModelConfig, params: dict[str, Any],
         hn = rms_norm(h, layer_params["ln_attn"], cfg.norm_eps)
         a, cache = attn.prefill_into_cache(
             cfg, layer_params["attn"], hn, positions, cache, layer=layer)
-        return _after_mixer(cfg, layer_params, h, a, prefill=True), cache
+        h, _ = _after_mixer(cfg, layer_params, h, a, prefill=True)
+        return (h, cache), None
 
     def body_ssm(carry, xs):
         h, cache = carry
@@ -323,21 +363,28 @@ def lm_prefill(cfg: ModelConfig, params: dict[str, Any],
         hn = rms_norm(h, layer_params["ln_ssm"], cfg.norm_eps)
         a, cache = mamba2.ssm_prefill(cfg, layer_params["ssm"], hn, cache,
                                       layer)
-        return _after_mixer(cfg, layer_params, h, a, prefill=True), cache
+        h, _ = _after_mixer(cfg, layer_params, h, a, prefill=True)
+        return (h, cache), None
 
     bodies = {"attention": maybe_remat(body, cfg.remat_policy),
               "mamba": maybe_remat(body_ssm, cfg.remat_policy)}
-    x, new_cache = _run_stacks(cfg, params, cache, bodies, x)
+    x, new_cache, _ = _run_stacks(cfg, params, cache, bodies, x)
     logits = lm_logits(cfg, params, x[:, -1:])
     return logits, new_cache
 
 
 def lm_decode_step(cfg: ModelConfig, params: dict[str, Any],
-                   cache: dict[str, Any], tokens: jax.Array, pos: jax.Array
-                   ) -> tuple[jax.Array, dict[str, Any]]:
-    """One decode step. tokens: (B, 1); pos: scalar current position."""
+                   cache: dict[str, Any], tokens: jax.Array, pos: jax.Array,
+                   *, experts_read: bool = False):
+    """One decode step. tokens: (B, 1); pos: scalar current position.
+    Returns (logits, cache), and with ``experts_read`` (a model with the
+    held-expert layer) the held experts its expert layers read, summed
+    (int32). Under ``decode_impl='pallas'`` the ``moe_decode`` kernel
+    reads only the routed ones, in place from each run's stacks; the
+    direct path reads every held expert of every layer."""
     x = embed_tokens(cfg, params, tokens)
     x = shard(x, "batch", None, None)
+    in_place = cfg.decode_impl == "pallas" and expert_layers(cfg) > 0
 
     def body(carry, xs):
         h, cache = carry
@@ -345,7 +392,9 @@ def lm_decode_step(cfg: ModelConfig, params: dict[str, Any],
         hn = rms_norm(h, layer_params["ln_attn"], cfg.norm_eps)
         a, cache = attn.attn_decode(cfg, layer_params["attn"], hn, cache, pos,
                                     layer=layer)
-        return _after_mixer(cfg, layer_params, h, a, prefill=False), cache
+        h, read = _after_mixer(cfg, layer_params, h, a, prefill=False,
+                               layer=layer if in_place else None)
+        return (h, cache), read
 
     def body_ssm(carry, xs):
         h, cache = carry
@@ -353,9 +402,15 @@ def lm_decode_step(cfg: ModelConfig, params: dict[str, Any],
         hn = rms_norm(h, layer_params["ln_ssm"], cfg.norm_eps)
         a, cache = mamba2.ssm_decode(cfg, layer_params["ssm"], hn, cache,
                                      layer)
-        return _after_mixer(cfg, layer_params, h, a, prefill=False), cache
+        h, read = _after_mixer(cfg, layer_params, h, a, prefill=False,
+                               layer=layer if in_place else None)
+        return (h, cache), read
 
-    x, new_cache = _run_stacks(cfg, params, cache,
-                               {"attention": body, "mamba": body_ssm}, x)
+    x, new_cache, reads = _run_stacks(
+        cfg, params, cache, {"attention": body, "mamba": body_ssm}, x,
+        whole_experts=in_place)
     logits = lm_logits(cfg, params, x)
-    return logits, new_cache
+    if not experts_read:
+        return logits, new_cache
+    read = sum(jnp.sum(r) for r in jax.tree.leaves(reads))
+    return logits, new_cache, jnp.asarray(read, jnp.int32)

@@ -70,15 +70,21 @@ from jax import lax
 
 from repro.models.mamba2 import STATE_LEAVES
 from repro.models.registry import LM_FAMILIES, ModelBundle, serving_params
+from repro.models.transformer import expert_layers
 from repro.observability import trace
 from repro.observability.metrics import get_registry
+
+
+def _greedy(bundle: ModelBundle, logits: jax.Array) -> jax.Array:
+    """The best token of each row's last position, (B, 1) int32."""
+    next_tok = jnp.argmax(logits[:, -1, :bundle.cfg.vocab_size], axis=-1)
+    return next_tok.astype(jnp.int32)[:, None]
 
 
 def make_prefill_step(bundle: ModelBundle) -> Callable:
     def prefill_step(params, batch, cache):
         logits, cache = bundle.prefill_fn(params, batch, cache)
-        next_tok = jnp.argmax(logits[:, -1, :bundle.cfg.vocab_size], axis=-1)
-        return next_tok.astype(jnp.int32)[:, None], cache
+        return _greedy(bundle, logits), cache
 
     return prefill_step
 
@@ -86,20 +92,22 @@ def make_prefill_step(bundle: ModelBundle) -> Callable:
 def make_decode_step(bundle: ModelBundle) -> Callable:
     def serve_step(params, cache, tokens, pos):
         logits, cache = bundle.decode_fn(params, cache, tokens, pos)
-        next_tok = jnp.argmax(logits[:, -1, :bundle.cfg.vocab_size], axis=-1)
-        return next_tok.astype(jnp.int32)[:, None], cache
+        return _greedy(bundle, logits), cache
 
     return serve_step
 
 
 def make_scheduled_step(bundle: ModelBundle) -> Callable:
     """The scheduler's decode step: ``make_decode_step``'s, which also
-    returns the positions advanced for the active rows."""
-    decode = make_decode_step(bundle)
+    returns the positions advanced for the active rows, and for a model
+    with the held-expert layer the held experts the step read."""
+    count = {"experts_read": True} if expert_layers(bundle.cfg) else {}
 
     def serve_step(params, cache, tokens, pos, active):
-        next_tok, cache = decode(params, cache, tokens, pos)
-        return next_tok, cache, pos + active.astype(pos.dtype)
+        logits, cache, *read = bundle.decode_fn(params, cache, tokens, pos,
+                                                **count)
+        return (_greedy(bundle, logits), cache,
+                pos + active.astype(pos.dtype), *read)
 
     return serve_step
 
@@ -125,9 +133,10 @@ class Request:
     finished_at: float = 0.0
 
 
-#: a launched decode step: its tokens on the device, and the (slot,
-#: request) rows it advances
-_Launched = tuple[jax.Array, list[tuple[int, Request]]]
+#: a launched decode step: its tokens on the device, the held experts it
+#: read (one device scalar, or none for a model without the held-expert
+#: layer), and the (slot, request) rows it advances
+_Launched = tuple[jax.Array, list[jax.Array], list[tuple[int, Request]]]
 
 
 class BatchScheduler:
@@ -200,6 +209,12 @@ class BatchScheduler:
         self._g_queue = reg.gauge("serving.queue_depth")
         self._h_latency = reg.histogram("serving.request_seconds")
         self._m_rejected = reg.counter("serving.rejected")
+        #: held experts a step could read: held x expert layers
+        self._experts_held = (expert_layers(bundle.cfg)
+                              * bundle.cfg.held_experts)
+        if self._experts_held:
+            self._m_experts_read = reg.counter("moe.experts_read")
+            self._m_experts_held = reg.counter("moe.experts_held")
 
     @property
     def params(self) -> Any:
@@ -307,7 +322,7 @@ class BatchScheduler:
     def _release_ending(self) -> None:
         """Free the slots whose request gets its last token from the step
         in flight, so that no step is launched past its end."""
-        pending = dict(self._in_flight[1]) if self._in_flight else {}
+        pending = dict(self._in_flight[2]) if self._in_flight else {}
         for i, req in enumerate(self.slots):
             if req is not None and not self._has_room(
                     req, len(req.generated) + (pending.get(i) is req)):
@@ -326,19 +341,23 @@ class BatchScheduler:
             self.active = jnp.asarray(occupied)
         if self._in_flight is not None:
             self._m_steps_ahead.inc()
-        next_tok, self.cache, self.pos = self.decode_step(
+        next_tok, self.cache, self.pos, *read = self.decode_step(
             self.params, self.cache, self.tokens, self.pos, self.active)
         next_tok.copy_to_host_async()
         self.tokens = next_tok
         self._m_decode_steps.inc()
-        return next_tok, rows
+        return next_tok, read, rows
 
     def _collect(self, launched: _Launched) -> list[Request]:
         """Fetch a launched step's tokens and hand them to its requests;
         returns the requests that finished."""
-        next_tok, rows = launched
+        next_tok, read, rows = launched
         with trace.span("serving.fetch", persist=False):
-            next_host = jax.device_get(next_tok)[:, 0]
+            next_host, read = jax.device_get((next_tok, read))
+        next_host = next_host[:, 0]
+        if read:
+            self._m_experts_read.inc(int(read[0]))
+            self._m_experts_held.inc(self._experts_held)
         finished, dropped = [], False
         for i, req in rows:
             if req.done:          # ended by an EOS learned after the launch

@@ -92,10 +92,13 @@ def test_forward_matches_reference(model):
 def test_prefill_then_latent_decode_matches_reference(model, impl):
     """Prefill the first 24 tokens into the latent cache, then decode the
     rest one token a step, the two rows at different depths (per-row
-    positions), each step's logits against the reference's forward."""
+    positions), each step's logits against the reference's forward. With
+    ``pallas`` the held-expert layer is the ``moe_decode`` kernel, and
+    each step's tokens equal the direct decode's."""
     cfg, params, tokens, want = model
     cfg = cfg.replace(decode_impl=impl, attn_kv_block=16)
     bundle = build(cfg)
+    direct = jax.jit(build(cfg.replace(decode_impl="direct")).decode_fn)
     cache = bundle.init_cache(B, 64)
     assert set(cache) == {"dense_layers", "layers"}
     assert cache["layers"]["latent"].shape == (2, B, 40, 64)   # positions last
@@ -110,13 +113,18 @@ def test_prefill_then_latent_decode_matches_reference(model, impl):
         cache = jax.tree.map(
             lambda c, o, r=row: c.at[:, r:r + 1].set(o), cache, one)
     decode = jax.jit(bundle.decode_fn)
+    other = cache
     for i in range(S - max(start)):
         pos = np.asarray([n + i for n in start], np.int32)
-        logits, cache = decode(params, cache,
-                               jnp.asarray(tokens[np.arange(B), pos][:, None]),
-                               jnp.asarray(pos))
+        step = (jnp.asarray(tokens[np.arange(B), pos][:, None]),
+                jnp.asarray(pos))
+        logits, cache = decode(params, cache, *step)
         got = np.asarray(logits, np.float32)[:, 0, :512]
         np.testing.assert_allclose(got, want[np.arange(B), pos], atol=TOL)
+        if impl == "pallas":
+            logits, other = direct(params, other, *step)
+            np.testing.assert_array_equal(
+                got.argmax(-1), np.asarray(logits)[:, 0, :512].argmax(-1))
 
 
 def _biased_gates(cfg, p, xt):
@@ -165,13 +173,13 @@ def test_chip_shares_add_up_to_the_uncut_layer():
     whole = _cfg(experts_held=8, expert_offset=0)
     params = _moe(whole)
     x = jax.random.normal(jax.random.PRNGKey(5), (2, 24, whole.d_model))
-    want = M.moe_ragged_forward(whole, params, x)
+    want, _ = M.moe_ragged_forward(whole, params, x)
     shared = M.mlp_forward(whole, params["shared"], x)
     total = -3 * shared
     for chip in range(4):
         cfg = _cfg(experts_held=2, expert_offset=2 * chip)
         total = total + M.moe_ragged_forward(cfg, _slice(params, 2 * chip, 2),
-                                             x)
+                                             x)[0]
     np.testing.assert_allclose(np.asarray(total), np.asarray(want),
                                atol=1e-5)
 

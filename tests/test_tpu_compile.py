@@ -123,10 +123,12 @@ def test_decode_step_updates_the_cache_in_place(one_chip, monkeypatch, arch,
 
     from repro.configs import get_config
     from repro.kernels.decode_attention import ops as da_ops
+    from repro.kernels.moe_decode import ops as md_ops
     from repro.models.registry import build, serving_params
     from repro.serving.serve import make_decode_step
 
     monkeypatch.setattr(da_ops, "interpret_default", lambda: False)
+    monkeypatch.setattr(md_ops, "interpret_default", lambda: False)
     cfg = get_config(arch).replace(decode_impl="pallas", num_layers=layers)
     if cfg.first_dense_layers:
         cfg = cfg.replace(first_dense_layers=1)
@@ -179,11 +181,13 @@ def test_hybrid_decode_step_updates_its_state_in_place(one_chip,
 
     from repro.configs import get_config
     from repro.kernels.decode_attention import ops as da_ops
+    from repro.kernels.moe_decode import ops as md_ops
     from repro.kernels.ssm_decode import ops as sd_ops
     from repro.models.registry import build, serving_params
     from repro.serving.serve import make_scheduled_step
 
     monkeypatch.setattr(da_ops, "interpret_default", lambda: False)
+    monkeypatch.setattr(md_ops, "interpret_default", lambda: False)
     monkeypatch.setattr(sd_ops, "interpret_default", lambda: False)
     cfg = get_config("granite-4.0-h-small").replace(
         decode_impl="pallas", num_layers=6, experts_held=9)
@@ -219,6 +223,59 @@ def test_hybrid_decode_step_updates_its_state_in_place(one_chip,
     assert mem.alias_size_in_bytes == sum(
         leaf.size * leaf.dtype.itemsize for leaf in jax.tree.leaves(cache))
     assert mem.temp_size_in_bytes < b * 128 * 8192 * 4
+
+
+@pytest.mark.parametrize("arch,over", [
+    ("moonlight-16b-a3b", dict(num_layers=3, experts_held=8)),
+    ("granite-4.0-h-small", dict(num_layers=6, experts_held=9)),
+])
+def test_expert_decode_step_reads_the_expert_stacks_in_place(
+        one_chip, monkeypatch, arch, over):
+    """Both expert configurations at their published widths, as the
+    scheduler jits the decode step: the held-expert layer is the
+    ``moe_decode`` kernel, no op but the kernel's call yields a layer's
+    expert matrices or the whole stack (no dynamic-slice of it), and the
+    step returns the held experts it read."""
+    import functools
+    import re
+
+    from repro.configs import get_config
+    from repro.kernels.decode_attention import ops as da_ops
+    from repro.kernels.moe_decode import ops as md_ops
+    from repro.kernels.ssm_decode import ops as sd_ops
+    from repro.models.registry import build, serving_params
+    from repro.serving.serve import make_scheduled_step
+
+    for ops in (da_ops, md_ops, sd_ops):
+        monkeypatch.setattr(ops, "interpret_default", lambda: False)
+    cfg = get_config(arch).replace(decode_impl="pallas", **over)
+    bundle = build(cfg)
+    b, max_len = 4, 2048
+
+    def on_chip(tree):
+        return jax.tree.map(lambda s: _sds(s.shape, s.dtype, one_chip), tree)
+
+    params = on_chip(jax.eval_shape(functools.partial(serving_params, cfg),
+                                    bundle.param_shapes()))
+    cache = on_chip(jax.eval_shape(lambda: bundle.init_cache(b, max_len)))
+    lowered = jax.jit(make_scheduled_step(bundle), donate_argnums=(1,)) \
+        .lower(params, cache, _sds((b, 1), I32, one_chip),
+               _sds((b,), I32, one_chip), _sds((b,), jnp.bool_, one_chip))
+    assert len(lowered.out_info) == 4
+    text = lowered.compile().as_text()
+    assert "moe_decode" in text
+    n, d, f = cfg.held_experts, cfg.d_model, cfg.moe_d_ff
+    sizes = {tuple(sorted(s)) for s in ((n, d, f), (d, f))}
+    found = []
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%(\S+) = \w+\[([\d,]*)\]\S* ([\w-]+)\(",
+                     line)
+        if m and m.group(3) not in _CACHE_PLUMBING | {"custom-call"}:
+            dims = tuple(sorted(int(x) for x in m.group(2).split(",")
+                                if x and int(x) > 1))
+            if dims in sizes or dims[-3:] == tuple(sorted((n, d, f))):
+                found.append(f"{m.group(1)} {m.group(3)}[{m.group(2)}]")
+    assert not found, found
 
 
 def test_sharded_decode_step_compiles_on_four_chips(topo, monkeypatch):
