@@ -18,6 +18,7 @@ ARCH_IDS = [
     "moonshot-v1-16b-a3b",
     "moonlight-16b-a3b",
     "granite-4.0-h-small",
+    "kimi-linear-48b-a3b",
     "recurrentgemma-2b",
     "llava-next-34b",
     "whisper-large-v3",
@@ -70,7 +71,7 @@ def reduced_config(arch_id: str) -> ModelConfig:
                   qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
                   moe_d_ff=64, shared_d_ff=128, num_experts=8,
                   num_experts_per_tok=3, experts_held=4, expert_offset=4)
-    if cfg.layer_types:
+    if "mamba" in cfg.layer_types:
         # Mamba-2 layers around one attention layer; 4 heads of 16 with
         # d_state 16 and chunks of 8; expert layers that hold 4 of 8
         # routed experts (the first half), with a shared expert
@@ -79,6 +80,13 @@ def reduced_config(arch_id: str) -> ModelConfig:
                   ssm_heads=4, ssm_head_dim=16, ssm_state=16, ssm_chunk=8,
                   moe_d_ff=64, shared_d_ff=128, num_experts=8,
                   num_experts_per_tok=3, experts_held=4, expert_offset=0)
+    if "kda" in cfg.layer_types:
+        # on top of the latent attention above: a KDA layer with a dense
+        # MLP, a KDA and an MLA layer with the expert layer, and a KDA
+        # layer after them; 4 KDA heads of 16
+        kw.update(num_layers=4, layer_types=("kda", "kda", "attention",
+                                             "kda"),
+                  kda_heads=4, kda_head_dim=16)
     if cfg.family == "hybrid":
         kw.update(num_layers=3, d_rnn=128, local_window=32)
     if cfg.family == "ssm":

@@ -503,7 +503,8 @@ def prefill_into_cache(cfg: ModelConfig, p: dict[str, jax.Array], x: jax.Array,
 #   q            = x W_q                         H x (nope + rope)
 #   [c, k_pe]    = x W_kv_a; c = RMSNorm(c)      r + rope, k_pe one head
 #   [k_nope, v]  = c W_kv_b                      H x (nope + v)
-#   rope on q_pe and k_pe over interleaved pairs; scale (nope + rope)^-1/2
+#   rope on q_pe and k_pe over interleaved pairs (none where use_rope is
+#   off: kimi_linear's NoPE layers); scale (nope + rope)^-1/2
 #
 # The cache holds the latent row [c, k_pe] of each position, not per-head
 # keys and values. Prefill and training expand c into k and v; decode
@@ -534,15 +535,20 @@ def _deinterleave(x: jax.Array) -> jax.Array:
 def _mla_project(cfg: ModelConfig, p: dict[str, jax.Array], x: jax.Array,
                  positions: jax.Array):
     """q_nope (B,S,H,nope), q_pe (B,S,H,rope), latent rows (B,S,r+rope):
-    the normed latent and the rotated rope key."""
+    the normed latent and the rope key, both parts rotated unless
+    ``cfg.use_rope`` is off (kimi_linear's ``mla_use_nope``: the shared
+    key and q_pe are scored as projected, and ``positions`` is unused)."""
     dt = x.dtype
     nope, r = cfg.qk_nope_head_dim, cfg.kv_lora_rank
     q = jnp.einsum("bsd,dhk->bshk", x, p["wq"].astype(dt))
     kv = jnp.einsum("bsd,dk->bsk", x, p["wkv_a"].astype(dt))
     c = rms_norm(kv[..., :r], p["kv_norm"], cfg.latent_norm_eps)
-    q_pe, k_pe = rope(_deinterleave(q[..., nope:]),
-                      _deinterleave(kv[..., None, r:]), positions,
-                      cfg.rope_theta)
+    if cfg.use_rope:
+        q_pe, k_pe = rope(_deinterleave(q[..., nope:]),
+                          _deinterleave(kv[..., None, r:]), positions,
+                          cfg.rope_theta)
+    else:
+        q_pe, k_pe = q[..., nope:], kv[..., None, r:]
     return q[..., :nope], q_pe, jnp.concatenate([c, k_pe[:, :, 0]], -1)
 
 

@@ -104,9 +104,9 @@ class ModelConfig:
     # (deepseek-v3); ragged layer only
     moe_score: str = "sigmoid"
 
-    # --- layer kinds in order (granitemoehybrid) ------------------------------
-    # one of 'attention' | 'mamba' per layer, the first ``num_layers`` read;
-    # () -> every layer attention
+    # --- layer kinds in order (granitemoehybrid, kimi_linear) -----------------
+    # one of 'attention' | 'mamba' | 'kda' per layer, the first
+    # ``num_layers`` read; () -> every layer attention
     layer_types: tuple[str, ...] = ()
 
     # --- Mamba-2 mixer (models/mamba2.py) --------------------------------------
@@ -116,6 +116,11 @@ class ModelConfig:
     ssm_groups: int = 1              # groups of heads that share B and C
     ssm_conv: int = 4                # causal depthwise conv taps
     ssm_chunk: int = 256             # chunk of the SSD prefill
+
+    # --- gated delta-rule mixer (KDA, models/kda.py) --------------------------
+    kda_heads: int = 0
+    kda_head_dim: int = 0            # d_k = d_v of a head
+    kda_conv: int = 4                # short causal depthwise conv taps
 
     # --- latent attention (MLA, deepseek-v2/v3; q_lora_rank null) -----------
     kv_lora_rank: int = 0            # > 0: latent attention, latent cache
@@ -151,6 +156,9 @@ class ModelConfig:
     # decode-attention inner product: 'direct' (einsum over the full cache)
     # or 'pallas' (the flash-decode kernel, ragged per-row kv lengths).
     decode_impl: str = "direct"
+    # the TPU compiler's scoped VMEM for the serving prefill, in KiB; 0
+    # keeps the compiler's default (16 MiB)
+    prefill_vmem_kib: int = 0
     # Number of physical replications of KV heads so the KV-head dim divides
     # the model axis. 1 means no repetition. Set by the sharding resolver.
     kv_repeat: int = 1
@@ -182,6 +190,12 @@ class ModelConfig:
     def ssm_conv_dim(self) -> int:
         """Channels the causal conv runs over: x, then B and C."""
         return self.ssm_inner + 2 * self.ssm_groups * self.ssm_state
+
+    @property
+    def kda_width(self) -> int:
+        """Channels of each of the KDA mixer's q, k and v: heads x head
+        width."""
+        return self.kda_heads * self.kda_head_dim
 
     @property
     def latent_width(self) -> int:

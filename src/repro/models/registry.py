@@ -29,6 +29,17 @@ from repro.models import encdec, rglru, transformer, xlstm
 from repro.models.common import ModelConfig, ParamSpec, spec_axes, spec_shapes
 
 LM_FAMILIES = ("dense", "moe", "vlm")
+#: why each family that is not an LM family is not served by the
+#: scheduler (the LM families serve attention, Mamba-2 and KDA layers)
+REFUSED = {
+    "hybrid": "the recurrent family 'hybrid' (RG-LRU) keeps per-layer state "
+              "lists with batch on axis 0, and its windowed ring buffers "
+              "take one position for all rows",
+    "ssm": "the recurrent family 'ssm' (xLSTM) keeps per-layer state lists "
+           "with batch on axis 0",
+    "audio": "the family 'audio' (whisper) cross-attends to an encoder's "
+             "output, and the scheduler has no cross-attention cache",
+}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -143,10 +154,19 @@ class ModelBundle:
 # Serving storage
 # ---------------------------------------------------------------------------
 
+def refused_reason(cfg: ModelConfig) -> str:
+    """Why ``cfg``'s family is not served: not the stacked cache tree with
+    batch on axis 1 that admission splices."""
+    return (f"{REFUSED.get(cfg.family, f'unknown family {cfg.family!r}')}, "
+            f"not the stacked tree with batch on axis 1 that admission "
+            f"splices")
+
+
 def _check_served(cfg: ModelConfig) -> None:
     if cfg.family not in LM_FAMILIES:
-        raise ValueError(f"serving parameters are declared for {LM_FAMILIES}, "
-                         f"not {cfg.family!r}")
+        raise ValueError(f"serving parameters are declared for {LM_FAMILIES} "
+                         f"(attention, Mamba-2 and KDA layers), not "
+                         f"{cfg.family!r}: {refused_reason(cfg)}")
 
 
 def _serving_leaf(cfg: ModelConfig, spec: ParamSpec, leaf: jax.Array):

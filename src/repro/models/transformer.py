@@ -1,31 +1,36 @@
 """Decoder-only LM covering the dense, MoE and VLM families, and the
-granitemoehybrid layers that interleave Mamba-2 mixers with attention.
+hybrid layers that interleave Mamba-2 (granitemoehybrid) or gated
+delta-rule (KDA, kimi_linear) mixers with attention.
 
 The stack is a list of *runs* (``layer_runs``): maximal stretches of
 consecutive layers of one kind, in published order. A layer's kind is its
-mixer (attention, or a Mamba-2 mixer) and its feed-forward part (a dense
-MLP, or the expert layer). Each run executes with ``jax.lax.scan`` over
-parameters stacked along a leading ``layers`` dimension: the lowered HLO
-contains one layer body per run regardless of depth, which keeps compile
-time flat in depth and is the standard production pattern (MaxText et
-al.).
+mixer (attention, a Mamba-2 or a KDA mixer) and its feed-forward part (a
+dense MLP, or the expert layer). Each run executes with ``jax.lax.scan``
+over parameters stacked along a leading ``layers`` dimension: the lowered
+HLO contains one layer body per run regardless of depth, which keeps
+compile time flat in depth and is the standard production pattern
+(MaxText et al.).
 
 * A model with neither ``layer_types`` nor ``first_dense_layers`` is one
   run, ``layers``, and its cache is that run's tree.
-* ``first_dense_layers`` (deepseek-v3, moonlight) makes two runs:
+* ``first_dense_layers`` alone (deepseek-v3, moonlight) makes two runs:
   ``dense_layers`` with a dense MLP, then ``layers`` with the expert layer.
-* ``layer_types`` (granite-4.0-h) names each layer's mixer; a run is named
-  by its mixer and the index of its first layer (``mamba0``,
-  ``attention5``, ``mamba6`` ...).
+* ``layer_types`` (granite-4.0-h, kimi-linear) names each layer's mixer;
+  a run is keyed by mixer and MLP kind, and named by its mixer and the
+  index of its first layer (``mamba0``, ``attention5``, ``mamba6`` ...;
+  with ``first_dense_layers`` 1, ``kda0`` with a dense MLP, then ``kda1``,
+  ``attention3`` ... with the expert layer).
 
 With more than one run, the parameter tree and the cache hold one stacked
-tree per run under the run's name: the KV cache of an attention run, the
-recurrent state (``models/mamba2.py``: conv inputs and the SSM state) of a
-Mamba run, both with batch on axis 1. Prefill and decode carry each run's
-stacked cache or state through its scan and update it in place, layer by
-layer. Decode with ``decode_impl='pallas'`` keeps the held-expert layer's
-matrices whole the same way: the ``moe_decode`` kernel reads each layer's
-routed experts from the run's stacks, and nothing slices a layer out.
+tree per run under the run's name: the KV or latent cache of an attention
+run, the recurrent state of a Mamba run (``models/mamba2.py``: conv
+inputs and the SSM state) or of a KDA run (``models/kda.py``: conv inputs
+and the matrix state), all with batch on axis 1. Prefill and decode carry
+each run's stacked cache or state through its scan and update it in
+place, layer by layer. Decode with ``decode_impl='pallas'`` keeps the
+held-expert layer's matrices whole the same way: the ``moe_decode``
+kernel reads each layer's routed experts from the run's stacks, and
+nothing slices a layer out.
 
 Remat (activation checkpointing) wraps the scanned body in training;
 ``cfg.remat_policy`` names the policy.
@@ -42,7 +47,7 @@ import numpy as np
 from jax import lax
 
 from repro.models import attention as attn
-from repro.models import mamba2
+from repro.models import kda, mamba2
 from repro.models import mlp as mlp_mod
 from repro.models.common import (
     ModelConfig,
@@ -63,27 +68,34 @@ class Run(NamedTuple):
     """A run of consecutive layers of one kind."""
 
     name: str           # its key in the parameter and cache trees
-    mixer: str          # 'attention' | 'mamba'
+    mixer: str          # 'attention' | 'mamba' | 'kda'
     moe: bool           # the expert layer, or a dense MLP
     layers: int
 
 
 #: each mixer's (norm key, parameter key) in a layer's tree
-MIXERS = {"attention": ("ln_attn", "attn"), "mamba": ("ln_ssm", "ssm")}
+MIXERS = {"attention": ("ln_attn", "attn"), "mamba": ("ln_ssm", "ssm"),
+          "kda": ("ln_kda", "kda")}
+#: each mixer's parameter specs
+_MIXER_SPECS = {"attention": attn.make_attn_specs,
+                "mamba": mamba2.make_ssm_specs, "kda": kda.make_kda_specs}
+#: the cache leaves that hold a slot's recurrent state
+STATE_LEAVES = frozenset(mamba2.STATE_LEAVES + kda.STATE_LEAVES)
 
 
 def layer_runs(cfg: ModelConfig) -> list[Run]:
     """The stack's runs in the order they run."""
     moe = cfg.family == "moe"
+    n0 = cfg.first_dense_layers
     if cfg.layer_types:
+        kinds = [(mixer, moe and i >= n0) for i, mixer in
+                 enumerate(cfg.layer_types[:cfg.num_layers])]
         runs, first = [], 0
-        for mixer, group in itertools.groupby(
-                cfg.layer_types[:cfg.num_layers]):
+        for (mixer, is_moe), group in itertools.groupby(kinds):
             n = len(tuple(group))
-            runs.append(Run(f"{mixer}{first}", mixer, moe, n))
+            runs.append(Run(f"{mixer}{first}", mixer, is_moe, n))
             first += n
         return runs
-    n0 = cfg.first_dense_layers
     runs = [Run("dense_layers", "attention", False, n0)] if n0 else []
     return runs + [Run("layers", "attention", moe, cfg.num_layers - n0)]
 
@@ -103,8 +115,7 @@ def make_layer_specs(cfg: ModelConfig, *, dense: bool = False,
                         f32_at_use=True),
         "ln_mlp": ParamSpec((cfg.d_model,), ("embed",), init="ones",
                             f32_at_use=True),
-        key: (attn.make_attn_specs(cfg) if mixer == "attention"
-              else mamba2.make_ssm_specs(cfg)),
+        key: _MIXER_SPECS[mixer](cfg),
     }
     if cfg.family == "moe" and not dense:
         specs["moe"] = mlp_mod.make_moe_specs(cfg)
@@ -143,6 +154,9 @@ def _layer_forward(cfg: ModelConfig, p: dict[str, Any], x: jax.Array,
     if "ssm" in p:
         h = rms_norm(x, p["ln_ssm"], cfg.norm_eps)
         a, _, _ = mamba2.ssm_forward(cfg, p["ssm"], h)
+    elif "kda" in p:
+        h = rms_norm(x, p["ln_kda"], cfg.norm_eps)
+        a, _, _ = kda.kda_forward(cfg, p["kda"], h)
     else:
         h = rms_norm(x, p["ln_attn"], cfg.norm_eps)
         a = attn.attn_forward(cfg, p["attn"], h, positions, causal=True)
@@ -248,6 +262,8 @@ def init_lm_cache(cfg: ModelConfig, batch: int, max_len: int) -> dict[str, Any]:
     def one(run: Run):
         if run.mixer == "mamba":
             return mamba2.init_state(cfg, batch, run.layers)
+        if run.mixer == "kda":
+            return kda.init_state(cfg, batch, run.layers)
         return attn.init_kv_cache(cfg, batch, max_len, layers=run.layers)
 
     runs = layer_runs(cfg)
@@ -260,6 +276,8 @@ def lm_cache_axes(cfg: ModelConfig) -> dict[str, Any]:
     def one(run: Run):
         if run.mixer == "mamba":
             return mamba2.state_axes(cfg)
+        if run.mixer == "kda":
+            return kda.state_axes(cfg)
         return attn.kv_cache_axes(cfg, layers=True)
 
     runs = layer_runs(cfg)
@@ -366,8 +384,18 @@ def lm_prefill(cfg: ModelConfig, params: dict[str, Any],
         h, _ = _after_mixer(cfg, layer_params, h, a, prefill=True)
         return (h, cache), None
 
+    def body_kda(carry, xs):
+        h, cache = carry
+        layer_params, layer = xs
+        hn = rms_norm(h, layer_params["ln_kda"], cfg.norm_eps)
+        a, cache = kda.kda_prefill(cfg, layer_params["kda"], hn, cache,
+                                   layer)
+        h, _ = _after_mixer(cfg, layer_params, h, a, prefill=True)
+        return (h, cache), None
+
     bodies = {"attention": maybe_remat(body, cfg.remat_policy),
-              "mamba": maybe_remat(body_ssm, cfg.remat_policy)}
+              "mamba": maybe_remat(body_ssm, cfg.remat_policy),
+              "kda": maybe_remat(body_kda, cfg.remat_policy)}
     x, new_cache, _ = _run_stacks(cfg, params, cache, bodies, x)
     logits = lm_logits(cfg, params, x[:, -1:])
     return logits, new_cache
@@ -406,8 +434,18 @@ def lm_decode_step(cfg: ModelConfig, params: dict[str, Any],
                                layer=layer if in_place else None)
         return (h, cache), read
 
+    def body_kda(carry, xs):
+        h, cache = carry
+        layer_params, layer = xs
+        hn = rms_norm(h, layer_params["ln_kda"], cfg.norm_eps)
+        a, cache = kda.kda_decode(cfg, layer_params["kda"], hn, cache, layer)
+        h, read = _after_mixer(cfg, layer_params, h, a, prefill=False,
+                               layer=layer if in_place else None)
+        return (h, cache), read
+
     x, new_cache, reads = _run_stacks(
-        cfg, params, cache, {"attention": body, "mamba": body_ssm}, x,
+        cfg, params, cache,
+        {"attention": body, "mamba": body_ssm, "kda": body_kda}, x,
         whole_experts=in_place)
     logits = lm_logits(cfg, params, x)
     if not experts_read:

@@ -9,7 +9,9 @@ building blocks.
   taken at the true last prompt position, and no pad token enters a
   recurrent state) whose cache rows are spliced into the slot's row of
   the shared batch cache, in the same jitted op that sets the slot's
-  token, position and mask on the device;
+  token, position and mask on the device. On a TPU the prefill compiles
+  with the configuration's scoped VMEM (``prefill_vmem_kib``), where it
+  sets one;
 * **per-slot positions** — every decode step runs ONE program over the
   whole batch with a ``(B,)`` position vector (``attn_decode``'s per-row
   path), so co-batched requests at different depths neither pad nor
@@ -37,8 +39,9 @@ building blocks.
 * **cache** — the model's own cache tree, one row per slot (batch on
   axis 1 of every leaf): one stacked tree per run of layers
   (``transformer.layer_runs``), per-head K/V or one latent row a position
-  for attention, and for the Mamba-2 runs of a hybrid model a fixed-size
-  recurrent state (the conv's last inputs and the SSM state) beside them.
+  for attention, and for the Mamba-2 or KDA runs of a hybrid model a
+  fixed-size recurrent state (the convs' last inputs, and the SSM state
+  or each head's delta-rule matrix) beside them.
   Admission splices the prefilled row of every leaf into the slot, so a
   re-admitted slot starts from the new request's state and KV rows, never
   the last request's; ``serving.cache_bytes`` reads the tree's bytes and
@@ -68,9 +71,9 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from repro.models.mamba2 import STATE_LEAVES
-from repro.models.registry import LM_FAMILIES, ModelBundle, serving_params
-from repro.models.transformer import expert_layers
+from repro.models.registry import (LM_FAMILIES, ModelBundle, refused_reason,
+                                   serving_params)
+from repro.models.transformer import STATE_LEAVES, expert_layers
 from repro.observability import trace
 from repro.observability.metrics import get_registry
 
@@ -154,12 +157,8 @@ class BatchScheduler:
         if bundle.cfg.family not in LM_FAMILIES:
             raise ValueError(
                 f"BatchScheduler drives the LM families {LM_FAMILIES} (with "
-                f"Mamba-2 layers through layer_types), not "
-                f"{bundle.cfg.family!r}: the recurrent families 'hybrid' "
-                f"(RG-LRU) and 'ssm' (xLSTM) keep per-layer state lists "
-                f"with batch on axis 0, and RG-LRU's windowed ring buffers "
-                f"take one position for all rows, not the stacked tree "
-                f"with batch on axis 1 that admission splices")
+                f"Mamba-2 and KDA layers through layer_types), not "
+                f"{bundle.cfg.family!r}: {refused_reason(bundle.cfg)}")
         self.bundle = bundle
         self._g_weight_bytes = get_registry().gauge("serving.weight_bytes")
         self.params = params
@@ -178,7 +177,12 @@ class BatchScheduler:
                                    donate_argnums=(1,))
         # one-row prefill; retraces per distinct prompt length (serving
         # workloads draw from a small set of lengths — see docs/serving.md)
-        self.prefill_step = jax.jit(make_prefill_step(bundle))
+        vmem = bundle.cfg.prefill_vmem_kib
+        self.prefill_step = jax.jit(
+            make_prefill_step(bundle),
+            compiler_options=(
+                {"xla_tpu_scoped_vmem_limit_kib": vmem}
+                if vmem and jax.default_backend() == "tpu" else None))
         self._insert_row = jax.jit(self._admit_row_impl, donate_argnums=(0,))
         self.cache = bundle.init_cache(batch_size, max_len)
         get_registry().gauge("serving.cache_bytes").set(sum(

@@ -328,7 +328,8 @@ def test_decode_steps_and_steps_ahead_per_request(lm):
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("arch", ["aiida-demo-110m", "recurrentgemma-2b",
-                                  "granite-4.0-h-small"])
+                                  "granite-4.0-h-small",
+                                  "kimi-linear-48b-a3b"])
 def test_prefill_equals_stepwise_decode(arch):
     """Prefilling N tokens must land in the same state as feeding those N
     tokens one decode step at a time: identical next token and identical
@@ -605,13 +606,15 @@ def test_generate_timeline_persists_request_spans_not_step_spans(runner):
 #: one reduced config per served family; qwen3-4b adds the q/k norms,
 #: moonlight the latent attention, the leading dense layer and the
 #: no-drop expert layer over held experts, granite-4.0-h the Mamba-2
-#: layers and softmax routing
+#: layers and softmax routing, kimi-linear the KDA layers with a dense
+#: first layer among them
 SERVED_ARCHS = ("qwen2-0.5b", "qwen3-4b", "moonshot-v1-16b-a3b",
-                "llava-next-34b", "moonlight-16b-a3b", "granite-4.0-h-small")
+                "llava-next-34b", "moonlight-16b-a3b", "granite-4.0-h-small",
+                "kimi-linear-48b-a3b")
 #: leaves the forward reads in float32, by name (independent of the specs)
 F32_LEAF_NAMES = ("ln_attn", "ln_mlp", "ln_final", "q_norm", "k_norm",
                   "router", "router_bias", "kv_norm", "ln_ssm", "dt_bias",
-                  "A_log", "D", "norm")
+                  "A_log", "D", "norm", "ln_kda", "o_norm")
 
 
 def _named_leaves(tree):

@@ -228,10 +228,11 @@ def test_hybrid_decode_step_updates_its_state_in_place(one_chip,
 @pytest.mark.parametrize("arch,over", [
     ("moonlight-16b-a3b", dict(num_layers=3, experts_held=8)),
     ("granite-4.0-h-small", dict(num_layers=6, experts_held=9)),
+    ("kimi-linear-48b-a3b", dict(num_layers=4, experts_held=16)),
 ])
 def test_expert_decode_step_reads_the_expert_stacks_in_place(
         one_chip, monkeypatch, arch, over):
-    """Both expert configurations at their published widths, as the
+    """The expert configurations at their published widths, as the
     scheduler jits the decode step: the held-expert layer is the
     ``moe_decode`` kernel, no op but the kernel's call yields a layer's
     expert matrices or the whole stack (no dynamic-slice of it), and the
@@ -241,12 +242,13 @@ def test_expert_decode_step_reads_the_expert_stacks_in_place(
 
     from repro.configs import get_config
     from repro.kernels.decode_attention import ops as da_ops
+    from repro.kernels.kda_decode import ops as kd_ops
     from repro.kernels.moe_decode import ops as md_ops
     from repro.kernels.ssm_decode import ops as sd_ops
     from repro.models.registry import build, serving_params
     from repro.serving.serve import make_scheduled_step
 
-    for ops in (da_ops, md_ops, sd_ops):
+    for ops in (da_ops, md_ops, sd_ops, kd_ops):
         monkeypatch.setattr(ops, "interpret_default", lambda: False)
     cfg = get_config(arch).replace(decode_impl="pallas", **over)
     bundle = build(cfg)
@@ -265,7 +267,10 @@ def test_expert_decode_step_reads_the_expert_stacks_in_place(
     text = lowered.compile().as_text()
     assert "moe_decode" in text
     n, d, f = cfg.held_experts, cfg.d_model, cfg.moe_d_ff
-    sizes = {tuple(sorted(s)) for s in ((n, d, f), (d, f))}
+    # one expert's (D, F): not where the shared expert has the same
+    # shape (kimi-linear), whose matrices the scan hands each layer
+    sizes = {tuple(sorted(s)) for s in ((n, d, f), (d, f))
+             if len(s) == 3 or cfg.shared_d_ff != f}
     found = []
     for line in text.splitlines():
         m = re.match(r"\s*(?:ROOT )?%(\S+) = \w+\[([\d,]*)\]\S* ([\w-]+)\(",
@@ -276,6 +281,124 @@ def test_expert_decode_step_reads_the_expert_stacks_in_place(
             if dims in sizes or dims[-3:] == tuple(sorted((n, d, f))):
                 found.append(f"{m.group(1)} {m.group(3)}[{m.group(2)}]")
     assert not found, found
+
+
+@pytest.mark.parametrize("heads_block", [4, 8])
+def test_kda_decode_compiles_at_kimi_linear(one_chip, heads_block):
+    """Kimi-Linear's KDA state, 32 heads of 128 for 4 slots in a stack of
+    3 layers: row blocks of q, k and the decay, column blocks of v and the
+    output, the state's block read at a scalar-prefetched layer and
+    written back in place."""
+    from repro.kernels.kda_decode.ops import kda_decode
+
+    def fn(q, k, v, g, beta, state, layer):
+        return kda_decode(q, k, v, g, beta, state, layer,
+                          heads_block=heads_block, interpret=False)
+
+    row = _sds((4, 32, 128), F32, one_chip)
+    compiled = jax.jit(fn, donate_argnums=(5,)).lower(
+        row, row, row, row, _sds((4, 32), F32, one_chip),
+        _sds((3, 4, 32, 128, 128), F32, one_chip),
+        _sds((), I32, one_chip)).compile()
+    assert "kda_decode" in compiled.as_text()
+    assert compiled.memory_analysis().alias_size_in_bytes == \
+        3 * 4 * 32 * 128 * 128 * 4
+
+
+def test_kda_decode_step_updates_its_state_in_place(one_chip, monkeypatch):
+    """kimi-linear-48b-a3b at its published widths, cut to its first four
+    layers (KDA 0 with the dense MLP, KDA 1-2 and MLA 3 with 16 held
+    experts), 4 slots of 2048, as the scheduler jits the step: the KDA,
+    latent and expert kernels compile inside it, the output aliases the
+    whole donated cache (the float32 states, the conv inputs and the
+    latent rows), no op but an in-place update yields a buffer of one
+    layer's state or of the stack, and the step's temporaries are smaller
+    than one layer's state."""
+    import functools
+    import re
+
+    from repro.configs import get_config
+    from repro.kernels.decode_attention import ops as da_ops
+    from repro.kernels.kda_decode import ops as kd_ops
+    from repro.kernels.moe_decode import ops as md_ops
+    from repro.models.registry import build, serving_params
+    from repro.serving.serve import make_scheduled_step
+
+    for ops in (da_ops, md_ops, kd_ops):
+        monkeypatch.setattr(ops, "interpret_default", lambda: False)
+    cfg = get_config("kimi-linear-48b-a3b").replace(
+        decode_impl="pallas", num_layers=4, experts_held=16)
+    bundle = build(cfg)
+    b, max_len = 4, 2048
+
+    def on_chip(tree):
+        return jax.tree.map(lambda s: _sds(s.shape, s.dtype, one_chip), tree)
+
+    params = on_chip(jax.eval_shape(functools.partial(serving_params, cfg),
+                                    bundle.param_shapes()))
+    cache = on_chip(jax.eval_shape(lambda: bundle.init_cache(b, max_len)))
+    assert cache["kda1"]["state"].shape == (2, b, 32, 128, 128)
+    compiled = jax.jit(make_scheduled_step(bundle), donate_argnums=(1,)) \
+        .lower(params, cache, _sds((b, 1), I32, one_chip),
+               _sds((b,), I32, one_chip),
+               _sds((b,), jnp.bool_, one_chip)).compile()
+    text = compiled.as_text()
+    for kernel in ("kda_decode", "latent_decode_attention", "moe_decode"):
+        assert kernel in text, kernel
+    state = cache["kda1"]["state"].shape
+    sizes = {tuple(sorted(d for d in state[i:] if d > 1)) for i in (0, 1)}
+    found = []
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%(\S+) = \w+\[([\d,]*)\]\S* ([\w-]+)\(",
+                     line)
+        if m and m.group(3) not in _CACHE_PLUMBING | {"custom-call"}:
+            dims = tuple(sorted(int(d) for d in m.group(2).split(",")
+                                if d and int(d) > 1))
+            if dims in sizes:
+                found.append(f"{m.group(1)} {m.group(3)}[{m.group(2)}]")
+    assert not found, found
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == sum(
+        leaf.size * leaf.dtype.itemsize for leaf in jax.tree.leaves(cache))
+    assert mem.temp_size_in_bytes < b * 32 * 128 * 128 * 4
+
+
+@pytest.mark.parametrize("roomy", [False, True])
+def test_kimi_prefill_compiles_at_1536_with_the_roomy_options(one_chip,
+                                                               roomy):
+    """kimi-linear-48b-a3b's prefill of a 1536-token prompt at its
+    published widths, cut to its first four layers: at the compiler's
+    default scoped VMEM of 16 MiB it is refused (the expert layer's row
+    gather, 12288 rows of 2304, asks for 16.41 MiB), and at the
+    configuration's ``prefill_vmem_kib``, as the scheduler compiles it, it
+    compiles. A compiler that stops refusing the default makes the setting
+    unneeded."""
+    import functools
+
+    from repro.configs import get_config
+    from repro.models.registry import build, serving_params
+    from repro.serving.serve import make_prefill_step
+
+    cfg = get_config("kimi-linear-48b-a3b").replace(
+        decode_impl="pallas", num_layers=4, experts_held=16)
+    bundle = build(cfg)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda s: _sds(s.shape, s.dtype, one_chip), tree)
+
+    params = on_chip(jax.eval_shape(functools.partial(serving_params, cfg),
+                                    bundle.param_shapes()))
+    row = on_chip(jax.eval_shape(lambda: bundle.init_cache(1, 2048)))
+    lowered = jax.jit(make_prefill_step(bundle)).lower(
+        params, {"tokens": _sds((1, 1536), I32, one_chip)}, row)
+    if not roomy:
+        with pytest.raises(jax.errors.JaxRuntimeError,
+                           match="memory space vmem"):
+            lowered.compile()
+        return
+    compiled = lowered.compile(
+        {"xla_tpu_scoped_vmem_limit_kib": cfg.prefill_vmem_kib})
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**30
 
 
 def test_sharded_decode_step_compiles_on_four_chips(topo, monkeypatch):
