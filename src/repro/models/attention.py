@@ -388,7 +388,7 @@ def attn_decode(cfg: ModelConfig, p: dict[str, jax.Array], x: jax.Array,
     # on the kernel's scalar-prefetch lens argument, and it reads the layer
     # from the stack. Ring buffers and soft-capping stay on the
     # masked-einsum path below.
-    if cfg.decode_impl == "pallas" and window == 0 and not cfg.attn_softcap:
+    if runs_decode_kernel(cfg) and window == 0:
         kv_len = (pos if per_row else jnp.broadcast_to(pos, (b,))) + 1
         out = _decode_kernel(cfg, q[:, 0], cache["k"], cache["v"],
                              kv_len.astype(jnp.int32), layer, float(scale))
@@ -423,6 +423,26 @@ def attn_decode(cfg: ModelConfig, p: dict[str, jax.Array], x: jax.Array,
     out = shard(out, "kv_batch", None, "heads_sharded", None)
     dt = x.dtype
     return jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(dt)), cache
+
+
+def runs_decode_kernel(cfg: ModelConfig) -> bool:
+    """Whether decode runs the dense flash-decode kernel: the Pallas
+    decode path over per-head K/V (not a latent cache), without
+    soft-capping. (A ring-buffer layer keeps the masked einsum too.)"""
+    return (cfg.decode_impl == "pallas" and not cfg.kv_lora_rank
+            and not cfg.attn_softcap)
+
+
+def decode_kernel_block(cfg: ModelConfig, k: jax.Array) -> int:
+    """Positions in each block that the dense decode kernel reads of the
+    stacked K cache ``k`` (L, B, Hkv, hd, Smax), 0 where decode does not
+    run it. Under a mesh each shard's block holds its share of the heads
+    and may be longer."""
+    if not runs_decode_kernel(cfg):
+        return 0
+    from repro.kernels.decode_attention.kernel import kv_block
+    _, _, hkv, hd, smax = k.shape
+    return kv_block(hkv, hd, smax, cfg.attn_kv_block, k.dtype.itemsize)
 
 
 def _decode_kernel(cfg: ModelConfig, q, ck, cv, kv_len, layer, scale: float):

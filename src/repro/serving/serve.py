@@ -71,6 +71,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from repro.models.attention import decode_kernel_block
 from repro.models.registry import (LM_FAMILIES, ModelBundle, refused_reason,
                                    serving_params)
 from repro.models.transformer import STATE_LEAVES, expert_layers
@@ -194,11 +195,13 @@ class BatchScheduler:
         # device-resident control state: last token, cache depth and mask
         # per slot. Empty slots keep a frozen pos — their rows are never
         # read, and admission overwrites the whole row before re-activating
-        # one. ``_active`` is the host's copy of the device's mask.
+        # one. ``_active`` and ``_pos`` are the host's copies of the
+        # device's mask and positions.
         self.tokens = jnp.zeros((batch_size, 1), jnp.int32)
         self.pos = jnp.zeros(batch_size, jnp.int32)
         self.active = jnp.zeros(batch_size, bool)
         self._active = np.zeros(batch_size, bool)
+        self._pos = np.zeros(batch_size, np.int64)
         self._in_flight: _Launched | None = None
         reg = get_registry()
         self._m_submitted = reg.counter("serving.requests_submitted")
@@ -219,6 +222,19 @@ class BatchScheduler:
         if self._experts_held:
             self._m_experts_read = reg.counter("moe.experts_read")
             self._m_experts_held = reg.counter("moe.experts_held")
+        #: the dense decode kernel's layers and positions a KV block, for
+        #: a model whose decode runs it (else 0), and its grid's blocks a
+        #: step over those layers
+        ks = [leaf for path, leaf in
+              jax.tree_util.tree_flatten_with_path(self.cache)[0]
+              if getattr(path[-1], "key", None) == "k"]
+        self._kv_layers = sum(k.shape[0] for k in ks)
+        self._kv_block = decode_kernel_block(bundle.cfg, ks[0]) if ks else 0
+        if self._kv_block:
+            self._kv_grid = (self._kv_layers * batch_size
+                             * (ks[0].shape[-1] // self._kv_block))
+            self._m_kv_read = reg.counter("attn.kv_blocks_read")
+            self._m_kv_grid = reg.counter("attn.kv_blocks_grid")
 
     @property
     def params(self) -> Any:
@@ -278,6 +294,7 @@ class BatchScheduler:
                                  self.active, row_cache, first_tok,
                                  np.int32(slot), np.int32(len(req.prompt)))
             self._active[slot] = True
+            self._pos[slot] = len(req.prompt)
             self.slots[slot] = req
             tok = int(jax.device_get(first_tok)[0, 0])
             req.generated = [tok]
@@ -350,6 +367,13 @@ class BatchScheduler:
         next_tok.copy_to_host_async()
         self.tokens = next_tok
         self._m_decode_steps.inc()
+        if self._kv_block:
+            # the blocks the kernel fetches: ceil(kv_len / block) a row,
+            # kv_len = pos + 1, in each layer that runs it
+            blocks = (self._pos + self._kv_block) // self._kv_block
+            self._m_kv_read.inc(self._kv_layers * int(blocks.sum()))
+            self._m_kv_grid.inc(self._kv_grid)
+        self._pos += self._active
         return next_tok, read, rows
 
     def _collect(self, launched: _Launched) -> list[Request]:

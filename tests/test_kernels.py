@@ -7,6 +7,7 @@ import numpy as np
 import pytest
 from _hypothesis_compat import given, settings, strategies as st  # noqa: E501
 
+from repro.kernels.decode_attention import kernel as DK
 from repro.kernels.decode_attention.ops import decode_attention
 from repro.kernels.decode_attention.ref import decode_attention_ref
 from repro.kernels.flash_attention.ops import flash_attention
@@ -212,6 +213,80 @@ def test_decode_attention_reads_its_layer_of_the_stack(layer):
     for other in set(range(n)) - {layer}:
         assert not np.allclose(np.asarray(out), np.asarray(
             decode_attention_ref(q, k, v, lens, layer=other)), atol=1e-2)
+
+
+@pytest.mark.parametrize("kv_len,blocks", [
+    (0, [0, 0, 0, 0]),        # an empty row reads block 0 once
+    (1, [0, 0, 0, 0]),
+    (64, [0, 0, 0, 0]),       # exactly one block
+    (65, [0, 1, 1, 1]),       # one position into the second
+    (130, [0, 1, 2, 2]),
+    (256, [0, 1, 2, 3]),      # the whole cache
+])
+def test_decode_attention_kv_index_map_stays_on_the_last_needed_block(
+        kv_len, blocks):
+    """The k/v index map at each step of a row's KV axis: the step's own
+    block up to the row's last needed one, that block past it, so the
+    pipeline fetches max(ceil(kv_len / bkv), 1) distinct blocks, the
+    count ``attn.kv_blocks_read`` takes. Layer and heads pass through."""
+    at = jnp.asarray([2], jnp.int32)
+    lens = jnp.asarray([256, kv_len], jnp.int32)
+    maps = [tuple(int(i) for i in DK.kv_block_map(1, ik, at, lens, bkv=64))
+            for ik in range(4)]
+    assert maps == [(2, 1, 0, 0, blk) for blk in blocks]
+    assert len(set(blocks)) == max(-(-kv_len // 64), 1)
+
+
+@pytest.mark.parametrize("hkv,hd,smax,block_kv,want", [
+    (16, 64, 2048, 512, 512),     # granite-3-2b: a 4 MiB pair
+    (8, 128, 2048, 512, 512),     # granite-4.0-h's attention layers
+    (2, 64, 2048, 512, 512),      # qwen2-0.5b
+    (2, 64, 128, 512, 128),       # a cache shorter than the block
+    (32, 128, 2048, 512, 256),    # an 8 MiB pair at 256
+    (64, 128, 2048, 512, 128),    # never under 128 positions
+    (4, 32, 192, 128, 64),        # cut to a divisor of the cache first
+])
+def test_decode_attention_block_size_from_the_shape(hkv, hd, smax, block_kv,
+                                                    want):
+    """The block holds every KV head of a row: ``block_kv`` is halved
+    while a double-buffered bfloat16 K and V pair passes 8 MiB."""
+    assert DK.kv_block(hkv, hd, smax, block_kv, 2) == want
+
+
+@pytest.mark.parametrize("hkv", [1, 2, 8, 16])
+@pytest.mark.parametrize("g", [1, 2, 7])
+def test_decode_attention_folded_heads_ragged_lengths(hkv, g):
+    """Every KV head of a row in one block, G query heads to each, over
+    ragged lengths that end inside a block, on a block edge and in the
+    first block."""
+    b, hd, smax = 3, 32, 256
+    q = jnp.asarray(RNG.normal(0, 1, (b, hkv * g, hd)), jnp.float32)
+    k = jnp.asarray(RNG.normal(0, 1, (b, hkv, hd, smax)), jnp.float32)
+    v = jnp.asarray(RNG.normal(0, 1, (b, hkv, hd, smax)), jnp.float32)
+    lens = jnp.asarray([200, 64, 5], jnp.int32)
+    out = decode_attention(q, k, v, lens, block_kv=64)
+    ref = decode_attention_ref(q, k, v, lens)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=5e-5)
+
+
+def test_decode_attention_one_long_row_beside_short_ones():
+    """The served case: one row far longer than the others. Positions past
+    each row's length hold poison, so a block fetched for the wrong row
+    or unmasked shows; each row matches the oracle."""
+    b, h, hkv, hd, smax = 4, 8, 4, 32, 1024
+    q = jnp.asarray(RNG.normal(0, 1, (b, h, hd)), jnp.float32)
+    k = RNG.normal(0, 1, (b, hkv, hd, smax)).astype(np.float32)
+    v = RNG.normal(0, 1, (b, hkv, hd, smax)).astype(np.float32)
+    lens = np.asarray([1000, 3, 1, 130])
+    for row, n in enumerate(lens):
+        k[row, ..., n:] = 999.0
+        v[row, ..., n:] = -999.0
+    out = decode_attention(q, jnp.asarray(k), jnp.asarray(v),
+                           jnp.asarray(lens, jnp.int32), block_kv=128)
+    ref = decode_attention_ref(q, jnp.asarray(k), jnp.asarray(v),
+                               jnp.asarray(lens, jnp.int32))
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=5e-5)
+    assert np.abs(np.asarray(out)).max() < 10.0
 
 
 # ---------------------------------------------------------------------------

@@ -323,6 +323,53 @@ def test_decode_steps_and_steps_ahead_per_request(lm):
     assert got["overrun_steps"] == 0
 
 
+def _kv_block_counts(monkeypatch, bundle, params, prompt, new, *, batch=4,
+                     max_len=64):
+    """Serve one request through a scheduler that counts into a registry
+    of its own; returns that registry's counters."""
+    from repro.observability.metrics import MetricsRegistry
+    from repro.serving import serve
+
+    reg = MetricsRegistry()
+    monkeypatch.setattr(serve, "get_registry", lambda: reg)
+    _serve(bundle, params, [prompt], new, batch=batch, max_len=max_len)
+    return reg.snapshot()["counters"]
+
+
+def test_scheduler_counts_the_kv_blocks_the_dense_kernel_reads(monkeypatch):
+    """One long row beside three empty slots, blocks of 16 over a cache of
+    64: the 4 decode steps of a 30-token prompt attend to 31, 32, 33 and
+    34 positions, 2, 2, 3 and 3 blocks of the live row, and block 0 of
+    each empty row, in each of the 2 layers, out of a grid of 4 rows x 4
+    blocks a step."""
+    bundle, params = _build("pallas", attn_kv_block=16)
+    got = _kv_block_counts(monkeypatch, bundle, params,
+                           RNG.integers(1, 500, 30).tolist(), 5)
+    assert bundle.cfg.num_layers == 2
+    assert got["serving.decode_steps"] == 4
+    assert got["attn.kv_blocks_read"] == 2 * ((2 + 2 + 3 + 3) + 4 * 3)
+    assert got["attn.kv_blocks_grid"] == 4 * 2 * (4 * 4)
+
+
+@pytest.mark.parametrize("arch,decode_impl", [
+    ("moonlight-16b-a3b", "pallas"),     # latent cache and expert layers
+    (ARCH, "direct"),                    # the masked einsum
+])
+def test_scheduler_counts_no_kv_blocks_without_the_dense_kernel(
+        monkeypatch, arch, decode_impl):
+    """A model whose decode does not run the dense kernel registers
+    neither counter."""
+    cfg = reduced_config(arch).replace(dtype="float32",
+                                       decode_impl=decode_impl)
+    bundle = build(cfg)
+    got = _kv_block_counts(monkeypatch, bundle,
+                           bundle.init_params(jax.random.PRNGKey(0)),
+                           RNG.integers(1, 500, 9).tolist(), 3)
+    assert got["serving.decode_steps"] == 2
+    assert "attn.kv_blocks_read" not in got
+    assert "attn.kv_blocks_grid" not in got
+
+
 # ---------------------------------------------------------------------------
 # prefill/decode parity
 # ---------------------------------------------------------------------------

@@ -60,20 +60,34 @@ def _compile(fn, *args):
 # decode attention (the serving path)
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("arch,b,h,hkv,hd,smax", [
-    ("qwen2-0.5b", 4, 14, 2, 64, 256),
-    ("aiida-demo-110m", 4, 12, 4, 64, 256),
+@pytest.mark.parametrize("arch,b,h,hkv,hd,smax,layers", [
+    pytest.param("qwen2-0.5b", 4, 14, 2, 64, 256, None,
+                 id="qwen2-0.5b-4-14-2-64-256"),
+    pytest.param("aiida-demo-110m", 4, 12, 4, 64, 256, None,
+                 id="aiida-demo-110m-4-12-4-64-256"),
+    # every KV head of a row in one block: granite's 16 stored heads of 64
+    # and granite-4.0-h's 8 of 128 fit scoped VMEM at 512 positions
+    pytest.param("granite-3-2b", 4, 32, 16, 64, 2048, 36,
+                 id="granite-3-2b-4-32-16-64-2048-36"),
+    pytest.param("granite-4.0-h-small", 4, 32, 8, 128, 2048, 2,
+                 id="granite-4.0-h-small-4-32-8-128-2048-2"),
 ])
-def test_decode_attention_compiles(one_chip, arch, b, h, hkv, hd, smax):
+def test_decode_attention_compiles(one_chip, arch, b, h, hkv, hd, smax,
+                                   layers):
+    """The dense kernel at served widths; with ``layers``, on the stacked
+    cache at a traced layer index, as the decode step calls it."""
     from repro.kernels.decode_attention.ops import decode_attention
 
-    def fn(q, k, v, lens):
-        return decode_attention(q, k, v, lens, block_kv=512, interpret=False)
+    stack = () if layers is None else (layers,)
+
+    def fn(q, k, v, lens, at):
+        return decode_attention(q, k, v, lens, block_kv=512, interpret=False,
+                                layer=None if layers is None else at)
 
     compiled = _compile(fn, _sds((b, h, hd), BF16, one_chip),
-                        _sds((b, hkv, hd, smax), BF16, one_chip),
-                        _sds((b, hkv, hd, smax), BF16, one_chip),
-                        _sds((b,), I32, one_chip))
+                        _sds(stack + (b, hkv, hd, smax), BF16, one_chip),
+                        _sds(stack + (b, hkv, hd, smax), BF16, one_chip),
+                        _sds((b,), I32, one_chip), _sds((), I32, one_chip))
     assert "tpu_custom_call" in compiled.as_text()
 
 
